@@ -9,8 +9,8 @@ each variant's numbers appended to a JSON log.
         --arch qwen3-moe-30b-a3b --shape train_4k \
         --variants baseline,no_fsdp,mb4 --out hillclimb_qwen3moe.json
 
-NOTE: must run in its own process (sets XLA_FLAGS for 512 host devices via
-repro.launch.dryrun import).
+NOTE: must run in its own process (``main`` sets XLA_FLAGS for 512 host
+devices through ``repro.launch.dryrun.force_host_devices``).
 """
 from __future__ import annotations
 
@@ -19,7 +19,8 @@ import json
 import os
 import time
 
-from repro.launch.dryrun import lower_cell  # sets XLA_FLAGS on import
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.dryrun import force_host_devices, lower_cell
 from repro.train.train_loop import TrainConfig
 
 #: named variants: kwargs for lower_cell
@@ -71,6 +72,8 @@ def run_variant(arch: str, shape: str, name: str) -> dict:
 
 
 def main() -> None:
+    force_host_devices()
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--shape", required=True)

@@ -51,6 +51,7 @@ import time
 import numpy as np
 
 from _bench_io import BenchRows, Gates, check_gates
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core.trace import JobClass
 from repro.market import SelectionDaemon, SimulatedSpotFeed, synthetic_stream
 from repro.selector import (BatchedRankState, IdentityCatalog, JaxRankState,
@@ -601,6 +602,7 @@ def bench_daemon(n_events: int = 10_000, seed: int = 7) -> None:
 
 
 def main(smoke: bool = False) -> None:
+    enable_compile_cache()
     print("name,us_per_call,derived")
     bench_reprice(64, 1_000, 0.01)
     bench_reprice(64, 10_000, 0.01)

@@ -49,6 +49,7 @@ import time
 from _bench_io import BenchRows, Gates, check_gates
 from serve_bench import SELECTIONS, _market_text, _service, _submissions, \
     _universe
+from repro.launch.compile_cache import enable_compile_cache
 from repro.market import RecordedPriceFeed, ServeFrontend
 from repro.obs import MetricsRegistry
 
@@ -128,6 +129,7 @@ def _trial(store, ids, base, market: str, subs, repeats: int
 
 
 def main(smoke: bool = False) -> None:
+    enable_compile_cache()
     print("name,us_per_call,derived")
     n_subs, repeats, trials = (4_000, 2, 2) if smoke else (20_000, 5, 3)
     store, ids, base = _universe()

@@ -14,6 +14,7 @@ from typing import Callable, Dict, Hashable, List, Mapping, Sequence, Tuple
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.selector import BackendUnavailableError, rank_dense, rank_pairs
 
 
@@ -77,6 +78,7 @@ def compare(n_jobs: int, n_cfgs: int, repeat: int = 20) -> Dict[str, float]:
 
 
 def main() -> None:
+    enable_compile_cache()
     print("name,cells,us_dict,us_numpy,us_jax,speedup")
     for n_jobs, n_cfgs in ((10, 10), (50, 20), (100, 100), (500, 100),
                            (1000, 250)):

@@ -30,6 +30,7 @@ import time
 import numpy as np
 
 from _bench_io import BenchRows
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core import costmodel, spark_sim
 from repro.core.trace import JobClass
 from repro.market import (JournalReplayer, RecordedPriceFeed,
@@ -138,6 +139,7 @@ def bench_journal_audit(daemon: SelectionDaemon, n_events: int, seed: int,
 
 
 def main(smoke: bool = False) -> None:
+    enable_compile_cache()
     print("name,us_per_call,derived")
     bench_record_roundtrip(64 if smoke else 256, 50 if smoke else 200)
 

@@ -14,6 +14,7 @@ import os
 import sys
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core import costmodel, evaluate, spark_sim
 from repro.core.flora import Flora
 from repro.core.trace import JobClass, PAPER_JOBS
@@ -134,6 +135,7 @@ def bench_rank_vectorized_vs_dict():
 
 
 def main() -> None:
+    enable_compile_cache()
     t0 = time.time()
     trace = spark_sim.generate_trace(seed=0)
     price = costmodel.LinearPriceModel()

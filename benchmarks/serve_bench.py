@@ -29,6 +29,7 @@ import sys
 import time
 
 from _bench_io import BenchRows, Gates, check_gates
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core.trace import JobClass
 from repro.market import (JournalReplayer, RecordedPriceFeed,
                           SelectionDaemon, ServeFrontend, SimulatedSpotFeed,
@@ -169,6 +170,7 @@ def bench_frontend(store, ids, base, market: str, subs, workers: int,
 
 
 def main(smoke: bool = False) -> None:
+    enable_compile_cache()
     print("name,us_per_call,derived")
     n_subs, n_ticks = (240, 60) if smoke else (600, 220)
     store, ids, base = _universe()

@@ -38,6 +38,7 @@ import sys
 import time
 
 from _bench_io import BenchRows, Gates, check_gates
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core import costmodel, spark_sim
 from repro.core.evaluate import turbulence_curves
 from repro.market import (PollingPriceFeed, RecordedPriceFeed,
@@ -218,6 +219,7 @@ def bench_polled_vs_recorded(catalog, store, base, events) -> None:
 
 
 def main(smoke: bool = False) -> None:
+    enable_compile_cache()
     print("name,us_per_call,derived")
     catalog, store, jobs = _universe()
     base = dict(PriceTable.from_catalog(catalog).items())

@@ -1,6 +1,11 @@
-"""Serve a small model with batched requests through the engine.
+"""Serve a model with batched requests through the engine.
 
-    PYTHONPATH=src python examples/serve_lm.py --arch rwkv6-3b --requests 6
+    PYTHONPATH=src python examples/serve_lm.py --arch qwen3-1.7b --requests 6
+    PYTHONPATH=src python examples/serve_lm.py --reduced --requests 2
+
+The model serves at its published width, with the weights in its compute
+dtype; ``--reduced`` swaps in the same-family shrunken config for a quick
+run on a CPU.
 
 With ``--report dryrun_single.json`` the decode fleet's mesh is first
 planned through the selection service (class A, state-resident), and the
@@ -16,6 +21,7 @@ import jax.numpy as jnp
 import repro.configs as configs
 from repro.core.costmodel import TpuPriceModel
 from repro.core.tpu_flora import service_from_dryrun_report
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model, count_params
 from repro.serve.engine import Engine, Request, plan_decode_placement
 
@@ -27,12 +33,15 @@ def main() -> None:
     ap.add_argument("--slots", type=int, default=2)
     ap.add_argument("--prompt-len", type=int, default=12)
     ap.add_argument("--max-new", type=int, default=8)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the shrunken same-family config")
     ap.add_argument("--report", default=None,
                     help="dry-run report: plan the decode mesh via the "
                          "selection service before serving")
     ap.add_argument("--market", default="ondemand",
                     choices=["ondemand", "spot"])
     args = ap.parse_args()
+    enable_compile_cache()
 
     placement = None
     if args.report and os.path.exists(args.report):
@@ -44,10 +53,14 @@ def main() -> None:
               f"at {placement.hourly_cost:.2f} $/h "
               f"(class {placement.job_class.value})")
 
-    cfg = configs.reduced(configs.get(args.arch))
+    cfg = configs.get(args.arch)
+    if args.reduced:
+        cfg = configs.reduced(cfg)
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    print(f"[serve] {cfg.name} (reduced): "
+    params = jax.jit(lambda key: jax.tree.map(
+        lambda x: x.astype(cfg.compute_dtype), model.init(key)))(
+            jax.random.PRNGKey(0))
+    print(f"[serve] {cfg.name}{' (reduced)' if args.reduced else ''}: "
           f"{count_params(model.param_specs())/1e6:.1f}M params, "
           f"{args.slots} decode slots")
 

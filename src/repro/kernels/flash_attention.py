@@ -30,9 +30,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool,
     """One (b, h, iq) tile.  q_ref: (1,1,bq,D); k_ref/v_ref: (1,1,Sk,D)."""
     bq, D = q_ref.shape[2], q_ref.shape[3]
     iq = pl.program_id(2)
-    # index the loaded array, not the ref: scalar-int ref indices are
-    # unsupported by interpret-mode discharge in this pallas version
-    q = q_ref[...][0, 0].astype(jnp.float32) * scale
+    q = q_ref[0, 0].astype(jnp.float32) * scale
 
     nkv = seq_k // block_kv
     q0 = iq * bq
@@ -47,12 +45,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool,
 
     def body(j, carry):
         acc, m, l = carry
-        k = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(0, 1),
-                            pl.dslice(j * block_kv, block_kv),
-                            slice(None)))[0, 0].astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(0, 1),
-                            pl.dslice(j * block_kv, block_kv),
-                            slice(None)))[0, 0].astype(jnp.float32)
+        kv_rows = pl.ds(j * block_kv, block_kv)
+        k = k_ref[0, 0, kv_rows, :].astype(jnp.float32)
+        v = v_ref[0, 0, kv_rows, :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         qpos = q0 + lax.broadcasted_iota(jnp.int32, (bq, block_kv), 0)

@@ -14,8 +14,8 @@ fusions.  This module fuses the whole tick into **one**
   ScoreContract's drift story depends on;
 * **masked row-min handoff detection** — the fresh masked row minimum
   falls out of the same streamed tiles (see below), and the handoff
-  count (#rows whose minimum moved) is accumulated into a scalar
-  output;
+  count (#rows whose minimum moved) is counted from the old and new
+  row minima in the same jitted call;
 * **accumulator score updates** — unchanged columns fold
   ``D = row_masks @ (norm_new - norm_old)`` into the standing
   accumulators; rows whose minimum did not move contribute *exact*
@@ -36,13 +36,15 @@ price vector, the row minima and the score accumulators.
 
 **Tiling.**  The grid is ``(2, C//block_c, J//block_j)``: phase 0
 sweeps the tiles accumulating the masked row minima of the *new* cost
-into a ``(J, 1)`` VMEM scratch; phase 1 recomputes both norms per tile
-and accumulates the two member matmuls (``S x block_j @ block_j x
-block_c``).  The j axis is innermost so each ``(S, block_c)`` output
-block sees its accumulation visits consecutively (the Pallas
-revisiting rule); with the default single C tile the input blocks keep
-their index across the phase boundary, so HBM streams ``hours``/
-``mask`` once per tick.  The member axis S rides whole in every block.
+into the resident ``(J, 1)`` row-minima output block; phase 1
+recomputes both norms per tile and accumulates the two member matmuls
+(``S x block_j @ block_j x block_c``, at float32 precision).  The j
+axis is innermost so each ``(S, block_c)`` output block sees its
+accumulation visits consecutively (the Pallas revisiting rule).  The
+member axis S rides whole in every block.  On the chip every block's
+last two dimensions must be multiples of (8, 128) or the whole array's
+(DESIGN.md §14), which is why the row masks travel as
+``(J/block_j, S, block_j)``.
 
 Like the other kernels in this package the Pallas body runs natively on
 TPU and under ``interpret=True`` on CPU; ``interpret`` is a *static*
@@ -66,25 +68,20 @@ from repro.kernels.ops import _interpret
 __all__ = ["fused_reprice", "fused_reprice_heads", "rank_delta_fns"]
 
 
-def _make_kernel(block_j: int, n_j_tiles: int, n_c_tiles: int,
-                 heads: Optional[int]):
+def _make_kernel(block_j: int, n_j_tiles: int, heads: Optional[int]):
     """The fused kernel body; ``heads=k`` adds the in-kernel top-k tail
     (requires a single C tile — the final scores must be resident)."""
 
     def kernel(hours_ref, mask_ref, oldp_ref, newp_ref, chg_ref,
                rb_in_ref, rm_ref, scores_in_ref, *refs):
         if heads is None:
-            scores_out_ref, rb_out_ref, moved_ref = refs[:3]
-            rb_scr, p_acc = refs[3:]
+            scores_out_ref, rb_out_ref, p_acc = refs
         else:
-            fin_ref = refs[0]
-            scores_out_ref, rb_out_ref, moved_ref = refs[1:4]
-            ti_ref, tv_ref = refs[4:6]
-            rb_scr, p_acc = refs[6:]
+            fin_ref, scores_out_ref, rb_out_ref, ti_ref, tv_ref, p_acc = refs
         p = pl.program_id(0)
         c = pl.program_id(1)
         j = pl.program_id(2)
-        jsl = pl.ds(j * block_j, block_j)
+        jsl = pl.ds(pl.multiple_of(j * block_j, block_j), block_j)
         hours = hours_ref[...]                        # (Jt, Ct)
         mask = mask_ref[...]
         # the new cost tile, recomputed in-stream: unchanged columns
@@ -93,39 +90,37 @@ def _make_kernel(block_j: int, n_j_tiles: int, n_c_tiles: int,
         # is needed to find the fresh masked row minima
         cost_new = jnp.where(mask, hours * newp_ref[...], jnp.inf)
 
-        @pl.when((p == 0) & (c == 0) & (j == 0))
-        def _init():
-            moved_ref[...] = jnp.zeros_like(moved_ref)
-
         @pl.when(p == 0)
         def _min_scan():
-            # phase 0: running masked row minima across the C tiles
+            # phase 0: running masked row minima across the C tiles,
+            # kept in the resident row_best output block
             tile_min = jnp.min(cost_new, axis=1, keepdims=True)
 
             @pl.when(c == 0)
             def _():
-                rb_scr[jsl, :] = tile_min
+                rb_out_ref[jsl, :] = tile_min
 
             @pl.when(c > 0)
             def _():
-                rb_scr[jsl, :] = jnp.minimum(rb_scr[jsl, :], tile_min)
+                rb_out_ref[jsl, :] = jnp.minimum(rb_out_ref[jsl, :],
+                                                 tile_min)
 
         @pl.when(p == 1)
         def _fold():
             # phase 1: both norms recomputed per tile, two member
-            # matmuls accumulated, handoffs counted — rb_scr is final
-            # (phase 0 swept every tile before phase 1 starts)
+            # matmuls accumulated — the row minima are final (phase 0
+            # swept every tile before phase 1 starts)
             cost_old = jnp.where(mask, hours * oldp_ref[...], jnp.inf)
-            rb_old = rb_in_ref[jsl, :]                # (Jt, 1)
-            fresh = rb_scr[jsl, :]
-            norm_old = jnp.where(mask, cost_old / rb_old, 0.0)
-            norm_new = jnp.where(mask, cost_new / fresh, 0.0)
-            rm = rm_ref[...]                          # (S, Jt)
+            norm_old = jnp.where(mask, cost_old / rb_in_ref[jsl, :], 0.0)
+            norm_new = jnp.where(mask, cost_new / rb_out_ref[jsl, :], 0.0)
+            rm = rm_ref[0]                            # (S, Jt)
             dims = (((1,), (0,)), ((), ()))
             re_reduce = jax.lax.dot_general(
-                rm, norm_new, dims, preferred_element_type=jnp.float32)
+                rm, norm_new, dims, precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
             delta = jax.lax.dot_general(
                 rm, norm_new - norm_old, dims,
+                precision=jax.lax.Precision.HIGHEST,
                 preferred_element_type=jnp.float32)
 
             @pl.when(j == 0)
@@ -138,39 +133,46 @@ def _make_kernel(block_j: int, n_j_tiles: int, n_c_tiles: int,
                 scores_out_ref[...] += delta
                 p_acc[...] += re_reduce
 
-            @pl.when(c == 0)
-            def _():
-                # handoff detection + the fresh minima, once per j tile
-                rb_out_ref[jsl, :] = fresh
-                moved_ref[0, 0] += jnp.sum(
-                    (fresh != rb_old).astype(jnp.int32))
-
             @pl.when(j == n_j_tiles - 1)
             def _combine():
                 # changed columns: re-set from the scratch re-reduction;
                 # unchanged: fold the (exact-zero-for-unmoved-rows)
                 # delta into the standing accumulators
                 chg = chg_ref[...] > 0                # (1, Ct)
-                scores_out_ref[...] = jnp.where(
-                    chg, p_acc[...],
-                    scores_in_ref[...] + scores_out_ref[...])
+                scores = jnp.where(chg, p_acc[...],
+                                   scores_in_ref[...] + scores_out_ref[...])
+                scores_out_ref[...] = scores
                 if heads is not None:
-                    # the fused top-k tail: iterative masked argmin
-                    # over the just-finalized resident scores —
-                    # jnp.argmin's first-occurrence tie-break IS the
-                    # catalog-order tie-break of _materialize
-                    masked = jnp.where(fin_ref[...], scores_out_ref[...],
-                                       jnp.inf)
-                    cols2 = jax.lax.broadcasted_iota(
-                        jnp.int32, masked.shape, 1)
-                    for t in range(heads):
-                        tv_ref[:, t] = jnp.min(masked, axis=1)
-                        idx = jnp.argmin(masked, axis=1)
-                        ti_ref[:, t] = idx.astype(jnp.int32)
-                        masked = jnp.where(cols2 == idx[:, None],
-                                           jnp.inf, masked)
+                    ti_ref[...], tv_ref[...] = _heads(
+                        scores, fin_ref[...], heads)
 
     return kernel
+
+
+def _heads(scores, finite, k: int):
+    """The fused top-k tail: k rounds of a masked row minimum over the
+    just-finalized resident scores.  Each round takes the lowest column
+    holding the minimum among the columns not yet taken — the catalog-
+    order tie-break of ``_materialize`` and of ``lax.top_k``, unprofiled
+    (+inf) columns included.  Column positions ride as float32 (exact
+    below 2**24) so every reduction is a float lane minimum."""
+    S, C = scores.shape
+    masked = jnp.where(finite, scores, jnp.inf)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (S, C), 1).astype(
+        jnp.float32)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (S, k), 1)
+    taken = jnp.zeros((S, C), jnp.bool_)
+    ti = jnp.zeros((S, k), jnp.int32)
+    tv = jnp.zeros((S, k), jnp.float32)
+    for t in range(k):
+        live = jnp.where(taken, jnp.inf, masked)
+        best = jnp.min(live, axis=1, keepdims=True)           # (S, 1)
+        first = jnp.min(jnp.where((live == best) & ~taken, cols, C),
+                        axis=1, keepdims=True)                 # (S, 1)
+        ti = jnp.where(slot == t, first.astype(jnp.int32), ti)
+        tv = jnp.where(slot == t, best, tv)
+        taken = taken | (cols == first)
+    return ti, tv
 
 
 def _check_tiling(shape_j: int, shape_c: int, block_j: int,
@@ -194,10 +196,14 @@ def _fused_call(hours, mask, old_prices, new_prices, changed, row_best,
     if heads is not None and nc != 1:
         raise ValueError("the fused reprice+top-k variant needs the "
                          "final scores resident: use block_c == C")
-    kernel = _make_kernel(block_j, nj, nc, heads)
+    kernel = _make_kernel(block_j, nj, heads)
     vec = lambda p, c, j: (0, c)                     # (1, Ct) vectors
     tile = lambda p, c, j: (j, c)                    # (Jt, Ct) tiles
     whole = lambda p, c, j: (0, 0)                   # resident blocks
+    # the row masks as (J/Jt, S, Jt): each job tile's (S, Jt) slab is a
+    # whole trailing pair of dimensions, as the chip's (8, 128) block
+    # rule requires of a Jt narrower than 128 lanes
+    rm_tiles = row_masks.reshape(S, nj, block_j).transpose(1, 0, 2)
     in_specs = [
         pl.BlockSpec((block_j, block_c), tile),      # hours
         pl.BlockSpec((block_j, block_c), tile),      # mask
@@ -205,38 +211,40 @@ def _fused_call(hours, mask, old_prices, new_prices, changed, row_best,
         pl.BlockSpec((1, block_c), vec),             # new prices
         pl.BlockSpec((1, block_c), vec),             # changed columns
         pl.BlockSpec((J, 1), whole),                 # row_best in
-        pl.BlockSpec((S, block_j), lambda p, c, j: (0, j)),  # row masks
+        pl.BlockSpec((1, S, block_j),
+                     lambda p, c, j: (j, 0, 0)),     # row masks
         pl.BlockSpec((S, block_c), vec),             # scores in
     ]
     args = [hours, mask, old_prices, new_prices, changed, row_best,
-            row_masks, scores]
+            rm_tiles, scores]
     out_specs = [
         pl.BlockSpec((S, block_c), vec),             # scores out
         pl.BlockSpec((J, 1), whole),                 # row_best out
-        pl.BlockSpec((1, 1), whole),                 # handoff count
     ]
     out_shape = [
         jax.ShapeDtypeStruct((S, C), jnp.float32),
         jax.ShapeDtypeStruct((J, 1), jnp.float32),
-        jax.ShapeDtypeStruct((1, 1), jnp.int32),
     ]
     if heads is not None:
-        in_specs.insert(8, pl.BlockSpec((S, block_c), vec))  # finite
-        args.insert(8, finite)
+        in_specs.append(pl.BlockSpec((S, block_c), vec))  # finite
+        args.append(finite)
         out_specs += [pl.BlockSpec((S, heads), whole),
                       pl.BlockSpec((S, heads), whole)]
         out_shape += [jax.ShapeDtypeStruct((S, heads), jnp.int32),
                       jax.ShapeDtypeStruct((S, heads), jnp.float32)]
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=(2, nc, nj),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((J, 1), jnp.float32),
-                        pltpu.VMEM((S, block_c), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((S, block_c), jnp.float32)],
         interpret=interpret,
     )(*args)
+    # the handoff count: rows whose masked minimum moved (an all-+inf
+    # padded row compares equal to itself and never counts)
+    moved = jnp.sum(out[1] != row_best, dtype=jnp.int32).reshape(1, 1)
+    return (out[0], out[1], moved, *out[2:])
 
 
 def _reprice(hours, mask, old_prices, new_prices, changed, row_best,

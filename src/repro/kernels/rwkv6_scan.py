@@ -1,11 +1,13 @@
 """Pallas TPU kernel for the RWKV-6 (Finch) WKV recurrence.
 
 TPU adaptation: the recurrence is O(1)-state sequential in T, so the grid
-parallelises over (batch, head) and each program streams its time series
-through VMEM while the (N, N) state matrix stays resident in VMEM scratch
-— the same structure Mamba/linear-attention TPU kernels use.  N = 64
-(rwkv6) keeps the state tile MXU/VREG-friendly; the T-loop body is pure
-VPU elementwise + rank-1 updates.
+parallelises over (batch, head) and streams each time series through
+VMEM in ``chunk``-row blocks (the innermost, sequential grid axis) while
+the (N, N) state matrix stays resident in VMEM scratch — the same
+structure Mamba/linear-attention TPU kernels use.  Operands are laid out
+(B, H, T, N), as the flash kernel lays out its heads, so every block's
+last two dimensions are a (chunk, N) slab.  The T-loop body is pure VPU
+elementwise work + rank-1 updates.
 
     y_t = r_t^T (s_{t-1} + (u * k_t) outer v_t)
     s_t = diag(w_t) s_{t-1} + k_t outer v_t
@@ -20,48 +22,60 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref, *,
-            chunk: int):
-    """One (b, h) stream.  r/k/v/w refs: (1, T, 1, N); u: (1, N);
-    s0/sT: (1, 1, N, N); y: (1, T, 1, N)."""
-    T, N = r_ref.shape[1], r_ref.shape[3]
-    # index the loaded arrays, not the refs: scalar-int ref indices are
-    # unsupported by interpret-mode discharge in this pallas version
-    u = u_ref[...][0].astype(jnp.float32)                # (N,)
-    s = s0_ref[...][0, 0].astype(jnp.float32)            # (N, N) rows=k, cols=v
+_ROWS = 8   # time steps per aligned f32 tile (the sublane height)
 
-    nchunks = T // chunk
 
-    def chunk_body(c, s):
-        t0 = c * chunk
-        def tchunk(ref):
-            return pl.load(ref, (pl.dslice(0, 1), pl.dslice(t0, chunk),
-                                 pl.dslice(0, 1), slice(None))
-                           )[0, :, 0].astype(jnp.float32)
+def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref,
+            s_scr, x_scr, y_scr, *, chunk: int):
+    """One (b, h, time-chunk) program.  r/k/v/w/y refs: (1, 1, chunk, N);
+    u: (1, 1, N); s0/sT: (1, 1, N, N); s_scr: the (N, N) running state,
+    rows = k index, cols = v index; x_scr/y_scr: the chunk's inputs and
+    outputs in float32."""
+    c = pl.program_id(2)
+    N = r_ref.shape[3]
 
-        r, k, v, w = tchunk(r_ref), tchunk(k_ref), tchunk(v_ref), \
-            tchunk(w_ref)
+    @pl.when(c == 0)
+    def _():
+        s_scr[...] = s0_ref[0, 0].astype(jnp.float32)
 
-        def step(t, carry):
-            s, ys = carry
-            rt, kt, vt, wt = r[t], k[t], v[t], w[t]      # (N,)
-            kv = kt[:, None] * vt[None, :]               # (N, N)
-            y = ((s + u[:, None] * kv) * rt[:, None]).sum(axis=0)
-            s = wt[:, None] * s + kv
-            ys = ys.at[t].set(y)
-            return s, ys
+    eye = (lax.broadcasted_iota(jnp.int32, (N, N), 0)
+           == lax.broadcasted_iota(jnp.int32, (N, N), 1))
+    tile_row = lax.broadcasted_iota(jnp.int32, (_ROWS, N), 0)
 
-        ys0 = jnp.zeros((chunk, N), jnp.float32)
-        s, ys = lax.fori_loop(0, chunk, step, (s, ys0))
-        pl.store(y_ref, (pl.dslice(0, 1), pl.dslice(t0, chunk),
-                         pl.dslice(0, 1), slice(None)),
-                 ys.astype(y_ref.dtype)[None, :, None])
+    def col(row):
+        # (1, N) row -> (N, 1) column through a diagonal mask and a lane
+        # reduction (exact: every other term is 0.0)
+        return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+    u = col(u_ref[0].astype(jnp.float32))
+    for i, ref in enumerate((r_ref, k_ref, v_ref, w_ref)):
+        x_scr[i] = ref[0, 0].astype(jnp.float32)
+
+    def tile(g, s):
+        # one aligned (8, N) tile of time steps per iteration; the steps
+        # inside it are unrolled over static row slices
+        rows = pl.ds(pl.multiple_of(g * _ROWS, _ROWS), _ROWS)
+        r, k, v, w = (x_scr[i, rows, :] for i in range(4))
+        ys = jnp.zeros((_ROWS, N), jnp.float32)
+        for t in range(_ROWS):
+            kv = col(k[t:t + 1]) * v[t:t + 1]            # (N, N)
+            y = jnp.sum((s + u * kv) * col(r[t:t + 1]), axis=0,
+                        keepdims=True)
+            ys = jnp.where(tile_row == t, y, ys)
+            s = col(w[t:t + 1]) * s + kv
+        y_scr[rows, :] = ys
         return s
 
-    s = lax.fori_loop(0, nchunks, chunk_body, s)
-    sT_ref[...] = s.astype(sT_ref.dtype)[None, None]
+    s = lax.fori_loop(0, chunk // _ROWS, tile, s_scr[...])
+    s_scr[...] = s
+    y_ref[0, 0] = y_scr[...].astype(y_ref.dtype)
+
+    @pl.when(c == pl.num_programs(2) - 1)
+    def _():
+        sT_ref[0, 0] = s.astype(sT_ref.dtype)
 
 
 def wkv6_pallas(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
@@ -70,19 +84,23 @@ def wkv6_pallas(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
     """r,k,v,w: (B,T,H,N); u: (H,N); s0: (B,H,N,N) -> (y, s_T)."""
     B, T, H, N = r.shape
     chunk = min(chunk, T)
-    assert T % chunk == 0, (T, chunk)
-    grid = (B, H)
-    io_spec = pl.BlockSpec((1, T, 1, N), lambda b, h: (b, 0, h, 0))
+    assert T % chunk == 0 and chunk % _ROWS == 0, (T, chunk)
+    # layout: put head next to batch so each block is a (chunk, N) slab
+    r, k, v, w = (x.transpose(0, 2, 1, 3) for x in (r, k, v, w))
+    io_spec = pl.BlockSpec((1, 1, chunk, N), lambda b, h, c: (b, h, c, 0))
+    state_spec = pl.BlockSpec((1, 1, N, N), lambda b, h, c: (b, h, 0, 0))
     y, sT = pl.pallas_call(
         functools.partial(_kernel, chunk=chunk),
-        grid=grid,
+        grid=(B, H, T // chunk),
         in_specs=[io_spec, io_spec, io_spec, io_spec,
-                  pl.BlockSpec((1, N), lambda b, h: (h, 0)),
-                  pl.BlockSpec((1, 1, N, N), lambda b, h: (b, h, 0, 0))],
-        out_specs=[io_spec,
-                   pl.BlockSpec((1, 1, N, N), lambda b, h: (b, h, 0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((B, T, H, N), r.dtype),
+                  pl.BlockSpec((1, 1, N), lambda b, h, c: (h, 0, 0)),
+                  state_spec],
+        out_specs=[io_spec, state_spec],
+        out_shape=[jax.ShapeDtypeStruct((B, H, T, N), r.dtype),
                    jax.ShapeDtypeStruct((B, H, N, N), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((N, N), jnp.float32),
+                        pltpu.VMEM((4, chunk, N), jnp.float32),
+                        pltpu.VMEM((chunk, N), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, w, u, s0)
-    return y, sT
+    )(r, k, v, w, u.reshape(H, 1, N), s0)
+    return y.transpose(0, 2, 1, 3), sT

@@ -1,11 +1,8 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
-# --- everything below this line may import jax -------------------------------
 import argparse
 import dataclasses
 import json
 import math
+import os
 import time
 import traceback
 from typing import Any, Dict, Optional
@@ -24,6 +21,17 @@ from repro.models.types import param_shapes
 from repro.sharding import rules as rules_lib
 from repro.sharding import ctx as ctx_lib
 from repro.train.train_loop import TrainConfig, make_train_step
+
+#: the host devices a dry-run lowers its meshes over
+HOST_DEVICES = 512
+
+
+def force_host_devices(n: int = HOST_DEVICES) -> None:
+    """Give the CPU backend ``n`` devices for the dry-run meshes.  XLA
+    reads the flag when the backend starts, so call this before anything
+    touches a device, from the entry point, in a process of its own."""
+    os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
+
 
 # per-arch training memory policy: bf16 moments for the 400B-class config
 TRAIN_CFGS: Dict[str, TrainConfig] = {
@@ -247,6 +255,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
 
 
 def main() -> None:
+    force_host_devices()
     ap = argparse.ArgumentParser(description="multi-pod dry-run")
     ap.add_argument("--arch", default="all")
     ap.add_argument("--shape", default="all")

@@ -23,6 +23,7 @@ from repro.configs import shapes as shapes_lib
 from repro.core.costmodel import TpuPriceModel
 from repro.core.tpu_flora import service_from_dryrun_report
 from repro.data import pipeline as data_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model, count_params
 from repro.models.types import ShapeSpec
 from repro.train.checkpoint import Checkpointer
@@ -61,6 +62,7 @@ def main() -> None:
                     choices=["ondemand", "spot"])
     ap.add_argument("--microbatches", type=int, default=1)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.auto_mesh and os.path.exists(args.report):
         select_mesh(args.report, args.market)

@@ -62,6 +62,7 @@ if _HAVE_JAX:
     import jax.numpy as jnp
 
     from repro.kernels.rank_delta import fused_reprice, fused_reprice_heads
+    from repro.selector.rank import _fleet_matmul
 
 __all__ = ["PallasBatchedRankState"]
 
@@ -89,7 +90,7 @@ if _HAVE_JAX:
                         # kernel recomputes it in-stream
                         norm = jnp.where(mask, (hours * prices) / row_best,
                                          0.0)
-                        return row_mask @ norm
+                        return _fleet_matmul(row_mask, norm)
 
                     _HELPER_FNS = (jax.jit(cold_row_best),
                                    jax.jit(member_scores))

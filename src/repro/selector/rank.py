@@ -651,6 +651,13 @@ if _HAVE_JAX:
                              cost[:, cols] / row_best[:, None], 0.0)
         return prices, cost, row_best, fresh_rows, moved, col_norm
 
+    def _fleet_matmul(a, b):
+        """The member-axis reductions of every fleet step, at float32
+        precision: a TPU's default float32 matmul rounds its operands to
+        bfloat16 (8 significand bits), far outside the jax
+        ScoreContract's 1e-4."""
+        return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
     def _jax_topk_fn() -> Any:
         """``topk(scores, finite, k)`` — ``jax.lax.top_k`` over the
         (possibly batched) score buffer with unprofiled configs masked
@@ -925,18 +932,19 @@ if _HAVE_JAX:
             #    J×C; rows that did not move contribute exact zeros, so
             #    a tick with no handoffs is drift-free here)
             row_delta = jnp.where(moved[:, None], fresh_rows - norm, 0.0)
-            scores = scores + row_masks @ row_delta
+            scores = scores + _fleet_matmul(row_masks, row_delta)
             norm = jnp.where(moved[:, None], fresh_rows, norm)
             # -- changed columns: re-reduce every member from scratch
             #    with a .set — idempotent under the duplicate indices
             #    the power-of-4 bucket padding introduces
             norm = norm.at[:, cols].set(col_norm)
-            scores = scores.at[:, cols].set(row_masks @ col_norm)
+            scores = scores.at[:, cols].set(_fleet_matmul(row_masks,
+                                                          col_norm))
             return prices, cost, row_best, norm, scores, moved.sum()
 
         def member_scores(norm, row_mask):
             # a new member's accumulators from the current shared norm
-            return row_mask @ norm
+            return _fleet_matmul(row_mask, norm)
 
         donate = () if jax.default_backend() == "cpu" else (0, 1, 2, 3, 4)
         _JAX_BATCHED_FNS = (jax.jit(step, donate_argnums=donate),

@@ -2,7 +2,7 @@
 
 :class:`ShardedBatchedRankState` is :class:`~repro.selector.rank.
 BatchedRankState` with the config (C) axis sharded across a 1-D device
-mesh via ``jax.experimental.shard_map`` (DESIGN.md §13).  Catalogs of
+mesh via ``jax.shard_map`` (DESIGN.md §13).  Catalogs of
 100k+ configs (multi-region × multi-cloud × spot/on-demand) no longer
 need to fit one device: every C-extent buffer — hours, mask, cost,
 normalized cost, prices, and the S×C member score accumulators — lives
@@ -59,8 +59,9 @@ from .rank import (SCORE_CONTRACTS, BackendUnavailableError,
 if _HAVE_JAX:
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from .rank import _fleet_matmul
 
 #: span names the sharded tick emits when a MetricsRegistry is wired in
 STEP_SPAN = "shard.step"
@@ -145,33 +146,33 @@ def _build_sharded_fns(n_dev: int) -> Tuple[Any, Any, Any]:
         col_norm = jnp.where(sub_mask,
                              cost[:, cols] / row_best[:, None], 0.0)
         row_delta = jnp.where(moved[:, None], fresh_rows - norm, 0.0)
-        scores = scores + row_masks @ row_delta
+        scores = scores + _fleet_matmul(row_masks, row_delta)
         norm = jnp.where(moved[:, None], fresh_rows, norm)
         norm = norm.at[:, cols].set(col_norm)
-        scores = scores.at[:, cols].set(row_masks @ col_norm)
+        scores = scores.at[:, cols].set(_fleet_matmul(row_masks, col_norm))
         return prices, cost, row_best, norm, scores, moved.sum()
 
     def member_local(norm, row_mask):
         # a new member's accumulators from the current shared norm
-        return row_mask @ norm
+        return _fleet_matmul(row_mask, norm)
 
     donate = () if jax.default_backend() == "cpu" else (0, 1, 2, 3, 4)
-    cold = jax.jit(shard_map(
+    cold = jax.jit(jax.shard_map(
         cold_local, mesh=mesh,
         in_specs=(spec_c, spec_c, spec_v),
         out_specs=(spec_c, spec_r, spec_c),
-        check_rep=False))
-    step = jax.jit(shard_map(
+        check_vma=False))
+    step = jax.jit(jax.shard_map(
         step_local, mesh=mesh,
         in_specs=(spec_v, spec_c, spec_r, spec_c, spec_c, spec_c,
                   spec_c, spec_r, P("c", None), P("c", None)),
         out_specs=(spec_v, spec_c, spec_r, spec_c, spec_c, spec_r),
-        check_rep=False), donate_argnums=donate)
-    member = jax.jit(shard_map(
+        check_vma=False), donate_argnums=donate)
+    member = jax.jit(jax.shard_map(
         member_local, mesh=mesh,
         in_specs=(spec_c, spec_r),
         out_specs=spec_v,
-        check_rep=False))
+        check_vma=False))
     _FNS[n_dev] = (cold, step, member)
     return _FNS[n_dev]
 
@@ -210,11 +211,11 @@ def _build_sharded_topk_fn(key: Tuple[int, int, int]) -> Any:
         gidx = jax.lax.axis_index("c") * c_loc + idx
         return gidx, -neg
 
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         topk_local, mesh=mesh,
         in_specs=(P(None, "c"), P(None, "c"), P()),
         out_specs=(P("c"), P("c")),
-        check_rep=False))
+        check_vma=False))
     _TOPK[key] = fn
     return fn
 

@@ -20,7 +20,6 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -86,8 +85,8 @@ def make_compressed_allreduce(mesh: Mesh, axis_names=("data",)):
 
         leaves, treedef = jax.tree_util.tree_flatten(grads_tree)
         specs = tuple(P() for _ in leaves)   # replicated view per leaf
-        fn = shard_map(per_shard, mesh=mesh, in_specs=specs,
-                       out_specs=specs, check_rep=False)
+        fn = jax.shard_map(per_shard, mesh=mesh, in_specs=specs,
+                           out_specs=specs, check_vma=False)
         out = fn(*leaves)
         return jax.tree_util.tree_unflatten(treedef, list(out))
     return allreduce

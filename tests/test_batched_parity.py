@@ -643,3 +643,48 @@ def test_batched_service_survives_out_of_band_table_apply():
     ref.reprice(deltas)
     assert_within_contract(list(svc.submit("j1").ranking),
                            list(ref.submit("j1").ranking), CONTRACT)
+
+
+# --- regression: the fleet matmuls ask for float32 precision ---------------
+
+def _fleet_programs():
+    """Every XLA fleet program with a member-axis matmul, lowered at a
+    tiny shape: (name, StableHLO text)."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.selector import pallas_rank, rank, sharded
+
+    J, C, S, B = 8, 16, 4, 8
+    f32, sd = jnp.float32, jax.ShapeDtypeStruct
+    universe = (sd((J, C), f32), sd((J, C), jnp.bool_))
+    step, member = rank._jax_batched_fns()
+    state = (sd((C,), f32), sd((J, C), f32), sd((J,), f32),
+             sd((J, C), f32), sd((S, C), f32))
+    yield "jax_batched step", step.lower(
+        *state, *universe, sd((S, J), f32), sd((B,), jnp.int32),
+        sd((B,), f32))
+    yield "jax_batched member", member.lower(sd((J, C), f32),
+                                             sd((J,), f32))
+    _, sh_step, sh_member = sharded._sharded_fns(1)
+    yield "jax_sharded step", sh_step.lower(
+        *state, *universe, sd((S, J), f32), sd((1, B), jnp.int32),
+        sd((1, B), f32))
+    yield "jax_sharded member", sh_member.lower(sd((J, C), f32),
+                                                sd((J,), f32))
+    yield "jax_pallas member", pallas_rank._helper_fns()[1].lower(
+        *universe, sd((1, C), f32), sd((J, 1), f32), sd((J,), f32))
+
+
+@pytest.mark.skipif(not backend_available("jax"), reason="jax missing")
+def test_fleet_matmuls_run_at_float32_precision():
+    """A TPU's default float32 matmul rounds its operands to bfloat16,
+    which alone breaks the 1e-4 ScoreContract on the chip: every fleet
+    matmul must request HIGHEST precision (the CPU cannot show the
+    rounding, so this pins the request itself)."""
+    for name, lowered in _fleet_programs():
+        dots = [line for line in lowered.as_text().splitlines()
+                if "dot_general" in line]
+        assert dots, name
+        assert all("precision = [HIGHEST, HIGHEST]" in d for d in dots), \
+            (name, dots)
