@@ -37,8 +37,6 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 import repro.configs as configs  # noqa: E402
-from repro.kernels import rank_delta  # noqa: E402
-from repro.kernels.ops import _interpret  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.market import (JournalReplayer, RecordedPriceFeed,  # noqa: E402
                           ServeFrontend, SimulatedSpotFeed, Submission,
@@ -49,7 +47,7 @@ from repro.selector import (BatchedRankState, IdentityCatalog,  # noqa: E402
                             PallasBatchedRankState, PriceTable,
                             SelectionService, rank_dense, score_contract)
 from repro.selector.sharded import ShardedBatchedRankState  # noqa: E402
-from repro.serve.engine import Engine, Request  # noqa: E402
+from repro.serve.engine import Engine, Request, serving_params  # noqa: E402
 
 from market_bench import _delta_batches, _fleet_members, _universe  # noqa: E402
 from serve_frontend import build_universe  # noqa: E402
@@ -103,23 +101,31 @@ def _check_head(label, head, cold, contract, k) -> None:
 
 
 def _lowered_kernels(state: PallasBatchedRankState, k: int) -> bool:
-    """Do both fused programs, as the state dispatches them here, lower
-    to a Mosaic kernel (``tpu_custom_call``) rather than the interpreter?"""
-    zeros = jnp.zeros_like(state.d_prices)
-    common = (state.d_hours, state.d_mask, state.d_prices, state.d_prices,
-              zeros, state.d_row_best, state.d_row_masks, state.d_scores)
-    tiles = dict(block_j=state._block_j, block_c=state._block_c,
-                 interpret=_interpret())
-    reprice, heads = rank_delta.rank_delta_fns()
-    texts = [reprice.lower(*common, **tiles).as_text(),
-             heads.lower(*common, state._d_finite, k=k, **tiles).as_text()]
+    """Do both fused programs, lowered from the state's own tick dispatch
+    (a no-change tick), hold a Mosaic kernel (``tpu_custom_call``) rather
+    than the interpreter?"""
+    unchanged = np.zeros_like(state._host_prices)
+    texts = []
+    for heads in (None, k):
+        fn, args, tiles = state._tick_call(state._host_prices, unchanged,
+                                           heads)
+        texts.append(fn.lower(*args, **tiles).as_text())
     return all("tpu_custom_call" in t for t in texts)
+
+
+def require_kernel(result: dict) -> dict:
+    """The selector fleet's result, provided its Pallas programs were
+    Mosaic kernels: on the chip nothing may fall back to the
+    interpreter."""
+    check(result["pallas_program"] == "tpu_custom_call",
+          "jax_pallas lowered to the interpreter, not a Mosaic kernel")
+    return result
 
 
 def phase_selector_fleet(n_jobs: int = 64, n_cfgs: int = 10_000,
                          n_members: int = 8, n_ticks: int = 32,
-                         frac: float = 0.01, k: int = 5, seed: int = 0,
-                         expect_kernel: bool = True) -> dict:
+                         frac: float = 0.01, k: int = 5, seed: int = 0
+                         ) -> dict:
     """Reprice a fleet of live member selections through
     ``BatchedRankState`` (jax_batched) and ``PallasBatchedRankState``
     (jax_pallas: ``reprice`` + ``top_k`` on odd ticks,
@@ -134,8 +140,6 @@ def phase_selector_fleet(n_jobs: int = 64, n_cfgs: int = 10_000,
         batched.add_state(key, rows=rows)
         pallas.add_state(key, rows=rows)
     kernel = _lowered_kernels(pallas, k)
-    check(kernel or not expect_kernel,
-          "jax_pallas lowered to the interpreter, not a Mosaic kernel")
     pos = {c: i for i, c in enumerate(ids)}
     live = prices.copy()
     for t, batch in enumerate(batches):
@@ -165,16 +169,17 @@ def phase_selector_fleet(n_jobs: int = 64, n_cfgs: int = 10_000,
 # --- the served path: ServeFrontend on jax_batched, journal audit -----------
 
 def phase_served_path(n_subs: int = 300, n_ticks: int = 40, workers: int = 2,
-                      seed: int = 42, backend: str = "jax_batched") -> dict:
-    """A threaded ``ServeFrontend`` (tick thread + ``workers`` snapshot
-    workers) over a recorded ``SimulatedSpotFeed``, built as
+                      seed: int = 42) -> dict:
+    """A threaded ``ServeFrontend`` on ``jax_batched`` (tick thread +
+    ``workers`` snapshot workers) over a recorded ``SimulatedSpotFeed``,
+    built as
     ``examples/serve_frontend.py`` builds it, closed cleanly, and its
     merged journal audited cold in tolerance mode."""
     store, ids, base = build_universe()
     feed = RecordedPriceFeed.loads(record_feed(
         SimulatedSpotFeed(base, seed=seed, change_fraction=0.5), n_ticks))
     service = SelectionService(IdentityCatalog(ids), store,
-                               PriceTable(base), backend=backend,
+                               PriceTable(base), backend="jax_batched",
                                serve_top_k=3)
     selections = [("j1", None), ("j2", None), ("j3", None),
                   ("j4", None), ("j1", ("g2", "g3")), ("j2", ("g1",))]
@@ -194,10 +199,10 @@ def phase_served_path(n_subs: int = 300, n_ticks: int = 40, workers: int = 2,
     check(stats.accounted and stats.decisions + stats.rejected == n_subs,
           f"accounting: {stats}")
     check(stats.ticks == n_ticks, f"{stats.ticks}/{n_ticks} ticks played")
-    check(audit.contract == score_contract(backend),
-          f"audited under {audit.contract}, served on {backend}")
+    check(audit.contract == score_contract("jax_batched"),
+          f"audited under {audit.contract}, served on jax_batched")
     check(audit.ok, f"journal audit mismatches: {audit.mismatches[:3]}")
-    return {"backend": backend, "workers": workers,
+    return {"backend": "jax_batched", "workers": workers,
             "decisions": stats.decisions, "rejected": stats.rejected,
             "ticks": stats.ticks, "audit": "clean",
             "drift_records": len(audit.drift),
@@ -216,9 +221,7 @@ def phase_lm_serving(cfg: ModelConfig, n_requests: int = 8, slots: int = 4,
     decode step's logits must match a full forward over the same tokens
     within :data:`LM_PARITY_REL_L2`."""
     model = build_model(cfg)
-    params = jax.jit(lambda key: jax.tree.map(
-        lambda x: x.astype(cfg.compute_dtype), model.init(key)))(
-            jax.random.PRNGKey(seed))
+    params = serving_params(model, seed)
     n_params = sum(x.size for x in jax.tree.leaves(params))
     eng = Engine(model, params, slots=slots, max_len=max_len)
     keys = jax.random.split(jax.random.PRNGKey(seed + 1), n_requests)
@@ -361,7 +364,8 @@ def main(argv=None) -> int:
     if args.chips == 4:
         phases = [("sharded_fleet", phase_sharded_fleet)]
     else:
-        phases = [("selector_fleet", phase_selector_fleet),
+        phases = [("selector_fleet",
+                   lambda: require_kernel(phase_selector_fleet())),
                   ("served_path", phase_served_path),
                   ("lm_serving",
                    lambda: phase_lm_serving(configs.get("qwen3-1.7b")))]

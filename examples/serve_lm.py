@@ -23,7 +23,8 @@ from repro.core.costmodel import TpuPriceModel
 from repro.core.tpu_flora import service_from_dryrun_report
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model, count_params
-from repro.serve.engine import Engine, Request, plan_decode_placement
+from repro.serve.engine import (Engine, Request, plan_decode_placement,
+                                serving_params)
 
 
 def main() -> None:
@@ -57,9 +58,7 @@ def main() -> None:
     if args.reduced:
         cfg = configs.reduced(cfg)
     model = build_model(cfg)
-    params = jax.jit(lambda key: jax.tree.map(
-        lambda x: x.astype(cfg.compute_dtype), model.init(key)))(
-            jax.random.PRNGKey(0))
+    params = serving_params(model)
     print(f"[serve] {cfg.name}{' (reduced)' if args.reduced else ''}: "
           f"{count_params(model.param_specs())/1e6:.1f}M params, "
           f"{args.slots} decode slots")

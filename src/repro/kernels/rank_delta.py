@@ -65,7 +65,30 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ops import _interpret
 
-__all__ = ["fused_reprice", "fused_reprice_heads", "rank_delta_fns"]
+__all__ = ["VMEM_LIMIT_BYTES", "fused_reprice", "fused_reprice_heads",
+           "fused_vmem_bytes", "rank_delta_fns"]
+
+#: the scoped VMEM a Mosaic kernel may hold on a TPU v5e by default
+VMEM_LIMIT_BYTES = 16 * 2**20
+
+
+def fused_vmem_bytes(members: int, jobs: int, block_c: int,
+                     heads: bool) -> int:
+    """Estimated VMEM of one fused call at 8-row job tiles, in bytes.
+
+    Per 128-lane column group of a ``block_c`` tile the call holds about
+    4 float32 rows per member (the member scores in, out and in scratch)
+    and 28 rows of job-tile operands and temporaries; with the top-k
+    tail, 9 rows per member and 12.  The ``(J, 1)`` row minima (in and
+    out) and the ``(S, 8)`` row-mask block pad every row to 128 lanes.
+    The coefficients are fitted to 74 verdicts of the v5e compiler on
+    chip-less compiles (S = 8..128, C = 1,000..80,000, J = 64 and
+    1,024); they reproduce every one, each at least 1.8 MiB from the
+    limit."""
+    lanes = -(-block_c // 128) * 128
+    sublanes = -(-members // 8) * 8
+    rows = 9 * sublanes + 12 if heads else 4 * sublanes + 28
+    return 4 * (rows * lanes + 128 * (2 * jobs + 2 * sublanes))
 
 
 def _make_kernel(block_j: int, n_j_tiles: int, heads: Optional[int]):

@@ -22,15 +22,11 @@ from repro.sharding import rules as rules_lib
 from repro.sharding import ctx as ctx_lib
 from repro.train.train_loop import TrainConfig, make_train_step
 
-#: the host devices a dry-run lowers its meshes over
-HOST_DEVICES = 512
-
-
-def force_host_devices(n: int = HOST_DEVICES) -> None:
-    """Give the CPU backend ``n`` devices for the dry-run meshes.  XLA
+def force_host_devices() -> None:
+    """Give the CPU backend the 512 devices the dry-run meshes span.  XLA
     reads the flag when the backend starts, so call this before anything
     touches a device, from the entry point, in a process of its own."""
-    os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 
 # per-arch training memory policy: bf16 moments for the 400B-class config

@@ -61,7 +61,9 @@ if _HAVE_JAX:
     import jax
     import jax.numpy as jnp
 
-    from repro.kernels.rank_delta import fused_reprice, fused_reprice_heads
+    from repro.kernels.ops import _interpret
+    from repro.kernels.rank_delta import (VMEM_LIMIT_BYTES, fused_vmem_bytes,
+                                          rank_delta_fns)
     from repro.selector.rank import _fleet_matmul
 
 __all__ = ["PallasBatchedRankState"]
@@ -107,6 +109,12 @@ class PallasBatchedRankState(BatchedRankState):
     module docstring.  ``block_j``/``block_c`` pick the kernel tiling
     (defaults: 8-row job tiles, a single C tile); the job axis is
     padded to a ``block_j`` multiple with masked-off rows.
+
+    A fused tick holds its member-by-``block_c`` tiles in the chip's
+    VMEM (:func:`~repro.kernels.rank_delta.fused_vmem_bytes`): building
+    the state, growing its member capacity and a fused top-k tick each
+    raise ``ValueError`` naming the limit when the tiles would not fit,
+    rather than leaving the chip's compiler to refuse the tick.
 
     **Contract** (:data:`SCORE_CONTRACTS` ``["jax_pallas"]``): the jax
     float32 tolerance envelope.  The fused kernel's changed-column
@@ -175,6 +183,7 @@ class PallasBatchedRankState(BatchedRankState):
         # the member axis: slot tables + batched accumulators (the
         # inherited add/retire/grow machinery manages these)
         cap = self._CAPACITY_BASE if capacity is None else max(1, capacity)
+        self._check_vmem(cap, heads=False)
         self._capacity = cap
         self._slots: "dict[Hashable, int]" = {}
         self._retired: "set" = set()
@@ -191,6 +200,20 @@ class PallasBatchedRankState(BatchedRankState):
         self._ranking_memo: "dict[Hashable, Tuple[int, List[RankedConfig]]]" = {}
 
     # -- member management (only the pieces the padding touches) ------------
+    def _check_vmem(self, capacity: int, heads: bool) -> None:
+        need = fused_vmem_bytes(capacity, self._n_jobs, self._block_c, heads)
+        if need > VMEM_LIMIT_BYTES:
+            raise ValueError(
+                f"jax_pallas: a fused {'top-k ' if heads else ''}tick over "
+                f"{capacity} member slots x {self._block_c}-config tiles "
+                f"needs ~{need / 2**20:.1f} MiB of VMEM, over the chip's "
+                f"{VMEM_LIMIT_BYTES / 2**20:.0f} MiB; serve fewer members "
+                f"or a smaller catalog on this backend, or use jax_batched")
+
+    def _grow(self) -> None:
+        self._check_vmem(self._capacity * 2, heads=False)
+        super()._grow()
+
     def _rows_of(self, rows, jobs) -> np.ndarray:
         if (rows is None) == (jobs is None):
             raise ValueError("pass exactly one of rows= or jobs=")
@@ -261,6 +284,21 @@ class PallasBatchedRankState(BatchedRankState):
         changed[0, cols] = 1.0
         return newp, changed
 
+    def _tick_call(self, newp: np.ndarray, changed: np.ndarray,
+                   k: Optional[int] = None):
+        """The fused tick as :meth:`reprice` (``k=None``) and
+        :meth:`reprice_with_heads` dispatch it: ``(jitted kernel entry,
+        arguments, static keywords)``, so it can also be lowered as is."""
+        reprice, heads = rank_delta_fns()
+        args = (self.d_hours, self.d_mask, self.d_prices, jnp.asarray(newp),
+                jnp.asarray(changed), self.d_row_best, self.d_row_masks,
+                self.d_scores)
+        tiles = dict(block_j=self._block_j, block_c=self._block_c,
+                     interpret=_interpret())
+        if k is None:
+            return reprice, args, tiles
+        return heads, args + (self._d_finite,), dict(tiles, k=k)
+
     def reprice(self, deltas: Union[Mapping[Hashable, float],
                                     Sequence[Tuple[Hashable, float]]]
                 ) -> int:
@@ -271,14 +309,10 @@ class PallasBatchedRankState(BatchedRankState):
         dense = self._dense_tick(deltas)
         if dense is None:
             return 0
-        newp, changed = dense
-        d_newp = jnp.asarray(newp)
-        self.d_scores, self.d_row_best, moved = fused_reprice(
-            self.d_hours, self.d_mask, self.d_prices, d_newp,
-            jnp.asarray(changed), self.d_row_best, self.d_row_masks,
-            self.d_scores, block_j=self._block_j, block_c=self._block_c)
-        self.d_prices = d_newp
-        self._host_prices = newp
+        fn, args, tiles = self._tick_call(*dense)
+        self.d_scores, self.d_row_best, moved = fn(*args, **tiles)
+        self.d_prices = args[3]
+        self._host_prices = dense[0]
         self.reprices += 1
         self.dispatches += 1
         return int(np.asarray(moved)[0, 0])
@@ -298,16 +332,11 @@ class PallasBatchedRankState(BatchedRankState):
         dense = self._dense_tick(deltas)
         if dense is None:
             return 0, {key: self.top_k(key, k) for key in self._slots}
-        newp, changed = dense
-        d_newp = jnp.asarray(newp)
-        (self.d_scores, self.d_row_best, moved,
-         ti, tv) = fused_reprice_heads(
-            self.d_hours, self.d_mask, self.d_prices, d_newp,
-            jnp.asarray(changed), self.d_row_best, self.d_row_masks,
-            self.d_scores, self._d_finite, block_j=self._block_j,
-            block_c=self._block_c, k=k)
-        self.d_prices = d_newp
-        self._host_prices = newp
+        self._check_vmem(self._capacity, heads=True)
+        fn, args, tiles = self._tick_call(*dense, k=k)
+        self.d_scores, self.d_row_best, moved, ti, tv = fn(*args, **tiles)
+        self.d_prices = args[3]
+        self._host_prices = dense[0]
         self.reprices += 1
         self.dispatches += 1
         ti_h = np.asarray(ti)

@@ -80,6 +80,15 @@ def plan_decode_placement(service: SelectionService,
         hourly_cost=current_rate)
 
 
+def serving_params(model, seed: int = 0):
+    """The model's weights for serving, made from ``seed``: initialised
+    on the device in one jitted call and cast to the config's compute
+    dtype, so a full-width model never holds float32 weights at once."""
+    dtype = model.cfg.compute_dtype
+    return jax.jit(lambda key: jax.tree.map(
+        lambda x: x.astype(dtype), model.init(key)))(jax.random.PRNGKey(seed))
+
+
 @dataclasses.dataclass
 class Request:
     uid: int

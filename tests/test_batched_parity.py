@@ -688,3 +688,22 @@ def test_fleet_matmuls_run_at_float32_precision():
         assert dots, name
         assert all("precision = [HIGHEST, HIGHEST]" in d for d in dots), \
             (name, dots)
+
+
+@pytest.mark.skipif(not backend_available("jax"), reason="jax missing")
+def test_fleet_precision_probe_runs_small(monkeypatch):
+    """``benchmarks/fleet_precision.py`` measures on the chip what
+    DEFAULT precision does to the fleet's scores.  At a tiny size on the
+    CPU, where both precisions multiply in float32, it reports every
+    cell inside the contract."""
+    import pathlib
+
+    monkeypatch.syspath_prepend(
+        str(pathlib.Path(__file__).resolve().parents[1] / "benchmarks"))
+    from fleet_precision import fleet_precision
+
+    out = fleet_precision(n_jobs=16, n_cfgs=256, n_members=3, n_ticks=4,
+                          frac=0.05)
+    assert sorted(out) == ["DEFAULT", "HIGHEST"]
+    for worst, n_bad in out.values():
+        assert n_bad == 0 and worst < 1e-6

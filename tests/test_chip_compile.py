@@ -59,21 +59,42 @@ def _assert_kernel(compiled):
 J, C, S, K = 64, 10_000, 8, 5
 
 
-@pytest.mark.parametrize("variant", ["reprice", "reprice_heads"])
-def test_fused_reprice_compiles_for_v5e(shape, variant):
+def _lower_fused(shape, variant, members):
+    """The fused tick at the state's default tiling (8-row job tiles, the
+    whole catalog per tile) over ``members`` slots."""
     f32 = jnp.float32
     args = (shape((J, C), f32), shape((J, C), jnp.bool_),
             shape((1, C), f32), shape((1, C), f32), shape((1, C), f32),
-            shape((J, 1), f32), shape((S, J), f32), shape((S, C), f32))
-    # the state's defaults: 8-row job tiles, the whole catalog per tile
+            shape((J, 1), f32), shape((members, J), f32),
+            shape((members, C), f32))
     tiles = dict(block_j=8, block_c=C, interpret=False)
     reprice, heads = rank_delta.rank_delta_fns()
     if variant == "reprice":
-        lowered = reprice.lower(*args, **tiles)
+        return reprice.lower(*args, **tiles)
+    return heads.lower(*args, shape((members, C), jnp.bool_), k=K, **tiles)
+
+
+@pytest.mark.parametrize("variant", ["reprice", "reprice_heads"])
+def test_fused_reprice_compiles_for_v5e(shape, variant):
+    _assert_kernel(_lower_fused(shape, variant, S).compile())
+
+
+@pytest.mark.parametrize("members", [16, 32, 64])
+@pytest.mark.parametrize("variant", ["reprice", "reprice_heads"])
+def test_fused_reprice_vmem_verdict_at_grown_capacity(shape, variant,
+                                                      members):
+    """A fleet doubles its member slots from 8 as it grows.  At each
+    capacity it reaches, the state's VMEM estimate and the chip's
+    compiler agree: what the estimate admits compiles to a Mosaic
+    kernel, and what it refuses the compiler refuses too."""
+    need = rank_delta.fused_vmem_bytes(members, J, C,
+                                       heads=variant == "reprice_heads")
+    lowered = _lower_fused(shape, variant, members)
+    if need <= rank_delta.VMEM_LIMIT_BYTES:
+        _assert_kernel(lowered.compile())
     else:
-        lowered = heads.lower(*args, shape((S, C), jnp.bool_), k=K,
-                              **tiles)
-    _assert_kernel(lowered.compile())
+        with pytest.raises(Exception, match="vmem"):
+            lowered.compile()
 
 
 def test_flash_attention_compiles_for_v5e_at_qwen3_widths(shape):

@@ -25,8 +25,7 @@ def smoke():
 
 def test_selector_fleet_phase_small(smoke):
     out = smoke.phase_selector_fleet(n_jobs=16, n_cfgs=64, n_members=3,
-                                     n_ticks=4, frac=0.05, k=3,
-                                     expect_kernel=False)
+                                     n_ticks=4, frac=0.05, k=3)
     assert out["heads_within_contract"] == 2 * 4 * 3
     assert out["pallas_program"] == "interpret"
 
@@ -34,9 +33,10 @@ def test_selector_fleet_phase_small(smoke):
 def test_selector_fleet_phase_demands_the_kernel(smoke):
     """On the CPU the fused programs lower to the interpreter, which the
     chip run refuses."""
+    out = smoke.phase_selector_fleet(n_jobs=8, n_cfgs=16, n_members=2,
+                                     n_ticks=1, k=2)
     with pytest.raises(smoke.SmokeFailure, match="interpreter"):
-        smoke.phase_selector_fleet(n_jobs=8, n_cfgs=16, n_members=2,
-                                   n_ticks=1, k=2)
+        smoke.require_kernel(out)
 
 
 def test_served_path_phase_small(smoke):

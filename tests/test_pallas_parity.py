@@ -212,6 +212,32 @@ def test_pallas_states_added_retired_and_slot_reuse():
 
 
 @needs_jax
+def test_pallas_member_growth_past_the_vmem_limit_raises_cleanly():
+    """At C = 10,000 a plain fused tick fits the chip's VMEM at up to 64
+    member slots, a fused top-k tick only below that: a top-k tick at 64
+    slots, growth to 128 and a state built at 128 each raise ValueError
+    naming the limit, before anything is dispatched or changed."""
+    rng = np.random.default_rng(0)
+    n_jobs, n_cfgs = 8, 10_000
+    hours = rng.uniform(0.5, 5.0, (n_jobs, n_cfgs))
+    mask = np.ones((n_jobs, n_cfgs), bool)
+    prices = rng.uniform(0.1, 3.0, n_cfgs)
+    ids = list(range(n_cfgs))
+    s = PallasBatchedRankState(hours, mask, prices, ids, capacity=64)
+    for i in range(64):
+        s.add_state(i, rows=[i % n_jobs])
+    with pytest.raises(ValueError, match="16 MiB"):
+        s.reprice_with_heads({0: 1.0}, k=3)
+    assert s.dispatches == 0 and np.array_equal(s.prices, prices.astype(
+        np.float32).astype(np.float64))
+    with pytest.raises(ValueError, match="128 member slots"):
+        s.add_state("overflow", rows=[0])
+    assert s.realloc_count == 0 and s.n_active == 64
+    with pytest.raises(ValueError, match="VMEM"):
+        PallasBatchedRankState(hours, mask, prices, ids, capacity=128)
+
+
+@needs_jax
 def test_pallas_validates_members_and_deltas():
     rng, hours, mask, prices, ids, _ = _fleet_universe(3, n_jobs=4,
                                                        n_cfgs=6)
