@@ -356,7 +356,8 @@ class ServeFrontend:
                         entries=MappingProxyType(entries))
 
     def _publish(self) -> None:
-        with self.metrics_registry.span("snapshot.build"):
+        with self.metrics_registry.span("snapshot.build",
+                                        tick=self._last_tick):
             snap = self._build_snapshot()
         # a single reference store: workers reading self._snapshot see
         # either the old snapshot or the new one, never a mix
@@ -568,13 +569,15 @@ class ServeFrontend:
         ``"idle"`` — the threaded loop keys its sleeps off this, and
         inline tests drive it directly for deterministic interleaves."""
         changed = self._drain_control()
-        status = "idle"
-        deltas = ()
+        if self.ticks is not None and self.ticker.tick_count >= self.ticks:
+            if changed:
+                self._publish()
+            return "idle"
         m = self.metrics_registry
-        t0 = -1.0
-        if self.ticks is None or self.ticker.tick_count < self.ticks:
-            if m.spans_enabled:
-                t0 = m.clock()
+        # the tick's annotation encloses every span of the tick, feed
+        # errors included; its histogram is timed by hand below
+        with m.annotate(TICK_SPAN, tick=self.ticker.tick_count):
+            t0 = m.clock() if m.spans_enabled else -1.0
             try:
                 deltas = self.ticker.tick()
             except FeedError as exc:
@@ -592,28 +595,26 @@ class ServeFrontend:
                 return "feed-error"
             self._feed_failures = 0
             self._last_tick = self.ticker.tick_count - 1
-            status = "tick"
             if deltas:
                 rec = tick_record(0, deltas, self.service.price_epoch)
                 rec["worker"] = 0
                 rec["tick"] = self._last_tick
                 self._shards[0].append(rec)
                 self._cell_journal[0].inc()
-        if deltas or changed:
-            self._publish()
-        if status == "tick":
+            if deltas or changed:
+                self._publish()
             if t0 >= 0.0:
                 # whole-tick latency, snapshot publication included —
                 # successful ticks only (feed errors returned above)
                 m.histogram(TICK_SPAN).observe(m.clock() - t0)
-            if self.metrics_every is not None and \
-                    self.ticker.tick_count % self.metrics_every == 0:
-                rec = metrics_record(0, self._last_tick,
-                                     self.service.price_epoch, m)
-                rec["worker"] = 0
-                self._shards[0].append(rec)
-                self._cell_journal[0].inc()
-        return status
+        if self.metrics_every is not None and \
+                self.ticker.tick_count % self.metrics_every == 0:
+            rec = metrics_record(0, self._last_tick,
+                                 self.service.price_epoch, m)
+            rec["worker"] = 0
+            self._shards[0].append(rec)
+            self._cell_journal[0].inc()
+        return "tick"
 
     def backoff_delay(self, failures: Optional[int] = None) -> float:
         """Capped exponential backoff after consecutive feed failures."""

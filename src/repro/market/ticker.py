@@ -61,7 +61,8 @@ class PriceTicker:
         if deltas:
             table: Dict[Hashable, float] = {d.config_id: d.price
                                             for d in deltas}
-            with self.metrics.span("tick.reprice"):
+            with self.metrics.span("tick.reprice",
+                                   epoch=self.service.price_epoch + 1):
                 self.service.reprice(table)
             self._c_deltas.inc(len(deltas))
             self.deltas_applied += len(deltas)

@@ -220,20 +220,26 @@ def histogram_quantile(bounds: Sequence[float], counts: Sequence[int],
 
 
 class _Span:
-    """Context manager timing one block into a histogram."""
+    """Context manager timing one block into a histogram, inside a
+    profiler annotation of the same name that encloses both clock
+    reads."""
 
-    __slots__ = ("_hist", "_clock", "_t0")
+    __slots__ = ("_hist", "_clock", "_t0", "_ann")
 
-    def __init__(self, hist: Histogram, clock: Callable[[], float]) -> None:
+    def __init__(self, hist: Histogram, clock: Callable[[], float],
+                 ann) -> None:
         self._hist = hist
         self._clock = clock
+        self._ann = ann
 
     def __enter__(self) -> "_Span":
+        self._ann.__enter__()
         self._t0 = self._clock()
         return self
 
     def __exit__(self, *exc) -> bool:
         self._hist.observe(self._clock() - self._t0)
+        self._ann.__exit__(*exc)
         return False
 
 
@@ -249,6 +255,22 @@ class _NullSpan:
 
 NULL_SPAN = _NullSpan()
 
+#: ``jax.profiler.TraceAnnotation``, bound on the first span so that
+#: importing this package imports no jax.  ``TraceAnnotation(name,
+#: **args)`` is a host event on the profiler's timeline while a trace is
+#: being recorded, and costs about a microsecond otherwise.
+_ANNOTATION: Optional[Callable[..., object]] = None
+
+
+def _bind_annotation() -> Callable[..., object]:
+    global _ANNOTATION
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:     # without jax, spans keep their histograms
+        TraceAnnotation = lambda name, **args: NULL_SPAN  # noqa: E731
+    _ANNOTATION = TraceAnnotation
+    return TraceAnnotation
+
 
 def maybe_span(registry: Optional["MetricsRegistry"], name: str):
     """``registry.span(name)`` when a registry is wired, else a no-op."""
@@ -258,9 +280,12 @@ def maybe_span(registry: Optional["MetricsRegistry"], name: str):
 class MetricsRegistry:
     """Named counters/gauges/histograms plus the ``span`` timing API.
 
-    ``spans_enabled=False`` turns every ``span()`` into a shared no-op
-    object — no clock reads, no histogram writes — which is the
-    uninstrumented leg of the overhead gate (``benchmarks/obs_bench.py``).
+    Every span is also a profiler annotation of the same name, so a
+    ``jax.profiler`` trace lays the spans on the device's timeline.
+    ``spans_enabled=False`` turns every ``span()`` and ``annotate()``
+    into a shared no-op object — no clock reads, no histogram writes, no
+    annotations — which is the uninstrumented leg of the overhead gate
+    (``benchmarks/obs_bench.py``).
     Counters stay live in both modes; they are the accounting the rest
     of the system reads back (cache hits, shed, reallocs, ...).
     """
@@ -298,14 +323,25 @@ class MetricsRegistry:
                                    lambda: Histogram(name, buckets))
 
     def span(self, name: str,
-             buckets: Sequence[float] = DEFAULT_LATENCY_BUCKETS):
-        """Time a ``with`` block into histogram ``name`` (no-op when
-        spans are disabled).  Hot paths that cannot afford the context
-        manager read ``clock``/``spans_enabled`` and observe into a
-        cached ``histogram(name).cell()`` directly."""
+             buckets: Sequence[float] = DEFAULT_LATENCY_BUCKETS, **args):
+        """Time a ``with`` block into histogram ``name`` inside a profiler
+        annotation ``name`` that carries ``args`` as its stats (the
+        histogram never sees them); a no-op when spans are disabled.
+        Hot paths that cannot afford the context manager read
+        ``clock``/``spans_enabled`` and observe into a cached
+        ``histogram(name).cell()`` directly, under :meth:`annotate`."""
         if not self.spans_enabled:
             return NULL_SPAN
-        return _Span(self.histogram(name, buckets), self.clock)
+        return _Span(self.histogram(name, buckets), self.clock,
+                     (_ANNOTATION or _bind_annotation())(name, **args))
+
+    def annotate(self, name: str, **args):
+        """The profiler annotation of :meth:`span` alone, for a span
+        whose histogram is timed by hand (a no-op when spans are
+        disabled)."""
+        if not self.spans_enabled:
+            return NULL_SPAN
+        return (_ANNOTATION or _bind_annotation())(name, **args)
 
     # -- merge-on-read export ------------------------------------------
 

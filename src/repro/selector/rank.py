@@ -80,6 +80,11 @@ BACKENDS = ("numpy", "jax", "jax_batched", "jax_sharded", "jax_pallas")
 #: live (class, exclusion) ranking into a single shared state, so a
 #: price tick is one (possibly collective) kernel dispatch fleet-wide.
 FLEET_BACKENDS = ("jax_batched", "jax_sharded", "jax_pallas")
+#: span names of a device ``top_k`` head: the enqueue (slot lookup,
+#: slices, the jitted call) and the blocking readback of its two
+#: results; the host assembly of the head lies outside both
+TOPK_DISPATCH_SPAN = "topk.dispatch"
+TOPK_READBACK_SPAN = "topk.readback"
 #: backends whose runtime dependency is jax.
 _JAX_FAMILY = ("jax", "jax_batched", "jax_sharded", "jax_pallas")
 
@@ -420,10 +425,9 @@ class RankState:
         self.config_ids = list(config_ids)
         self.job_ids = list(job_ids) if job_ids is not None else None
         # optional shared telemetry (DESIGN.md §12): host materializations
-        # tick an aggregate counter + span on the injected registry; the
-        # plain per-state ``materializations`` int stays authoritative for
-        # the freshness tests.
-        self._metrics = metrics
+        # tick an aggregate counter on the injected registry; the plain
+        # per-state ``materializations`` int stays authoritative for the
+        # freshness tests.
         self._c_mat = (None if metrics is None
                        else metrics.counter("rank.materializations"))
         self.hours, self.mask, self.prices = _canonicalize_universe(
@@ -511,11 +515,9 @@ class RankState:
             self.materializations += 1
             if self._c_mat is not None:
                 self._c_mat.inc()
-            with maybe_span(self._metrics, "rank.materialize"):
-                self._ranking_memo = (
-                    self.reprices,
-                    _materialize(self.scores, self.counts,
-                                 self.config_ids))
+            self._ranking_memo = (
+                self.reprices,
+                _materialize(self.scores, self.counts, self.config_ids))
         return list(self._ranking_memo[1])
 
     def top_k(self, k: int) -> List[RankedConfig]:
@@ -849,11 +851,9 @@ class JaxRankState:
             self.materializations += 1
             if self._c_mat is not None:
                 self._c_mat.inc()
-            with maybe_span(self._metrics, "rank.materialize"):
-                self._ranking_memo = (
-                    self.reprices,
-                    _materialize(self.scores, self.counts,
-                                 self.config_ids))
+            self._ranking_memo = (
+                self.reprices,
+                _materialize(self.scores, self.counts, self.config_ids))
         return list(self._ranking_memo[1])
 
     def top_k(self, k: int) -> List[RankedConfig]:
@@ -862,11 +862,14 @@ class JaxRankState:
         an O(k) readback — the full C-config materialize/sort never
         happens.  Tie-break (catalog order on equal scores) matches the
         materialized ranking; see :func:`_jax_topk_fn`."""
-        k = _check_k(k, len(self.config_ids))
-        idx, vals = _jax_topk_fn()(self.d_scores, self._d_finite, k)
-        idx = np.asarray(idx)
+        with maybe_span(self._metrics, TOPK_DISPATCH_SPAN):
+            k = _check_k(k, len(self.config_ids))
+            idx, vals = _jax_topk_fn()(self.d_scores, self._d_finite, k)
+        with maybe_span(self._metrics, TOPK_READBACK_SPAN):
+            idx = np.asarray(idx)
+            vals = np.asarray(vals, dtype=np.float64)
         out = []
-        for i, s in zip(idx, np.asarray(vals, dtype=np.float64)):
+        for i, s in zip(idx, vals):
             n = int(self.counts[i])
             out.append(RankedConfig(
                 self.config_ids[int(i)],
@@ -1216,10 +1219,9 @@ class BatchedRankState:
             self.materializations += 1
             if self._c_mat is not None:
                 self._c_mat.inc()
-            with maybe_span(self._metrics, "rank.materialize"):
-                memo = (self.reprices,
-                        _materialize(self.scores(key), self._counts[slot],
-                                     self.config_ids))
+            memo = (self.reprices,
+                    _materialize(self.scores(key), self._counts[slot],
+                                 self.config_ids))
             self._ranking_memo[key] = memo
         return list(memo[1])
 
@@ -1228,14 +1230,17 @@ class BatchedRankState:
         buffer: ``jax.lax.top_k`` on the member's row plus an O(k)
         readback — no C-object materialization, same catalog-order
         tie-break as :meth:`ranking` (see :func:`_jax_topk_fn`)."""
-        slot = self._slot_of(key)
-        k = _check_k(k, len(self.config_ids))
-        idx, vals = _jax_topk_fn()(self.d_scores[slot],
-                                   self._d_finite[slot], k)
+        with maybe_span(self._metrics, TOPK_DISPATCH_SPAN):
+            slot = self._slot_of(key)
+            k = _check_k(k, len(self.config_ids))
+            idx, vals = _jax_topk_fn()(self.d_scores[slot],
+                                       self._d_finite[slot], k)
+        with maybe_span(self._metrics, TOPK_READBACK_SPAN):
+            idx = np.asarray(idx)
+            vals = np.asarray(vals, dtype=np.float64)
         counts = self._counts[slot]
         out = []
-        for i, s in zip(np.asarray(idx), np.asarray(vals,
-                                                    dtype=np.float64)):
+        for i, s in zip(idx, vals):
             n = int(counts[i])
             out.append(RankedConfig(
                 self.config_ids[int(i)],

@@ -50,8 +50,9 @@ import numpy as np
 
 from repro.obs import MetricsRegistry, maybe_span
 
-from .rank import (SCORE_CONTRACTS, BackendUnavailableError,
-                   NothingRankableError, RankedConfig,
+from .rank import (SCORE_CONTRACTS, TOPK_DISPATCH_SPAN, TOPK_READBACK_SPAN,
+                   BackendUnavailableError, NothingRankableError,
+                   RankedConfig,
                    _bucket_size, _canonicalize_universe, _check_k,
                    _materialize, _position_index, _validated_deltas,
                    _HAVE_JAX)
@@ -532,11 +533,9 @@ class ShardedBatchedRankState:
             self.materializations += 1
             if self._c_mat is not None:
                 self._c_mat.inc()
-            with maybe_span(self._metrics, "rank.materialize"):
-                memo = (self.reprices,
-                        _materialize(self.scores(key),
-                                     self._counts[slot],
-                                     self.config_ids))
+            memo = (self.reprices,
+                    _materialize(self.scores(key), self._counts[slot],
+                                 self.config_ids))
             self._ranking_memo[key] = memo
         return list(memo[1])
 
@@ -551,16 +550,18 @@ class ShardedBatchedRankState:
         recompile storm); the per-shard depth is further clamped to
         the shard width, which still guarantees >= k real candidates
         after the merge."""
-        slot = self._slot_of(key)
-        n_cfgs = len(self.config_ids)
-        k = _check_k(k, n_cfgs)
-        k_loc = min(k, self._c_loc)
-        fn = _sharded_topk_fn(self.n_devices, k_loc, self._c_loc)
-        gidx, vals = fn(self.d_scores, self._d_finite,
-                        jnp.asarray(slot, dtype=jnp.int32))
+        with maybe_span(self._metrics, TOPK_DISPATCH_SPAN):
+            slot = self._slot_of(key)
+            n_cfgs = len(self.config_ids)
+            k = _check_k(k, n_cfgs)
+            k_loc = min(k, self._c_loc)
+            fn = _sharded_topk_fn(self.n_devices, k_loc, self._c_loc)
+            gidx, vals = fn(self.d_scores, self._d_finite,
+                            jnp.asarray(slot, dtype=jnp.int32))
         with maybe_span(self._metrics, MERGE_SPAN):
-            gidx = np.asarray(gidx)
-            vals = np.asarray(vals, dtype=np.float64)
+            with maybe_span(self._metrics, TOPK_READBACK_SPAN):
+                gidx = np.asarray(gidx)
+                vals = np.asarray(vals, dtype=np.float64)
             keep = gidx < n_cfgs           # drop pad-tail candidates
             gidx, vals = gidx[keep], vals[keep]
             order = np.lexsort((gidx, vals))[:k]

@@ -376,6 +376,147 @@ def test_engine_records_prefill_decode_spans():
     assert comp.decode_ms == pytest.approx(1.0)
 
 
+
+# --- registry spans on the profiler's timeline ----------------------------------
+
+def test_span_args_reach_the_annotation_and_never_the_histogram(monkeypatch):
+    import repro.obs.registry as registry
+    seen = []
+
+    def fake_annotation(name, **args):
+        seen.append((name, args))
+        return NULL_SPAN
+    monkeypatch.setattr(registry, "_ANNOTATION", fake_annotation)
+    reg = MetricsRegistry(clock=FakeClock(step=0.001))
+    with reg.span("tick.reprice", epoch=4):
+        pass
+    with reg.annotate("tick.total", tick=2):
+        pass
+    assert seen == [("tick.reprice", {"epoch": 4}), ("tick.total", {"tick": 2})]
+    # the annotation reads no clock: the span is one FakeClock step, and
+    # annotate() alone makes no histogram
+    assert list(reg.snapshot()["histograms"]) == ["tick.reprice"]
+    h = reg.histogram("tick.reprice")
+    assert h.count == 1 and h.merged()[1] == 1_000_000
+    off = MetricsRegistry(spans_enabled=False)
+    assert off.annotate("tick.total", tick=2) is NULL_SPAN
+    assert off.span("tick.reprice", epoch=4) is NULL_SPAN
+    assert len(seen) == 2
+
+
+def test_importing_obs_imports_no_jax():
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    probe = ("import sys, repro.obs\n"
+             "reg = repro.obs.MetricsRegistry()\n"
+             "reg.counter('c').inc()\n"
+             "assert 'jax' not in sys.modules, 'jax imported'\n")
+    result = subprocess.run([sys.executable, "-c", probe],
+                            capture_output=True, text=True,
+                            env={**os.environ, "PYTHONPATH": src})
+    assert result.returncode == 0, result.stderr
+
+
+TICK_ANNOTATIONS = ("tick.total", "tick.reprice", "snapshot.build",
+                    "topk.dispatch", "topk.readback")
+
+
+def _traced_tick(fe, log_dir):
+    """Run one ``step_tick`` under a ``jax.profiler`` trace; returns the
+    tick's annotations, ``{host line: [(name, start, end, stats)]}``."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        assert fe.step_tick() == "tick"
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                       recursive=True)
+    lines = {}
+    for plane in ProfileData.from_file(path).planes:
+        for i, line in enumerate(plane.lines):
+            for ev in line.events:
+                if ev.name in TICK_ANNOTATIONS:
+                    lines.setdefault((plane.name, i), []).append(
+                        (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                         dict(ev.stats)))
+    return lines
+
+
+def test_tick_spans_nest_on_one_host_line_of_the_profiler_trace(tmp_path):
+    pytest.importorskip("jax")
+    fe, _ = _frontend("jax_batched", n_ticks=4)
+    fe.warm([Submission("j1"), Submission("j2")])
+    assert fe.step_tick() == "tick"          # compiles outside the trace
+    [events] = _traced_tick(fe, tmp_path).values()
+    by = {}
+    for ev in sorted(events, key=lambda e: e[1]):
+        by.setdefault(ev[0], []).append(ev)
+    inside = lambda a, b: b[1] <= a[1] and a[2] <= b[2]  # noqa: E731
+    [tick] = by["tick.total"]
+    [reprice] = by["tick.reprice"]
+    [build] = by["snapshot.build"]
+    assert tick[3]["tick"] == 1 and build[3]["tick"] == 1
+    assert reprice[3]["epoch"] == fe.service.price_epoch == 2
+    assert inside(reprice, tick) and inside(build, tick)
+    assert reprice[2] <= build[1]
+    heads = by["topk.dispatch"], by["topk.readback"]
+    assert [len(h) for h in heads] == [2, 2]
+    for dispatch, readback in zip(*heads):
+        assert inside(dispatch, build) and inside(readback, build)
+        assert dispatch[2] <= readback[1]
+    fe.close()
+
+
+def test_disabled_spans_leave_no_histograms_and_no_annotations(tmp_path):
+    pytest.importorskip("jax")
+    store, ids, base = _universe()
+    reg = MetricsRegistry(spans_enabled=False)
+    svc = SelectionService(IdentityCatalog(ids), store, PriceTable(base),
+                           backend="jax_batched", metrics=reg)
+    fe = ServeFrontend(svc, _recorded(base, n_ticks=4))
+    fe.warm([Submission("j1"), Submission("j2")])
+    assert fe.step_tick() == "tick"
+    assert _traced_tick(fe, tmp_path) == {}
+    hists = reg.snapshot()["histograms"]
+    assert not set(TICK_ANNOTATIONS) & set(hists)
+    assert all(h["count"] == 0 for h in hists.values())
+    fe.close()
+
+
+@pytest.mark.parametrize("backend,per_head", [
+    ("numpy", 0), ("jax", 1), ("jax_batched", 1), ("jax_sharded", 1)])
+def test_one_topk_dispatch_and_readback_per_live_selection(backend,
+                                                           per_head):
+    if backend != "numpy":
+        pytest.importorskip("jax")
+    fe, _ = _frontend(backend, n_ticks=4)
+    fe.warm([Submission("j1"), Submission("j2"),
+             Submission("j1", exclude_groups=("g0",))])
+    live = sum(e.head is not None for e in fe.snapshot.entries.values())
+    assert live == 3
+    reg = fe.metrics_registry
+
+    def counts():
+        hists = reg.snapshot()["histograms"]
+        return {name: hists.get(name, {"count": 0})["count"]
+                for name in ("snapshot.build", "topk.dispatch",
+                             "topk.readback")}
+    before = counts()
+    assert fe.step_tick() == "tick"
+    after = counts()
+    assert {n: after[n] - before[n] for n in after} == {
+        "snapshot.build": 1, "topk.dispatch": per_head * live,
+        "topk.readback": per_head * live}
+    if backend == "numpy":
+        assert after["topk.dispatch"] == after["topk.readback"] == 0
+    fe.close()
+
+
 if __name__ == "__main__":
     import sys
     if "--regen-golden" in sys.argv:
