@@ -142,8 +142,6 @@ class PallasBatchedRankState(BatchedRankState):
         self.config_ids = list(config_ids)
         self.job_ids = list(job_ids) if job_ids is not None else None
         self._metrics = metrics
-        self._c_mat = (None if metrics is None
-                       else metrics.counter("rank.materializations"))
         hours, mask, prices = _canonicalize_universe(hours, mask, prices,
                                                      self.job_ids)
         self._pos = _position_index(self.config_ids)
@@ -184,20 +182,7 @@ class PallasBatchedRankState(BatchedRankState):
         # inherited add/retire/grow machinery manages these)
         cap = self._CAPACITY_BASE if capacity is None else max(1, capacity)
         self._check_vmem(cap, heads=False)
-        self._capacity = cap
-        self._slots: "dict[Hashable, int]" = {}
-        self._retired: "set" = set()
-        self._free: List[int] = list(range(cap - 1, -1, -1))
-        self.d_row_masks = jnp.zeros((cap, self._n_jobs),
-                                     dtype=jnp.float32)
-        self.d_scores = jnp.zeros((cap, n_cfgs), dtype=jnp.float32)
-        self._counts = np.zeros((cap, n_cfgs), dtype=np.int64)
-        self._d_finite = jnp.zeros((cap, n_cfgs), dtype=bool)
-        self.reprices = 0
-        self.dispatches = 0
-        self.realloc_count = 0
-        self.materializations = 0
-        self._ranking_memo: "dict[Hashable, Tuple[int, List[RankedConfig]]]" = {}
+        self._init_members(cap)
 
     # -- member management (only the pieces the padding touches) ------------
     def _check_vmem(self, capacity: int, heads: bool) -> None:

@@ -80,9 +80,11 @@ BACKENDS = ("numpy", "jax", "jax_batched", "jax_sharded", "jax_pallas")
 #: live (class, exclusion) ranking into a single shared state, so a
 #: price tick is one (possibly collective) kernel dispatch fleet-wide.
 FLEET_BACKENDS = ("jax_batched", "jax_sharded", "jax_pallas")
-#: span names of a device ``top_k`` head: the enqueue (slot lookup,
-#: slices, the jitted call) and the blocking readback of its two
-#: results; the host assembly of the head lies outside both
+#: span names of a device ``top_k``: the enqueue of the jitted call
+#: (per head on the per-state and sharded states, with their slot lookup
+#: and slices; once per state change over every slot on
+#: :class:`BatchedRankState`) and the blocking readback of its two
+#: results; the host assembly of a head lies outside both
 TOPK_DISPATCH_SPAN = "topk.dispatch"
 TOPK_READBACK_SPAN = "topk.readback"
 #: backends whose runtime dependency is jax.
@@ -982,9 +984,12 @@ class BatchedRankState:
 
     Serving is per member: :meth:`ranking` materializes the full sorted
     list (memoized on the tick count), :meth:`top_k` serves the head of
-    the ranking straight from the device score buffer
-    (``jax.lax.top_k`` + an O(k) readback — the C-object build/sort
-    never happens), and :meth:`winner` is ``top_k(1)``.
+    the ranking from the device score buffer without the C-object
+    build/sort, and :meth:`winner` is ``top_k(1)``.  Heads are served
+    fleet-wide: the first :meth:`top_k` after the scores or the
+    membership changed runs ONE ``jax.lax.top_k`` over every slot and
+    reads the (capacity, k) result back once; every later head, of any
+    member, until the next change is a host slice of that readback.
 
     **Contract** (:data:`SCORE_CONTRACTS` ``["jax_batched"]``): same
     float32 tolerance envelope as the per-state jax kernel — batching
@@ -1009,8 +1014,6 @@ class BatchedRankState:
         self.config_ids = list(config_ids)
         self.job_ids = list(job_ids) if job_ids is not None else None
         self._metrics = metrics
-        self._c_mat = (None if metrics is None
-                       else metrics.counter("rank.materializations"))
         hours, mask, prices = _canonicalize_universe(hours, mask, prices,
                                                      self.job_ids)
         self._pos = _position_index(self.config_ids)
@@ -1027,8 +1030,12 @@ class BatchedRankState:
         self.d_prices = jnp.asarray(prices, dtype=jnp.float32)
         (self.d_cost, self.d_row_best, self.d_norm,
          _) = cold(self.d_hours, self.d_mask, self.d_prices)
-        # the member axis: slot tables + batched accumulators
-        cap = self._CAPACITY_BASE if capacity is None else max(1, capacity)
+        self._init_members(self._CAPACITY_BASE if capacity is None
+                           else max(1, capacity))
+
+    def _init_members(self, cap: int) -> None:
+        """The member axis at ``cap`` empty slots (slot tables, batched
+        accumulators), the serving memos and the counters."""
         self._capacity = cap
         self._slots: "dict[Hashable, int]" = {}
         #: keys retired via :meth:`retire_state`; serving one raises
@@ -1057,6 +1064,17 @@ class BatchedRankState:
         self.realloc_count = 0
         self.materializations = 0
         self._ranking_memo: "dict[Hashable, Tuple[int, List[RankedConfig]]]" = {}
+        #: ``(d_scores, d_finite, {k: (idx, vals)})``: every slot's head,
+        #: valid while both buffers are the arrays it was computed from
+        #: (:meth:`_fleet_heads`)
+        self._head_memo: Tuple[Any, Any, dict] = (None, None, {})
+        m = self._metrics
+        self._c_mat = (None if m is None
+                       else m.counter("rank.materializations"))
+        self._c_head_batches = (None if m is None
+                                else m.counter("rank.head_batches"))
+        self._c_head_hits = (None if m is None
+                             else m.counter("rank.head_memo_hits"))
 
     # -- member management --------------------------------------------------
     def __contains__(self, key: Hashable) -> bool:
@@ -1225,22 +1243,46 @@ class BatchedRankState:
             self._ranking_memo[key] = memo
         return list(memo[1])
 
-    def top_k(self, key: Hashable, k: int) -> List[RankedConfig]:
-        """The head of a member's ranking served from the device score
-        buffer: ``jax.lax.top_k`` on the member's row plus an O(k)
-        readback — no C-object materialization, same catalog-order
-        tie-break as :meth:`ranking` (see :func:`_jax_topk_fn`)."""
+    def _fleet_heads(self, k: int) -> Tuple[np.ndarray, np.ndarray]:
+        """Every slot's ``k``-head as host arrays ``(idx, vals)`` of
+        shape (capacity, k): one jitted ``top_k`` over the whole score
+        buffer (``lax.top_k`` sorts the last axis, so each row keeps its
+        catalog-order tie-break) and one readback of the pair.  Memoized
+        per ``k`` on the identity of ``d_scores`` and ``_d_finite``:
+        every path that changes scores or membership (the reprice, add,
+        retire, growth) assigns new arrays, so identity is the
+        state-change test."""
+        scores, finite = self.d_scores, self._d_finite
+        memo_scores, memo_finite, heads = self._head_memo
+        if memo_scores is not scores or memo_finite is not finite:
+            heads = {}
+            self._head_memo = (scores, finite, heads)
+        hit = heads.get(k)
+        if hit is not None:
+            if self._c_head_hits is not None:
+                self._c_head_hits.inc()
+            return hit
         with maybe_span(self._metrics, TOPK_DISPATCH_SPAN):
-            slot = self._slot_of(key)
-            k = _check_k(k, len(self.config_ids))
-            idx, vals = _jax_topk_fn()(self.d_scores[slot],
-                                       self._d_finite[slot], k)
+            idx, vals = _jax_topk_fn()(scores, finite, k)
         with maybe_span(self._metrics, TOPK_READBACK_SPAN):
-            idx = np.asarray(idx)
-            vals = np.asarray(vals, dtype=np.float64)
+            idx, vals = jax.device_get((idx, vals))
+        if self._c_head_batches is not None:
+            self._c_head_batches.inc()
+        heads[k] = hit = (idx, vals.astype(np.float64))
+        return hit
+
+    def top_k(self, key: Hashable, k: int) -> List[RankedConfig]:
+        """The head of a member's ranking, sliced from the fleet's heads
+        (:meth:`_fleet_heads`: the first call after a state change runs
+        one ``jax.lax.top_k`` over every slot, later calls touch no
+        device) — no C-object materialization, same catalog-order
+        tie-break as :meth:`ranking` (see :func:`_jax_topk_fn`)."""
+        slot = self._slot_of(key)
+        k = _check_k(k, len(self.config_ids))
+        idx, vals = self._fleet_heads(k)
         counts = self._counts[slot]
         out = []
-        for i, s in zip(idx, vals):
+        for i, s in zip(idx[slot], vals[slot]):
             n = int(counts[i])
             out.append(RankedConfig(
                 self.config_ids[int(i)],
