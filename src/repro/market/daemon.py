@@ -37,7 +37,10 @@ JOURNAL_FORMAT = "repro.market.decision-journal"
 #: is data, and consumers resolve it through ``score_contract``.  Decision records served via device-side top-k carry an
 #: additive ``served_via`` field (absent = full-ranking serving); a
 #: feed that raises mid-tick journals an additive ``feed-error`` record
-#: kind (the tick is retried; prices stay at the last good epoch); and
+#: kind (the tick is retried; prices stay at the last good epoch); a
+#: front end that ingests test-job executions journals an additive
+#: ``profile`` kind, which replay applies to its store (journals with
+#: no arrivals carry none and keep their bytes); and
 #: journals merged from the concurrent front-end
 #: (:mod:`repro.market.frontend`) stamp decisions/rejections with
 #: additive ``worker`` / ``snapshot_tick`` fields and tick/feed-error
@@ -58,6 +61,19 @@ def tick_record(seq: int, deltas: Sequence[PriceDelta],
                 price_epoch: int) -> Dict[str, Any]:
     return {"kind": "tick", "seq": seq, "deltas": len(deltas),
             "applied": [[d.config_id, d.price] for d in deltas],
+            "price_epoch": price_epoch}
+
+
+def profile_record(seq: int, cells: Sequence[Tuple[Hashable, Hashable,
+                                                   float]],
+                   price_epoch: int) -> Dict[str, Any]:
+    """Additive record kind (DESIGN.md §8): profile cells ``[job,
+    config, runtime hours]`` written into the store at ``price_epoch``,
+    before the prices of the tick that carried them.  Journal replay
+    applies them to its store at their position, so decisions after
+    them are audited against the store they were served from."""
+    return {"kind": "profile", "seq": seq,
+            "cells": [[j, c, h] for j, c, h in cells],
             "price_epoch": price_epoch}
 
 

@@ -9,9 +9,11 @@ concurrency layer on top of the exact same service/journal machinery
   * **one tick thread owns all mutable selection state.**  It is the only
     thread that touches the :class:`~repro.selector.SelectionService`
     (and through it the shared :class:`~repro.selector.BatchedRankState`
-    delta refresh).  Per tick it polls the feed, applies the deltas, and
-    publishes an immutable :class:`Snapshot`: the tick id, the price
-    epoch, the price-table version, and the top-k head of every
+    delta refresh).  Per tick it polls the feed, applies the profile
+    records queued since the last tick (:meth:`ServeFrontend.
+    add_profiles`), then the deltas, and publishes an immutable
+    :class:`Snapshot`: the tick id, the price epoch, the price-table
+    version, and the top-k head of every
     registered (class, exclusion) selection — pulled through
     ``SelectionService.rank_head``, i.e. the device-side ``top_k`` on
     the jax backends.
@@ -72,8 +74,8 @@ from repro.selector import (Decision, NothingRankableError, RankedConfig,
                             SelectionService)
 from repro.market.daemon import (JOURNAL_FORMAT, JOURNAL_VERSION, Submission,
                                  decision_record, feed_error_record,
-                                 metrics_record, rejection_record,
-                                 tick_record)
+                                 metrics_record, profile_record,
+                                 rejection_record, tick_record)
 from repro.market.feed import FeedError, PriceFeed
 from repro.market.ticker import PriceTicker
 
@@ -314,6 +316,9 @@ class ServeFrontend:
         self._queues: List["queue.SimpleQueue"] = \
             [queue.SimpleQueue() for _ in range(workers)]
         self._control: "queue.SimpleQueue" = queue.SimpleQueue()
+        #: profile cells waiting for the next tick (add_profiles)
+        self._profiles: "queue.SimpleQueue" = queue.SimpleQueue()
+        self._tick_ingested = 0
         self._rr = itertools.count()
         self._route_memo: Dict[Tuple, Route] = {}
         #: registered selections (tick-thread-owned; insertion-ordered,
@@ -411,6 +416,46 @@ class ServeFrontend:
         later submission for it re-registers through the control path —
         or journals a genuine rejection if it is unrankable."""
         self._control.put(("retire", job_class, tuple(exclude_groups)))
+
+    def add_profiles(self, cells: Iterable[Tuple[Hashable, Hashable, float]]
+                     ) -> int:
+        """Hand test-job executions, ``(job, config, runtime hours)``
+        cells, to the tick thread.  Callable from any thread; the cells
+        are checked here (a bad runtime raises ``ValueError`` to the
+        caller, never on the tick thread) and queued.  The next tick
+        whose poll succeeds applies every queued cell through
+        ``SelectionService.ingest`` before its prices, journals them as
+        one ``profile`` record and publishes: a snapshot at price epoch
+        ``e`` reflects every record of the ticks before ``e``.  A failed
+        poll leaves them queued for the retry; past the tick budget they
+        stay queued.  Returns the cells queued."""
+        batch = tuple((j, c, float(h)) for j, c, h in cells)
+        for j, c, h in batch:
+            if not 0 < h < float("inf"):
+                raise ValueError(f"non-positive or non-finite runtime "
+                                 f"for {j!r} on {c!r}")
+        if batch:
+            self._profiles.put(batch)
+        return len(batch)
+
+    def _apply_profiles(self) -> None:
+        """The ticker's hook between a successful poll and its prices:
+        ingest every queued cell and journal it (tick thread only)."""
+        cells: List[Tuple[Hashable, Hashable, float]] = []
+        while True:
+            try:
+                cells.extend(self._profiles.get_nowait())
+            except queue.Empty:
+                break
+        self._tick_ingested = len(cells)
+        if not cells:
+            return
+        self.service.ingest(cells)
+        rec = profile_record(0, cells, self.service.price_epoch)
+        rec["worker"] = 0
+        rec["tick"] = self.ticker.tick_count - 1
+        self._shards[0].append(rec)
+        self._cell_journal[0].inc()
 
     # -- serving (worker w, or inline) ---------------------------------------
     def _serve_one(self, w: int, sub: Submission, t0: float = -1.0) -> None:
@@ -579,7 +624,7 @@ class ServeFrontend:
         with m.annotate(TICK_SPAN, tick=self.ticker.tick_count):
             t0 = m.clock() if m.spans_enabled else -1.0
             try:
-                deltas = self.ticker.tick()
+                deltas = self.ticker.tick(self._apply_profiles)
             except FeedError as exc:
                 self._c_feed_errors.inc()
                 self._feed_failures += 1
@@ -601,7 +646,7 @@ class ServeFrontend:
                 rec["tick"] = self._last_tick
                 self._shards[0].append(rec)
                 self._cell_journal[0].inc()
-            if deltas or changed:
+            if deltas or changed or self._tick_ingested:
                 self._publish()
             if t0 >= 0.0:
                 # whole-tick latency, snapshot publication included —

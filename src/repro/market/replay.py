@@ -402,15 +402,16 @@ class JournalReplayer:
         return out
 
     # -- the consistency audit ----------------------------------------------
-    def _rank_cold(self, job_class: Optional[JobClass],
+    def _rank_cold(self, store: ProfilingStore,
+                   job_class: Optional[JobClass],
                    exclude_groups: Sequence[str],
                    prices: Mapping[Hashable, float]):
-        jobs = self.store.select_jobs(job_class=job_class,
-                                      exclude_groups=exclude_groups)
+        jobs = store.select_jobs(job_class=job_class,
+                                 exclude_groups=exclude_groups)
         if not jobs:
             raise NothingRankableError("no test jobs to learn from")
-        hours, mask = self.store.matrix(job_ids=jobs,
-                                        config_ids=self.catalog_ids)
+        hours, mask = store.matrix(job_ids=jobs,
+                                   config_ids=self.catalog_ids)
         vec = np.asarray([prices[c] for c in self.catalog_ids],
                          dtype=np.float64)
         return rank_dense(hours, mask, vec, self.catalog_ids, job_ids=jobs)
@@ -453,10 +454,18 @@ class JournalReplayer:
         winner means the daemon silently served nothing for a rankable
         job — that is a mismatch, not bookkeeping.
 
+        ``profile`` records (test-job executions ingested while
+        serving) are applied, at their journal position, to a copy of
+        the store the replayer was given -- which must therefore be the
+        store as serving began -- so every later selection is audited
+        against the store it was served from; each one's stamped price
+        epoch is verified like a tick's.
+
         Decisions between the same two ticks with the same
         (class, exclusions) share identical rank inputs, so the cold
-        ranking is memoized per ``(epoch, class, exclusions)`` — the
-        audit costs O(epochs x distinct selections), not O(decisions).
+        ranking is memoized per ``(epoch, store version, class,
+        exclusions)`` — the audit costs O(epochs x distinct
+        selections), not O(decisions).
         """
         if contract is None:
             contract = score_contract(self.backend)
@@ -465,6 +474,7 @@ class JournalReplayer:
         mismatches: List[ReplayMismatch] = []
         drift: List[ReplayMismatch] = []
         rank_memo: Dict[Tuple, Any] = {}
+        store = self.store
 
         def differ(seq, job, field, journaled, replayed):
             mismatches.append(ReplayMismatch(seq, job, field, journaled,
@@ -475,11 +485,11 @@ class JournalReplayer:
             klass = JobClass(rec["job_class"]) if rec.get("job_class") \
                 else None
             excl = tuple(rec.get("exclude_groups", ()))
-            key = (epoch, klass, excl)
+            key = (epoch, store.version, klass, excl)
             if key in rank_memo:
                 return rank_memo[key]
             try:
-                ranking = self._rank_cold(klass, excl, prices)
+                ranking = self._rank_cold(store, klass, excl, prices)
             except NothingRankableError:
                 ranking = None
             if ranking is not None and \
@@ -503,6 +513,16 @@ class JournalReplayer:
                 if rec["price_epoch"] != epoch:
                     differ(rec["seq"], None, "price_epoch",
                            rec["price_epoch"], epoch)
+                continue
+            if kind == "profile":
+                # additive kind: cells written before the prices of the
+                # tick that carried them
+                if rec["price_epoch"] != epoch:
+                    differ(rec["seq"], None, "price_epoch",
+                           rec["price_epoch"], epoch)
+                if store is self.store:
+                    store = self.store.copy()
+                store.add_cells(rec["cells"])
                 continue
             if kind == "metrics":
                 # additive kind: cumulative telemetry export — verify

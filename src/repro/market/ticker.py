@@ -10,7 +10,7 @@ hot — so quiet markets cost nothing.
 """
 from __future__ import annotations
 
-from typing import Dict, Hashable, Optional, Tuple
+from typing import Callable, Dict, Hashable, Optional, Tuple
 
 from repro.obs import MetricsRegistry
 from repro.selector import PriceTable, SelectionService
@@ -38,8 +38,14 @@ class PriceTicker:
         self.deltas_applied = 0
         self.epochs_driven = 0
 
-    def tick(self) -> Tuple[PriceDelta, ...]:
+    def tick(self, before_prices: Optional[Callable[[], object]] = None
+             ) -> Tuple[PriceDelta, ...]:
         """Poll one batch and apply it; returns the batch.
+
+        ``before_prices``, when given, runs once the poll has succeeded
+        and before the batch is applied: the hook through which a tick
+        applies its profile records ahead of its prices
+        (:meth:`~repro.market.ServeFrontend.add_profiles`).
 
         A ``feed.poll`` that raises surfaces as a typed
         :class:`~repro.market.FeedError` (original exception as
@@ -58,6 +64,8 @@ class PriceTicker:
                 f"{type(exc).__name__}: {exc}", self.tick_count) from exc
         self.tick_count += 1
         self._c_ticks.inc()
+        if before_prices is not None:
+            before_prices()
         if deltas:
             table: Dict[Hashable, float] = {d.config_id: d.price
                                             for d in deltas}

@@ -64,19 +64,22 @@ if _HAVE_JAX:
     from repro.kernels.ops import _interpret
     from repro.kernels.rank_delta import (VMEM_LIMIT_BYTES, fused_vmem_bytes,
                                           rank_delta_fns)
-    from repro.selector.rank import _fleet_matmul
+    from repro.selector.rank import (_cost_of, _fleet_matmul, _fold_rows,
+                                     _ingest_universe, _norm_of,
+                                     _unpack_cells)
 
 __all__ = ["PallasBatchedRankState"]
 
 
 if _HAVE_JAX:
-    # small off-hot-path helpers (cold row minima, a new member's
-    # accumulators), jitted once under a lock — the same double-checked
-    # discipline as the rank.py singletons and rank_delta_fns()
-    _HELPER_FNS: Optional[Tuple[Any, Any]] = None
+    # small helpers beside the fused tick (cold row minima, a new
+    # member's accumulators, the profile ingest), jitted once under a
+    # lock -- the same double-checked discipline as the rank.py
+    # singletons and rank_delta_fns()
+    _HELPER_FNS: Optional[Tuple[Any, Any, Any]] = None
     _HELPER_LOCK = threading.Lock()
 
-    def _helper_fns() -> Tuple[Any, Any]:
+    def _helper_fns() -> Tuple[Any, Any, Any]:
         global _HELPER_FNS
         if _HELPER_FNS is None:
             with _HELPER_LOCK:
@@ -94,8 +97,28 @@ if _HAVE_JAX:
                                          0.0)
                         return _fleet_matmul(row_mask, norm)
 
+                    def ingest(hours, mask, row_best, scores, finite,
+                               prices, row_masks, idx, vals):
+                        rows, cols, new_hours, trows, row_w = \
+                            _unpack_cells(idx, vals)
+                        # the touched rows' normalised costs before the
+                        # write, implied as the kernel streams them
+                        old = _norm_of(
+                            _cost_of(hours[trows], mask[trows], prices),
+                            mask[trows], row_best[trows, 0])
+                        (hours, mask, _, t_best,
+                         t_norm) = _ingest_universe(hours, mask, prices,
+                                                    rows, cols, new_hours,
+                                                    trows)
+                        scores, finite = _fold_rows(
+                            scores, finite, row_masks, trows, row_w,
+                            t_norm - old, mask[trows])
+                        return (hours, mask,
+                                row_best.at[trows, 0].set(t_best), scores,
+                                finite)
+
                     _HELPER_FNS = (jax.jit(cold_row_best),
-                                   jax.jit(member_scores))
+                                   jax.jit(member_scores), jax.jit(ingest))
         return _HELPER_FNS
 
 
@@ -147,7 +170,7 @@ class PallasBatchedRankState(BatchedRankState):
         self._pos = _position_index(self.config_ids)
         self._job_pos = (None if self.job_ids is None else
                          {j: i for i, j in enumerate(self.job_ids)})
-        self._mask = mask                     # host copy: member counts
+        self._mask = mask.copy()              # host copy: member counts
         n_cfgs = len(self.config_ids)
         #: true (unpadded) job count — what ``rows=`` validates against
         self._n_true_jobs = hours.shape[0]
@@ -220,33 +243,18 @@ class PallasBatchedRankState(BatchedRankState):
             raise ValueError("duplicate rows in member selection")
         return idx
 
-    def add_state(self, key: Hashable, *,
-                  rows: Optional[Sequence[int]] = None,
-                  jobs: Optional[Sequence[Hashable]] = None) -> None:
-        """Register a member ranking over a subset of the job axis; the
-        accumulators come from the *implied* current norm matrix
-        (recomputed from the residents exactly as the kernel streams
-        it), so a mid-stream add is immediately in sync."""
-        if key in self._slots:
-            raise ValueError(f"duplicate member state {key!r}")
-        self._retired.discard(key)
-        idx = self._rows_of(rows, jobs)
-        if not self._free:
-            self._grow()
-        slot = self._free.pop()
-        row_mask = np.zeros(self._n_jobs, dtype=np.float32)
-        row_mask[idx] = 1.0
-        counts = self._mask[idx].sum(axis=0) if idx.size else \
-            np.zeros(len(self.config_ids), dtype=np.int64)
-        d_row = jnp.asarray(row_mask)
-        self.d_row_masks = self.d_row_masks.at[slot].set(d_row)
-        self.d_scores = self.d_scores.at[slot].set(
-            _helper_fns()[1](self.d_hours, self.d_mask, self.d_prices,
-                             self.d_row_best, d_row))
-        self._counts[slot] = counts
-        self._d_finite = self._d_finite.at[slot].set(
-            jnp.asarray(counts > 0))
-        self._slots[key] = slot
+    def _new_member_scores(self, d_row):
+        """A new member's accumulators from the *implied* current norm
+        matrix (recomputed from the residents exactly as the kernel
+        streams it), so a mid-stream add is immediately in sync."""
+        return _helper_fns()[1](self.d_hours, self.d_mask, self.d_prices,
+                                self.d_row_best, d_row)
+
+    def _dispatch_ingest(self, idx, vals) -> None:
+        (self.d_hours, self.d_mask, self.d_row_best, self.d_scores,
+         self._d_finite) = _helper_fns()[2](
+            self.d_hours, self.d_mask, self.d_row_best, self.d_scores,
+            self._d_finite, self.d_prices, self.d_row_masks, idx, vals)
 
     # -- the fused tick -----------------------------------------------------
     @property

@@ -87,6 +87,9 @@ FLEET_BACKENDS = ("jax_batched", "jax_sharded", "jax_pallas")
 #: results; the host assembly of a head lies outside both
 TOPK_DISPATCH_SPAN = "topk.dispatch"
 TOPK_READBACK_SPAN = "topk.readback"
+#: span name of a fleet state's profile-ingest enqueue (host preparation
+#: outside it, no sync inside it)
+INGEST_DISPATCH_SPAN = "ingest.dispatch"
 #: backends whose runtime dependency is jax.
 _JAX_FAMILY = ("jax", "jax_batched", "jax_sharded", "jax_pallas")
 
@@ -620,6 +623,16 @@ if _HAVE_JAX:
     #: initialized reads (regression-stressed in tests/test_kernels.py)
     _JAX_FNS_LOCK = threading.Lock()
 
+    def _cost_of(hours, mask, prices):
+        """Masked cost: runtime times price, ``+inf`` where unprofiled.
+        With :func:`_norm_of`, the one float32 expression every jitted
+        step (cold, price delta, profile ingest) computes a cell with."""
+        return jnp.where(mask, hours * prices, jnp.inf)
+
+    def _norm_of(cost, mask, row_best):
+        """Normalised cost at the rows' minima, 0 where unprofiled."""
+        return jnp.where(mask, cost / row_best[:, None], 0.0)
+
     def _delta_universe_update(prices, cost, row_best, hours, mask,
                                cols, new_prices):
         """The shared universe half of every jitted delta step (traced
@@ -638,9 +651,7 @@ if _HAVE_JAX:
           bucket padding introduces.
         """
         sub_mask = mask[:, cols]
-        new_cost = jnp.where(sub_mask,
-                             hours[:, cols] * new_prices[None, :],
-                             jnp.inf)
+        new_cost = _cost_of(hours[:, cols], sub_mask, new_prices[None, :])
         old_cost = cost[:, cols]
         prices = prices.at[cols].set(new_prices)
         cost = cost.at[:, cols].set(new_cost)
@@ -650,10 +661,43 @@ if _HAVE_JAX:
                           row_best)
         moved = fresh != row_best
         row_best = fresh
-        fresh_rows = jnp.where(mask, cost / row_best[:, None], 0.0)
-        col_norm = jnp.where(sub_mask,
-                             cost[:, cols] / row_best[:, None], 0.0)
+        fresh_rows = _norm_of(cost, mask, row_best)
+        col_norm = _norm_of(cost[:, cols], sub_mask, row_best)
         return prices, cost, row_best, fresh_rows, moved, col_norm
+
+    def _unpack_cells(idx, vals):
+        """An ingest's operands, packed host-side into one int32 and one
+        float32 array (two transfers, not five): ``idx`` holds the
+        cells' rows, their columns and the touched rows, ``vals`` the
+        cells' runtimes and the touched rows' weights."""
+        n = idx.shape[0] - vals.shape[0]
+        return idx[:n], idx[n:2 * n], vals[:n], idx[2 * n:], vals[n:]
+
+    def _ingest_universe(hours, mask, prices, rows, cols, new_hours,
+                         trows):
+        """The universe half of a profile ingest: write the new
+        runtimes at ``(rows, cols)`` (the padded duplicates of a bucket
+        re-set the same cell, so ``.set`` is idempotent) and recompute
+        the touched rows ``trows`` whole -- their cost, masked minimum
+        and normalised row -- with the cold path's expressions."""
+        hours = hours.at[rows, cols].set(new_hours)
+        mask = mask.at[rows, cols].set(True)
+        t_mask = mask[trows]
+        t_cost = _cost_of(hours[trows], t_mask, prices)
+        t_best = t_cost.min(axis=1)
+        return hours, mask, t_cost, t_best, _norm_of(t_cost, t_mask,
+                                                     t_best)
+
+    def _fold_rows(scores, finite, row_masks, trows, row_w, row_delta,
+                   t_mask):
+        """Fold the touched rows' change of normalised cost into every
+        member holding them (``row_masks[:, trows] @ delta``, the
+        bucket's padded rows weighted 0), and mark the configurations a
+        new cell made finite for those members."""
+        w = row_masks[:, trows] * row_w[None, :]
+        scores = scores + _fleet_matmul(w, row_delta)
+        finite = finite | (_fleet_matmul(w, t_mask.astype(w.dtype)) > 0)
+        return scores, finite
 
     def _fleet_matmul(a, b):
         """The member-axis reductions of every fleet step, at float32
@@ -703,9 +747,9 @@ if _HAVE_JAX:
         def cold(hours, mask, prices):
             # the cold-path arithmetic (float32): the state a delta
             # stream starts from
-            cost = jnp.where(mask, hours * prices[None, :], jnp.inf)
+            cost = _cost_of(hours, mask, prices[None, :])
             row_best = jnp.min(cost, axis=1)
-            norm = jnp.where(mask, cost / row_best[:, None], 0.0)
+            norm = _norm_of(cost, mask, row_best)
             return cost, row_best, norm, norm.sum(axis=0)
 
         def step(prices, cost, row_best, norm, scores, hours, mask,
@@ -956,6 +1000,39 @@ if _HAVE_JAX:
                             jax.jit(member_scores))
         return _JAX_BATCHED_FNS
 
+    _JAX_INGEST_FN: Optional[Any] = None
+
+    def _jax_ingest_fn() -> Any:
+        """The jitted profile-ingest step of :class:`BatchedRankState`,
+        built once on first use: new runtimes written into the resident
+        universe, the touched rows recomputed whole
+        (:func:`_ingest_universe`) and their change folded into every
+        member (:func:`_fleet_matmul` at HIGHEST, like the reprice
+        step).  Donates the seven buffers it rewrites (not on CPU)."""
+        global _JAX_INGEST_FN
+        if _JAX_INGEST_FN is None:
+            with _JAX_FNS_LOCK:
+                if _JAX_INGEST_FN is None:
+                    def ingest(hours, mask, cost, row_best, norm, scores,
+                               finite, prices, row_masks, idx, vals):
+                        rows, cols, new_hours, trows, row_w = \
+                            _unpack_cells(idx, vals)
+                        (hours, mask, t_cost, t_best,
+                         t_norm) = _ingest_universe(hours, mask, prices,
+                                                    rows, cols, new_hours,
+                                                    trows)
+                        scores, finite = _fold_rows(
+                            scores, finite, row_masks, trows, row_w,
+                            t_norm - norm[trows], mask[trows])
+                        return (hours, mask, cost.at[trows].set(t_cost),
+                                row_best.at[trows].set(t_best),
+                                norm.at[trows].set(t_norm), scores, finite)
+
+                    donate = () if jax.default_backend() == "cpu" \
+                        else tuple(range(7))
+                    _JAX_INGEST_FN = jax.jit(ingest, donate_argnums=donate)
+        return _JAX_INGEST_FN
+
 
 class BatchedRankState:
     """One device dispatch per tick for a whole fleet of rankings.
@@ -1019,7 +1096,7 @@ class BatchedRankState:
         self._pos = _position_index(self.config_ids)
         self._job_pos = (None if self.job_ids is None else
                          {j: i for i, j in enumerate(self.job_ids)})
-        self._mask = mask                     # host copy: member counts
+        self._mask = mask.copy()              # host copy: member counts
         self._n_jobs = hours.shape[0]
         cold = _jax_state_fns()[0]
         self._step, self._member_scores = _jax_batched_fns()
@@ -1045,6 +1122,8 @@ class BatchedRankState:
         self._free: List[int] = list(range(cap - 1, -1, -1))
         self.d_row_masks = jnp.zeros((cap, self._n_jobs),
                                      dtype=jnp.float32)
+        #: host copy of the row masks: which members a new cell counts in
+        self._member_rows = np.zeros((cap, self._n_jobs), dtype=bool)
         self.d_scores = jnp.zeros((cap, len(self.config_ids)),
                                   dtype=jnp.float32)
         self._counts = np.zeros((cap, len(self.config_ids)),
@@ -1075,6 +1154,8 @@ class BatchedRankState:
                                 else m.counter("rank.head_batches"))
         self._c_head_hits = (None if m is None
                              else m.counter("rank.head_memo_hits"))
+        self._c_ingests = (None if m is None
+                           else m.counter("rank.ingest_batches"))
 
     # -- member management --------------------------------------------------
     def __contains__(self, key: Hashable) -> bool:
@@ -1087,6 +1168,11 @@ class BatchedRankState:
 
     def keys(self) -> List[Hashable]:
         return list(self._slots)
+
+    def has_job(self, job_id: Hashable) -> bool:
+        """Is ``job_id`` a row of the universe (what :meth:`ingest`
+        can write)?"""
+        return self._job_pos is not None and job_id in self._job_pos
 
     def _slot_of(self, key: Hashable) -> int:
         try:
@@ -1115,6 +1201,9 @@ class BatchedRankState:
         counts = np.zeros((cap, len(self.config_ids)), dtype=np.int64)
         counts[:self._capacity] = self._counts
         self._counts = counts
+        member_rows = np.zeros((cap, self._n_jobs), dtype=bool)
+        member_rows[:self._capacity] = self._member_rows
+        self._member_rows = member_rows
         self._free.extend(range(cap - 1, self._capacity - 1, -1))
         self._capacity = cap
         self.realloc_count += 1
@@ -1160,12 +1249,17 @@ class BatchedRankState:
             np.zeros(len(self.config_ids), dtype=np.int64)
         d_row = jnp.asarray(row_mask)
         self.d_row_masks = self.d_row_masks.at[slot].set(d_row)
+        self._member_rows[slot] = row_mask > 0
         self.d_scores = self.d_scores.at[slot].set(
-            self._member_scores(self.d_norm, d_row))
+            self._new_member_scores(d_row))
         self._counts[slot] = counts
         self._d_finite = self._d_finite.at[slot].set(
             jnp.asarray(counts > 0))
         self._slots[key] = slot
+
+    def _new_member_scores(self, d_row):
+        """A new member's accumulators from the current shared norm."""
+        return self._member_scores(self.d_norm, d_row)
 
     def retire_state(self, key: Hashable) -> None:
         """Drop a member: its slot is zero-masked (contributes nothing
@@ -1179,6 +1273,7 @@ class BatchedRankState:
             raise ValueError(f"unknown member state {key!r}")
         zeros_j = jnp.zeros(self._n_jobs, dtype=jnp.float32)
         self.d_row_masks = self.d_row_masks.at[slot].set(zeros_j)
+        self._member_rows[slot] = False
         self.d_scores = self.d_scores.at[slot].set(
             jnp.zeros(len(self.config_ids), dtype=jnp.float32))
         self._counts[slot] = 0
@@ -1225,6 +1320,92 @@ class BatchedRankState:
         self.reprices += 1
         self.dispatches += 1
         return int(moved)
+
+    # -- profile ingest ---------------------------------------------------
+    def ingest(self, cells: Sequence[Tuple[Hashable, Hashable, float]]
+               ) -> int:
+        """Write ``(job, config, runtime hours)`` cells into the resident
+        universe and refresh every member in one jitted dispatch, with
+        no host sync: the touched job rows are recomputed whole (cost,
+        masked minimum, normalised row) and their change is folded into
+        each member that holds them, so a re-run that moves a row's
+        minimum, or a new cell that makes a configuration rankable for a
+        member, lands without a cold rebuild.  Jobs must be on the job
+        axis and configurations in the catalog (``ValueError``
+        otherwise); a later cell overwrites an earlier one.  Cells are
+        padded to power-of-4 buckets (touched rows too, weighted 0), so
+        the step compiles O(log) variants.  Returns the cells written."""
+        prepared = self._prepared_cells(cells)
+        if prepared is None:
+            return 0
+        operands, n = prepared
+        with maybe_span(self._metrics, INGEST_DISPATCH_SPAN):
+            self._dispatch_ingest(*operands)
+        # every score, finite flag and ranking may have moved: the heads
+        # memo sees new buffers; the per-member sorts are dropped
+        self._ranking_memo.clear()
+        if self._c_ingests is not None:
+            self._c_ingests.inc()
+        return n
+
+    def _prepared_cells(self, cells):
+        """Host half of :meth:`ingest`: ids to positions, runtimes
+        checked, the host mask and member counts updated; returns the
+        bucket-padded device operands packed for :func:`_unpack_cells`
+        and the distinct cells, or ``None`` for an empty batch."""
+        if self._job_pos is None:
+            raise ValueError("ingest needs a state constructed with "
+                             "job_ids")
+        cells = list(cells)
+        if not cells:
+            return None
+        try:
+            rows = np.array([self._job_pos[j] for j, _, _ in cells],
+                            dtype=np.int32)
+            cols = np.array([self._pos[c] for _, c, _ in cells],
+                            dtype=np.int32)
+        except KeyError as e:
+            raise ValueError(f"unknown job or config id in ingested "
+                             f"cells: {e.args[0]!r}")
+        hours = np.array([h for _, _, h in cells], dtype=np.float64)
+        bad = np.flatnonzero(~((hours > 0) & (hours < np.inf)))
+        if bad.size:
+            job, config, _ = cells[bad[0]]
+            raise ValueError(f"non-positive or non-finite runtime for "
+                             f"{job!r} on {config!r}")
+        # a later cell overwrites an earlier one: keep each last write
+        key = rows.astype(np.int64) * len(self.config_ids) + cols
+        _, last = np.unique(key[::-1], return_index=True)
+        if last.size < key.size:
+            keep = np.sort(key.size - 1 - last)
+            rows, cols, hours = rows[keep], cols[keep], hours[keep]
+        n = rows.size
+        new = ~self._mask[rows, cols]
+        if new.any():
+            self._mask[rows[new], cols[new]] = True
+            # each new cell counts once in every member holding its row
+            np.add.at(self._counts.T, cols[new],
+                      self._member_rows[:, rows[new]].T)
+        trows = np.unique(rows)
+        t = trows.size
+        nb = _bucket_size(n, self._BUCKET_BASE)
+        tb = _bucket_size(t, 1)
+        # pads repeat the first cell (an idempotent re-set) and the
+        # first touched row at weight 0
+        idx = np.empty(2 * nb + tb, dtype=np.int32)
+        vals = np.zeros(nb + tb, dtype=np.float32)
+        idx[:nb], idx[nb:2 * nb], idx[2 * nb:] = rows[0], cols[0], trows[0]
+        idx[:n], idx[nb:nb + n], idx[2 * nb:2 * nb + t] = rows, cols, trows
+        vals[:nb] = hours[0]
+        vals[:n], vals[nb:nb + t] = hours, 1.0
+        return (idx, vals), n
+
+    def _dispatch_ingest(self, idx, vals) -> None:
+        (self.d_hours, self.d_mask, self.d_cost, self.d_row_best,
+         self.d_norm, self.d_scores, self._d_finite) = _jax_ingest_fn()(
+            self.d_hours, self.d_mask, self.d_cost, self.d_row_best,
+            self.d_norm, self.d_scores, self._d_finite, self.d_prices,
+            self.d_row_masks, idx, vals)
 
     # -- per-member serving -------------------------------------------------
     def ranking(self, key: Hashable) -> List[RankedConfig]:

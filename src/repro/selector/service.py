@@ -22,8 +22,8 @@ math itself:
 from __future__ import annotations
 
 import dataclasses
-from typing import (Any, Callable, Dict, Hashable, Mapping, Optional,
-                    Sequence, Tuple)
+from typing import (Any, Callable, Dict, Hashable, Iterable, Mapping,
+                    Optional, Sequence, Tuple)
 
 from repro.core.trace import JobClass
 from repro.obs import MetricsRegistry
@@ -310,8 +310,8 @@ class SelectionService:
         the journal only records a rejection when the selection is
         genuinely unrankable.  Returns True when anything was dropped.
         """
-        base_key = (self.store.version, job_class,
-                    tuple(sorted(exclude_groups)))
+        selection = (job_class, tuple(sorted(exclude_groups)))
+        base_key = (self.store.version,) + selection
         retired = False
         for cache in (self._cache, self._head_cache):
             for key in [k for k in cache if k[2:5] == base_key]:
@@ -320,10 +320,51 @@ class SelectionService:
         if self._states.pop(base_key, None) is not None:
             self._state_tags.pop(base_key, None)
             retired = True
-        if self._batched is not None and base_key in self._batched:
-            self._batched.retire_state(base_key)
+        if self._batched is not None and selection in self._batched:
+            self._batched.retire_state(selection)
             retired = True
         return retired
+
+    # -- profile arrivals ------------------------------------------------------
+    def ingest(self, cells: Iterable[Tuple[Hashable, Hashable, float]]
+               ) -> int:
+        """Write test-job executions, ``(job, config, runtime hours)``
+        cells, into the store while serving, and bring the live rankings
+        up to date.  On ``jax_batched`` and ``jax_pallas`` a fleet in
+        sync with the store takes the cells in one ingest dispatch
+        (:meth:`BatchedRankState.ingest`): its members, their slots and
+        the price tag survive.  A cell of a job the fleet does not hold
+        (a new row), the ``jax_sharded`` fleet and the per-state
+        backends fall back to the cold path: the live states are
+        dropped and rebuilt on demand, counted in
+        ``service.ingest_fallbacks`` (and the rebuild in
+        ``rank.cold_rebuilds``).  Cells of configurations outside the
+        catalog are stored and rank nowhere.  Cached rankings are
+        dropped either way.  A served tick applies its records before
+        its prices (:class:`~repro.market.ServeFrontend`).  Returns the
+        cells written."""
+        with self.metrics.span("ingest.apply"):
+            synced = (self._batched is not None and
+                      self._batched_store_version == self.store.version)
+            written = self.store.add_cells(cells)
+            if not written:
+                return 0
+            self._cache.clear()
+            self._head_cache.clear()
+            b = self._batched
+            if synced and isinstance(b, BatchedRankState) and \
+                    all(b.has_job(j) for j, _, _ in written):
+                b.ingest([cell for cell in written
+                          if cell[1] in self.catalog])
+                self._batched_store_version = self.store.version
+            elif b is not None or self._states:
+                self._batched = None
+                self._batched_tag = None
+                self._batched_store_version = None
+                self._states.clear()
+                self._state_tags.clear()
+                self.metrics.counter("service.ingest_fallbacks").inc()
+        return len(written)
 
     # -- ranking (cached) ----------------------------------------------------
     def _live_serving(self, base_key: Tuple, tag: Tuple
@@ -336,11 +377,12 @@ class SelectionService:
         ``None`` when the selection must be built cold."""
         if self.backend in FLEET_BACKENDS:
             b = self._batched
+            member = base_key[1:]
             if b is not None and self._batched_tag == tag and \
                     self._batched_store_version == self.store.version \
-                    and base_key in b:
-                return (lambda: b.ranking(base_key),
-                        lambda k: b.top_k(base_key, k))
+                    and member in b:
+                return (lambda: b.ranking(member),
+                        lambda k: b.top_k(member, k))
             return None
         state = self._states.get(base_key)
         if state is not None and self._state_tags.get(base_key) == tag:
@@ -382,13 +424,17 @@ class SelectionService:
                 b = fleet_cls(hours, mask, prices, config_ids,
                               job_ids=all_jobs,
                               metrics=self.metrics)
+                self.metrics.counter("rank.cold_rebuilds").inc()
                 self._batched = b
                 self._batched_tag = tag
                 self._batched_store_version = self.store.version
-            if base_key not in b:
-                b.add_state(base_key, jobs=jobs)
-            return (lambda: b.ranking(base_key),
-                    lambda k: b.top_k(base_key, k))
+            # fleet members are keyed by selection alone: an ingest
+            # keeps them in sync across store versions
+            member = base_key[1:]
+            if member not in b:
+                b.add_state(member, jobs=jobs)
+            return (lambda: b.ranking(member),
+                    lambda k: b.top_k(member, k))
         hours, mask = self.store.matrix(job_ids=jobs, config_ids=config_ids)
         # build through a live state so later reprices are incremental:
         # RankState's arithmetic is the cold numpy path verbatim

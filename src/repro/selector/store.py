@@ -123,6 +123,44 @@ class ProfilingStore:
         self._hours[r, c] = runtime_hours
         self.version += 1
 
+    def add_cells(self, cells: Iterable[Tuple[Hashable, Hashable, float]]
+                  ) -> Tuple[Tuple[Hashable, Hashable, float], ...]:
+        """Record a batch of ``(job, config, runtime hours)`` cells with
+        one version bump: test-job executions arriving while the store
+        serves.  Every runtime is checked (finite and positive) before
+        any cell is written, so a bad batch writes nothing.  Later cells
+        overwrite earlier ones, as :meth:`add` overwrites re-profiled
+        cells; a job seen for the first time joins with no class or
+        group.  Returns the cells as written (hours as floats), the
+        change this version made."""
+        batch = tuple((j, c, float(h)) for j, c, h in cells)
+        for j, c, h in batch:
+            if not 0 < h < np.inf:
+                raise ValueError(
+                    f"non-positive or non-finite runtime for {j!r} on "
+                    f"{c!r}")
+        if not batch:
+            return batch
+        for j, c, h in batch:
+            r = self._add_job(j, None, None)
+            self._hours[r, self._add_config(c)] = h
+        self.version += 1
+        self.metrics.counter("store.cells_ingested").inc(len(batch))
+        return batch
+
+    def copy(self) -> "ProfilingStore":
+        """An independent store holding the same cells, metadata and
+        version (a fresh metrics registry)."""
+        out = ProfilingStore()
+        out._config_ids = list(self._config_ids)
+        out._config_pos = dict(self._config_pos)
+        out._job_ids = list(self._job_ids)
+        out._job_pos = dict(self._job_pos)
+        out._meta = dict(self._meta)
+        out._hours = self._hours.copy()
+        out.version = self.version
+        return out
+
     # -- accessors ---------------------------------------------------------
     @property
     def config_ids(self) -> List[Hashable]:

@@ -115,3 +115,30 @@ def test_wkv6_compiles_for_v5e_at_rwkv6_3b_widths(shape):
     lowered = fn.lower(x, x, x, x, shape((H, N), jnp.bfloat16),
                        shape((1, H, N, N), jnp.float32))
     _assert_kernel(lowered.compile())
+
+
+@pytest.mark.parametrize("backend", ["jax_batched", "jax_pallas"])
+def test_profile_ingest_step_compiles_for_v5e(shape, backend):
+    """The fleet's ingest step (XLA, no Pallas kernel) at the benchmark's
+    profiling deployment: 18 jobs x 15,360 configurations, 16 member
+    slots, one record's 80 cells in a 128-cell bucket, one touched row."""
+    from repro.selector import rank
+    from repro.selector.pallas_rank import _helper_fns
+    f32, i32 = jnp.float32, jnp.int32
+    j, c, s = 18, 15_360, 16
+    cells = (shape((2 * 128 + 1,), i32), shape((128 + 1,), f32))
+    if backend == "jax_batched":
+        args = (shape((j, c), f32), shape((j, c), jnp.bool_),
+                shape((j, c), f32), shape((j,), f32), shape((j, c), f32),
+                shape((s, c), f32), shape((s, c), jnp.bool_),
+                shape((c,), f32), shape((s, j), f32)) + cells
+        step = rank._jax_ingest_fn()
+    else:
+        jp = 24                                 # padded to 8-row tiles
+        args = (shape((jp, c), f32), shape((jp, c), jnp.bool_),
+                shape((jp, 1), f32), shape((s, c), f32),
+                shape((s, c), jnp.bool_), shape((1, c), f32),
+                shape((s, jp), f32)) + cells
+        step = _helper_fns()[2]
+    compiled = step.lower(*args).compile()
+    assert compiled.memory_analysis() is not None

@@ -495,7 +495,7 @@ def test_batched_retired_member_raises_typed_not_raw():
     svc = SelectionService(IdentityCatalog(ids), store, PriceTable(base),
                            backend="jax_batched")
     d1 = svc.submit("j1")
-    base_key = (store.version, JobClass.A, ("g1",))
+    base_key = (JobClass.A, ("g1",))     # fleet members: the selection
     assert svc._batched is not None and base_key in svc._batched
     assert svc.retire_selection(JobClass.A, ("g1",)) is True
     with pytest.raises(NothingRankableError, match="retired"):
