@@ -82,9 +82,10 @@ BACKENDS = ("numpy", "jax", "jax_batched", "jax_sharded", "jax_pallas")
 FLEET_BACKENDS = ("jax_batched", "jax_sharded", "jax_pallas")
 #: span names of a device ``top_k``: the enqueue of the jitted call
 #: (per head on the per-state and sharded states, with their slot lookup
-#: and slices; once per state change over every slot on
-#: :class:`BatchedRankState`) and the blocking readback of its two
-#: results; the host assembly of a head lies outside both
+#: and slices; on :class:`BatchedRankState` once over every slot per
+#: state change whose heads the reprice did not already take) and the
+#: blocking readback of its two results; the host assembly of a head
+#: lies outside both
 TOPK_DISPATCH_SPAN = "topk.dispatch"
 TOPK_READBACK_SPAN = "topk.readback"
 #: span name of a fleet state's profile-ingest enqueue (host preparation
@@ -706,24 +707,27 @@ if _HAVE_JAX:
         ScoreContract's 1e-4."""
         return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
 
+    def _masked_top_k(scores, finite, k):
+        """``jax.lax.top_k`` over the last axis of the (possibly
+        batched) score buffer with unprofiled configs masked to
+        ``+inf``: ``(idx, vals)``.  Scores rank ascending (lower is
+        better), so the kernel negates; ``lax.top_k`` breaks value ties
+        by lower index, which after negation is exactly the
+        catalog-order tie-break of :func:`_materialize` -- and makes the
+        depth-``k`` head the prefix of every deeper one."""
+        masked = jnp.where(finite, scores, jnp.inf)
+        neg, idx = jax.lax.top_k(-masked, k)
+        return idx, -neg
+
     def _jax_topk_fn() -> Any:
-        """``topk(scores, finite, k)`` — ``jax.lax.top_k`` over the
-        (possibly batched) score buffer with unprofiled configs masked
-        to ``+inf``.  Scores rank ascending (lower is better), so the
-        kernel negates; ``lax.top_k`` breaks value ties by lower index,
-        which after negation is exactly the catalog-order tie-break of
-        :func:`_materialize`.  ``k`` is static — one compile per
-        requested depth, the same O(distinct shapes) discipline as the
-        delta buckets."""
+        """``topk(scores, finite, k)``: :func:`_masked_top_k` jitted.
+        ``k`` is static — one compile per requested depth, the same
+        O(distinct shapes) discipline as the delta buckets."""
         global _JAX_TOPK_FN
         if _JAX_TOPK_FN is None:
             with _JAX_FNS_LOCK:
                 if _JAX_TOPK_FN is None:
-                    def topk(scores, finite, k):
-                        masked = jnp.where(finite, scores, jnp.inf)
-                        neg, idx = jax.lax.top_k(-masked, k)
-                        return idx, -neg
-                    _JAX_TOPK_FN = jax.jit(topk, static_argnums=2)
+                    _JAX_TOPK_FN = jax.jit(_masked_top_k, static_argnums=2)
         return _JAX_TOPK_FN
 
     def _jax_state_fns() -> Tuple[Any, Any, Any]:
@@ -936,10 +940,36 @@ class JaxRankState:
 # --- batched multi-state repricing (jax_batched backend) --------------------------
 
 if _HAVE_JAX:
-    _JAX_BATCHED_FNS: Optional[Tuple[Any, Any]] = None
+    _JAX_BATCHED_FNS: Optional[Tuple[Any, Any, Any]] = None
 
-    def _jax_batched_fns() -> Tuple[Any, Any]:
-        """``(step, member_scores)`` jitted kernels for
+    def _batched_step(prices, cost, row_best, norm, scores, hours, mask,
+                      row_masks, cols, new_prices):
+        """The batched delta step's traced body, shared by the plain
+        step and the step that also takes the fleet's heads
+        (:func:`_jax_batched_fns`)."""
+        # the universe half is the SAME traced helper as the
+        # per-state kernel — the backends cannot diverge on it
+        (prices, cost, row_best, fresh_rows, moved,
+         col_norm) = _delta_universe_update(prices, cost, row_best,
+                                            hours, mask, cols,
+                                            new_prices)
+        # -- handed-off rows: fold the renormalization delta into
+        #    every member's standing accumulators at once (S×J @
+        #    J×C; rows that did not move contribute exact zeros, so
+        #    a tick with no handoffs is drift-free here)
+        row_delta = jnp.where(moved[:, None], fresh_rows - norm, 0.0)
+        scores = scores + _fleet_matmul(row_masks, row_delta)
+        norm = jnp.where(moved[:, None], fresh_rows, norm)
+        # -- changed columns: re-reduce every member from scratch
+        #    with a .set — idempotent under the duplicate indices
+        #    the power-of-4 bucket padding introduces
+        norm = norm.at[:, cols].set(col_norm)
+        scores = scores.at[:, cols].set(_fleet_matmul(row_masks,
+                                                      col_norm))
+        return prices, cost, row_best, norm, scores, moved.sum()
+
+    def _jax_batched_fns() -> Tuple[Any, Any, Any]:
+        """``(step, step_heads, member_scores)`` jitted kernels for
         :class:`BatchedRankState`, built once on first use.
 
         The key observation that makes batching cheap (DESIGN.md §10):
@@ -956,7 +986,10 @@ if _HAVE_JAX:
         refreshes *all* member accumulators with two small matmuls
         (handed-off-row deltas folded in; changed columns re-reduced
         from scratch) — one dispatch for the whole fleet, independent
-        of the member count."""
+        of the member count.  ``step_heads(..., finite, k)`` is the same
+        step followed by :func:`_masked_top_k` over the new scores at
+        the static depth ``k``: the tick and every slot's head in one
+        program."""
         global _JAX_BATCHED_FNS
         if _JAX_BATCHED_FNS is not None:
             return _JAX_BATCHED_FNS
@@ -965,39 +998,24 @@ if _HAVE_JAX:
                 return _JAX_BATCHED_FNS
             return _build_jax_batched_fns()
 
-    def _build_jax_batched_fns() -> Tuple[Any, Any]:
+    def _build_jax_batched_fns() -> Tuple[Any, Any, Any]:
         global _JAX_BATCHED_FNS
 
-        def step(prices, cost, row_best, norm, scores, hours, mask,
-                 row_masks, cols, new_prices):
-            # the universe half is the SAME traced helper as the
-            # per-state kernel — the backends cannot diverge on it
-            (prices, cost, row_best, fresh_rows, moved,
-             col_norm) = _delta_universe_update(prices, cost, row_best,
-                                                hours, mask, cols,
-                                                new_prices)
-            # -- handed-off rows: fold the renormalization delta into
-            #    every member's standing accumulators at once (S×J @
-            #    J×C; rows that did not move contribute exact zeros, so
-            #    a tick with no handoffs is drift-free here)
-            row_delta = jnp.where(moved[:, None], fresh_rows - norm, 0.0)
-            scores = scores + _fleet_matmul(row_masks, row_delta)
-            norm = jnp.where(moved[:, None], fresh_rows, norm)
-            # -- changed columns: re-reduce every member from scratch
-            #    with a .set — idempotent under the duplicate indices
-            #    the power-of-4 bucket padding introduces
-            norm = norm.at[:, cols].set(col_norm)
-            scores = scores.at[:, cols].set(_fleet_matmul(row_masks,
-                                                          col_norm))
-            return prices, cost, row_best, norm, scores, moved.sum()
+        def step_heads(prices, cost, row_best, norm, scores, hours, mask,
+                       row_masks, cols, new_prices, finite, k):
+            out = _batched_step(prices, cost, row_best, norm, scores,
+                                hours, mask, row_masks, cols, new_prices)
+            return out + _masked_top_k(out[4], finite, k)
 
         def member_scores(norm, row_mask):
             # a new member's accumulators from the current shared norm
             return _fleet_matmul(row_mask, norm)
 
         donate = () if jax.default_backend() == "cpu" else (0, 1, 2, 3, 4)
-        _JAX_BATCHED_FNS = (jax.jit(step, donate_argnums=donate),
-                            jax.jit(member_scores))
+        _JAX_BATCHED_FNS = (
+            jax.jit(_batched_step, donate_argnums=donate),
+            jax.jit(step_heads, donate_argnums=donate, static_argnums=11),
+            jax.jit(member_scores))
         return _JAX_BATCHED_FNS
 
     _JAX_INGEST_FN: Optional[Any] = None
@@ -1067,6 +1085,9 @@ class BatchedRankState:
     membership changed runs ONE ``jax.lax.top_k`` over every slot and
     reads the (capacity, k) result back once; every later head, of any
     member, until the next change is a host slice of that readback.
+    Once heads are being read, a :meth:`reprice` takes them in its own
+    program and readback, so a tick that reprices and then publishes
+    makes one round trip to the device, not two.
 
     **Contract** (:data:`SCORE_CONTRACTS` ``["jax_batched"]``): same
     float32 tolerance envelope as the per-state jax kernel — batching
@@ -1099,7 +1120,8 @@ class BatchedRankState:
         self._mask = mask.copy()              # host copy: member counts
         self._n_jobs = hours.shape[0]
         cold = _jax_state_fns()[0]
-        self._step, self._member_scores = _jax_batched_fns()
+        (self._step, self._step_heads,
+         self._member_scores) = _jax_batched_fns()
         # shared read-only residents (uploaded once, never donated)
         self.d_hours = jnp.asarray(hours, dtype=jnp.float32)
         self.d_mask = jnp.asarray(mask)
@@ -1147,6 +1169,9 @@ class BatchedRankState:
         #: valid while both buffers are the arrays it was computed from
         #: (:meth:`_fleet_heads`)
         self._head_memo: Tuple[Any, Any, dict] = (None, None, {})
+        #: head depths asked since the previous reprice: the next
+        #: reprice takes the heads in its own program (:meth:`reprice`)
+        self._asked: "set[int]" = set()
         m = self._metrics
         self._c_mat = (None if m is None
                        else m.counter("rank.materializations"))
@@ -1154,6 +1179,8 @@ class BatchedRankState:
                                 else m.counter("rank.head_batches"))
         self._c_head_hits = (None if m is None
                              else m.counter("rank.head_memo_hits"))
+        self._c_reprice_heads = (None if m is None
+                                 else m.counter("rank.reprice_heads"))
         self._c_ingests = (None if m is None
                            else m.counter("rank.ingest_batches"))
 
@@ -1306,17 +1333,38 @@ class BatchedRankState:
         and refresh **every** member's accumulators in one batched
         kernel dispatch; returns #rows whose masked row-minimum handed
         off (synced to host, so a return means the tick's kernel has
-        completed)."""
+        completed).
+
+        When heads were asked since the previous reprice
+        (:meth:`_fleet_heads`), the same program also runs the fleet's
+        ``top_k`` over the new scores at the deepest depth asked, and
+        the one readback of the handoff count brings every slot's head
+        home into the heads memo, each asked depth a prefix of the
+        deepest (``lax.top_k`` keeps the lower index first on ties)."""
         prepared = _validated_delta_cols(self._pos, deltas,
                                          self._BUCKET_BASE)
         if prepared is None:
             return 0
         cols, new_prices = prepared
-        (self.d_prices, self.d_cost, self.d_row_best, self.d_norm,
-         self.d_scores, moved) = self._step(
-            self.d_prices, self.d_cost, self.d_row_best, self.d_norm,
-            self.d_scores, self.d_hours, self.d_mask, self.d_row_masks,
-            jnp.asarray(cols), jnp.asarray(new_prices, dtype=jnp.float32))
+        args = (self.d_prices, self.d_cost, self.d_row_best, self.d_norm,
+                self.d_scores, self.d_hours, self.d_mask, self.d_row_masks,
+                jnp.asarray(cols), jnp.asarray(new_prices,
+                                               dtype=jnp.float32))
+        asked, self._asked = self._asked, set()
+        if asked:
+            (self.d_prices, self.d_cost, self.d_row_best, self.d_norm,
+             self.d_scores, moved, idx, vals) = self._step_heads(
+                *args, self._d_finite, max(asked))
+            moved, idx, vals = jax.device_get((moved, idx, vals))
+            vals = vals.astype(np.float64)
+            self._head_memo = (self.d_scores, self._d_finite,
+                               {k: (idx[:, :k], vals[:, :k])
+                                for k in asked})
+            if self._c_reprice_heads is not None:
+                self._c_reprice_heads.inc()
+        else:
+            (self.d_prices, self.d_cost, self.d_row_best, self.d_norm,
+             self.d_scores, moved) = self._step(*args)
         self.reprices += 1
         self.dispatches += 1
         return int(moved)
@@ -1432,7 +1480,9 @@ class BatchedRankState:
         per ``k`` on the identity of ``d_scores`` and ``_d_finite``:
         every path that changes scores or membership (the reprice, add,
         retire, growth) assigns new arrays, so identity is the
-        state-change test."""
+        state-change test.  Each ``k`` asked, hit or miss, is recorded
+        for the next :meth:`reprice` to take in its own program."""
+        self._asked.add(k)
         scores, finite = self.d_scores, self._d_finite
         memo_scores, memo_finite, heads = self._head_memo
         if memo_scores is not scores or memo_finite is not finite:

@@ -658,12 +658,14 @@ def _fleet_programs():
     J, C, S, B = 8, 16, 4, 8
     f32, sd = jnp.float32, jax.ShapeDtypeStruct
     universe = (sd((J, C), f32), sd((J, C), jnp.bool_))
-    step, member = rank._jax_batched_fns()
+    step, step_heads, member = rank._jax_batched_fns()
     state = (sd((C,), f32), sd((J, C), f32), sd((J,), f32),
              sd((J, C), f32), sd((S, C), f32))
-    yield "jax_batched step", step.lower(
-        *state, *universe, sd((S, J), f32), sd((B,), jnp.int32),
-        sd((B,), f32))
+    tick = (*state, *universe, sd((S, J), f32), sd((B,), jnp.int32),
+            sd((B,), f32))
+    yield "jax_batched step", step.lower(*tick)
+    yield "jax_batched step+heads", step_heads.lower(
+        *tick, sd((S, C), jnp.bool_), 3)
     yield "jax_batched member", member.lower(sd((J, C), f32),
                                              sd((J,), f32))
     _, sh_step, sh_member = sharded._sharded_fns(1)

@@ -140,10 +140,167 @@ def test_head_counters_count_launches_per_state_change(backend):
     state.reprice({ids[6]: 1.5})                       # a state change
     for key in MEMBERS:
         state.top_k(key, 3)
-    assert _launches(reg) == (2, 7)
+    # the XLA fleet's reprice takes the depth asked before it in its own
+    # program: the memo answers all three heads after it
+    fused = backend == "jax_batched"
+    assert _launches(reg) == ((1, 8) if fused else (2, 7))
     state.retire_state("head")                         # another
     state.top_k("all", 3)
-    assert _launches(reg) == (3, 7)
+    assert _launches(reg) == ((2, 8) if fused else (3, 7))
     hists = reg.snapshot()["histograms"]
-    assert hists["topk.dispatch"]["count"] == 3
-    assert hists["topk.readback"]["count"] == 3
+    assert hists["topk.dispatch"]["count"] == (2 if fused else 3)
+    assert hists["topk.readback"]["count"] == (2 if fused else 3)
+
+
+# --- the heads taken by the XLA fleet's reprice ------------------------------------
+
+def _fused(reg):
+    return reg.snapshot()["counters"]["rank.reprice_heads"]
+
+
+def _separate_heads(state, k):
+    """Every slot's ``k``-head from a separate top-k launch."""
+    from repro.selector.rank import _jax_topk_fn
+    idx, vals = _jax_topk_fn()(state.d_scores, state._d_finite, k)
+    return np.asarray(idx), np.asarray(vals, dtype=np.float64)
+
+
+def _spy_depths(state):
+    """Record the depth of each fused reprice+top-k program ``state``
+    runs."""
+    depths, real = [], state._step_heads
+
+    def spy(*args):
+        depths.append(args[-1])
+        return real(*args)
+    state._step_heads = spy
+    return depths
+
+
+def test_every_shallower_head_is_a_prefix_of_the_deeper():
+    """``lax.top_k`` keeps the lower index first on exact ties, unprofiled
+    configurations included, so a depth-k head is the first k entries
+    of any deeper one: one launch at the deepest depth serves them all."""
+    state, _, ids = _state("jax_batched")
+    state.reprice({ids[3]: 0.5})
+    idx, vals = _separate_heads(state, N_CFGS)
+    for k in range(1, N_CFGS):
+        ik, vk = _separate_heads(state, k)
+        np.testing.assert_array_equal(ik, idx[:, :k])
+        np.testing.assert_array_equal(vk, vals[:, :k])
+
+
+def test_reprice_takes_the_heads_asked_before_it():
+    state, reg, ids = _state("jax_batched")
+    _assert_heads(state, MEMBERS, 3)
+    launched = _launches(reg)
+    # a clone column moves onto its twins' price: exact ties stay exact
+    state.reprice({ids[N_CFGS - 1]: state.prices[N_CFGS - 3],
+                   ids[1]: 0.9, ids[2]: 0.9, ids[6]: 0.02})
+    assert _fused(reg) == 1
+    idx, vals = _separate_heads(state, 3)
+    memo_idx, memo_vals = state._fleet_heads(3)
+    np.testing.assert_array_equal(memo_idx, idx)
+    np.testing.assert_array_equal(memo_vals, vals)
+    _assert_heads(state, MEMBERS, 3)
+    full = [r.config_id for r in state.ranking("all")]
+    i = full.index(ids[N_CFGS - 3])
+    assert full[i:i + 3] == ids[N_CFGS - 3:]
+    assert _launches(reg)[0] == launched[0]            # no launch of its own
+
+
+def test_two_depths_asked_before_a_reprice_take_one_program():
+    state, reg, ids = _state("jax_batched")
+    depths = _spy_depths(state)
+    for k in (1, 3):
+        _assert_heads(state, MEMBERS, k)
+    state.reprice({ids[4]: 3.0, ids[8]: 0.3})
+    assert depths == [3] and _fused(reg) == 1
+    launched = _launches(reg)[0]
+    for k in (1, 3):
+        memo = state._fleet_heads(k)
+        for got, want in zip(memo, _separate_heads(state, k)):
+            np.testing.assert_array_equal(got, want)
+        _assert_heads(state, MEMBERS, k)
+    assert _launches(reg)[0] == launched
+    state.reprice({ids[4]: 2.0})           # both depths asked again
+    assert depths == [3, 3]
+
+
+def test_a_reprice_with_no_depth_asked_is_the_plain_step():
+    state, reg, ids = _state("jax_batched")
+    depths = _spy_depths(state)
+    state.reprice({ids[5]: 0.2})
+    assert depths == [] and _fused(reg) == 0
+    assert _launches(reg) == (0, 0)
+    _assert_heads(state, MEMBERS, 3)                   # launched as before
+    assert _launches(reg) == (1, 2)
+    state.reprice({ids[5]: 0.3})                       # now the depth is asked
+    state.reprice({ids[5]: 0.4})                       # and used up
+    assert depths == [3] and _fused(reg) == 1
+
+
+def test_an_ingest_before_the_reprice_lands_in_the_heads_it_takes():
+    hours, mask, prices, ids = _universe()
+    jobs = [f"j{i}" for i in range(N_JOBS)]
+    mask[:, 0] = False
+    reg = MetricsRegistry()
+    state = BatchedRankState(hours, mask, prices, ids, job_ids=jobs,
+                             metrics=reg)
+    for key, rows in MEMBERS.items():
+        state.add_state(key, rows=rows)
+    _assert_heads(state, MEMBERS, 3)
+    state.ingest([("j1", ids[0], 1e-3), ("j4", ids[5], 1e-3)])
+    hours[1, 0] = hours[4, 5] = 1e-3
+    mask[1, 0] = mask[4, 5] = True
+    state.reprice({ids[7]: 0.05})
+    prices[7] = 0.05
+    launched = _launches(reg)[0]
+    cold = BatchedRankState(hours, mask, prices, ids, job_ids=jobs)
+    for key, rows in MEMBERS.items():
+        cold.add_state(key, rows=rows)
+        got = state.top_k(key, 3)
+        assert [r.config_id for r in got] == \
+            [r.config_id for r in cold.top_k(key, 3)]
+        assert [r.score for r in got] == pytest.approx(
+            [r.score for r in cold.top_k(key, 3)], rel=1e-5)
+    assert _fused(reg) == 1 and _launches(reg)[0] == launched
+
+
+@pytest.mark.parametrize("change", ["add", "retire"])
+def test_a_membership_change_after_the_reprice_launches_its_own_top_k(
+        change):
+    state, reg, ids = _state("jax_batched")
+    _assert_heads(state, MEMBERS, 3)
+    state.reprice({ids[9]: 0.07})
+    assert _fused(reg) == 1
+    launched = _launches(reg)[0]
+    if change == "add":
+        state.add_state("mid", rows=[1, 2, 3])
+        keys = list(MEMBERS) + ["mid"]
+    else:
+        state.retire_state("head")
+        keys = ["all", "tail"]
+    _assert_heads(state, keys, 3)
+    assert _launches(reg)[0] == launched + 1
+
+
+def test_reprice_returns_the_same_handoffs_with_and_without_a_depth():
+    plain, _, ids = _state("jax_batched")
+    fused, reg, _ = _state("jax_batched")
+    rng = np.random.default_rng(11)
+    moved = []
+    for tick in range(8):
+        fused.top_k("all", 2)
+        cols = rng.choice(np.arange(1, N_CFGS), 3, replace=False)
+        # every other tick one column drops far below its rows' minima
+        new = rng.uniform(0.5, 20.0, 3) if tick % 2 else \
+            np.array([1e-3, 5.0, 6.0])
+        deltas = {ids[c]: float(p) for c, p in zip(cols, new)}
+        a, b = plain.reprice(deltas), fused.reprice(deltas)
+        assert a == b
+        moved.append(a)
+    assert any(moved) and _fused(reg) == 8
+    for key in MEMBERS:
+        np.testing.assert_allclose(fused.scores(key), plain.scores(key),
+                                   rtol=1e-6)

@@ -384,6 +384,15 @@ def test_jax_topk_fn_first_call_races_build_once(monkeypatch):
         rank._jax_topk_fn, expected_jits=1)
 
 
+def test_jax_batched_fns_first_call_races_build_once(monkeypatch):
+    from repro.selector import rank
+
+    _stress_first_call(
+        monkeypatch,
+        lambda mp: mp.setattr(rank, "_JAX_BATCHED_FNS", None),
+        rank._jax_batched_fns, expected_jits=3)
+
+
 def test_rank_delta_fns_first_call_races_build_once(monkeypatch):
     _stress_first_call(
         monkeypatch,

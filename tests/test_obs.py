@@ -464,12 +464,9 @@ def test_tick_spans_nest_on_one_host_line_of_the_profiler_trace(tmp_path):
     assert reprice[3]["epoch"] == fe.service.price_epoch == 2
     assert inside(reprice, tick) and inside(build, tick)
     assert reprice[2] <= build[1]
-    heads = by["topk.dispatch"], by["topk.readback"]
-    # the fleet state serves both live heads from one top-k launch
-    assert [len(h) for h in heads] == [1, 1]
-    for dispatch, readback in zip(*heads):
-        assert inside(dispatch, build) and inside(readback, build)
-        assert dispatch[2] <= readback[1]
+    # the fleet's reprice took both live heads in its own program, so
+    # the publication launches and reads back no top-k of its own
+    assert "topk.dispatch" not in by and "topk.readback" not in by
     fe.close()
 
 
@@ -490,13 +487,15 @@ def test_disabled_spans_leave_no_histograms_and_no_annotations(tmp_path):
 
 
 @pytest.mark.parametrize("backend,per_head,per_build", [
-    ("numpy", 0, 0), ("jax", 1, 0), ("jax_batched", 0, 1),
+    ("numpy", 0, 0), ("jax", 1, 0), ("jax_batched", 0, 0),
     ("jax_pallas", 0, 1), ("jax_sharded", 1, 0)])
 def test_one_topk_dispatch_and_readback_per_live_selection(backend,
                                                            per_head,
                                                            per_build):
     """Per-state and sharded states launch and read back one top-k per
-    live head; the single-device fleet states one per publication."""
+    live head; the Pallas fleet one per publication; the XLA fleet none
+    in a steady tick, because its reprice takes the heads asked at the
+    previous publication."""
     if backend != "numpy":
         pytest.importorskip("jax")
     fe, _ = _frontend(backend, n_ticks=4)
@@ -520,6 +519,34 @@ def test_one_topk_dispatch_and_readback_per_live_selection(backend,
         "topk.readback": launches}
     if backend == "numpy":
         assert after["topk.dispatch"] == after["topk.readback"] == 0
+    fe.close()
+
+
+def test_each_steady_tick_of_the_xla_fleet_takes_its_heads_in_the_reprice():
+    """On ``jax_batched`` every tick after the first publication
+    reprices and reads the fleet's heads back in one program: the
+    ``rank.reprice_heads`` counter moves with the ticks, and no
+    publication launches a top-k of its own."""
+    pytest.importorskip("jax")
+    fe, _ = _frontend("jax_batched", n_ticks=6)
+    fe.warm([Submission("j1"), Submission("j2"),
+             Submission("j1", exclude_groups=("g0",))])
+    reg = fe.metrics_registry
+
+    def counts():
+        snap = reg.snapshot()
+        hists = snap["histograms"]
+        return {"ticks": snap["counters"].get("rank.reprice_heads", 0),
+                "launches": snap["counters"].get("rank.head_batches", 0),
+                "builds": hists["snapshot.build"]["count"],
+                "readbacks": hists.get("topk.readback",
+                                       {"count": 0})["count"]}
+    before = counts()
+    for _ in range(5):
+        assert fe.step_tick() == "tick"
+    after = counts()
+    assert {n: after[n] - before[n] for n in after} == {
+        "ticks": 5, "launches": 0, "builds": 5, "readbacks": 0}
     fe.close()
 
 
