@@ -2,7 +2,7 @@
 
     PYTHONPATH=src python benchmarks/rank_bench.py
 
-Prints ``name,cells,us_dict,us_numpy,us_jax,speedup`` CSV rows.  The
+Prints ``name,cells,us_dict,us_numpy,speedup`` CSV rows.  The
 acceptance bar: the vectorized formulation must beat the dict loop from
 ~1k (job x config) cells up (at 10k+ cells the ranking is one fused
 matrix op instead of ~cells dict lookups).
@@ -14,8 +14,7 @@ from typing import Callable, Dict, Hashable, List, Mapping, Sequence, Tuple
 
 import numpy as np
 
-from repro.launch.compile_cache import enable_compile_cache
-from repro.selector import BackendUnavailableError, rank_dense, rank_pairs
+from repro.selector import rank_dense, rank_pairs
 
 
 def rank_dict_loop(
@@ -50,7 +49,7 @@ def synth_universe(n_jobs: int, n_cfgs: int, seed: int = 0):
 
 
 def _timed(fn, repeat: int) -> float:
-    fn()                                    # warmup (jit compile, caches)
+    fn()                                    # warmup (caches)
     t0 = time.perf_counter()
     for _ in range(repeat):
         fn()
@@ -63,28 +62,21 @@ def compare(n_jobs: int, n_cfgs: int, repeat: int = 20) -> Dict[str, float]:
     us_dict = _timed(lambda: rank_dict_loop(pairs, jobs, cfgs, price_of),
                      repeat)
     us_numpy = _timed(lambda: rank_dense(hours, mask, prices, cfgs), repeat)
-    try:
-        us_jax = _timed(lambda: rank_dense(hours, mask, prices, cfgs,
-                                           backend="jax"), repeat)
-    except BackendUnavailableError:
-        us_jax = float("nan")
     # sanity: identical winner and ordering
     base = [c for c, _ in rank_dict_loop(pairs, jobs, cfgs, price_of)]
     vec = [r.config_id for r in rank_pairs(pairs, jobs, cfgs, price_of)]
     assert base == vec, "vectorized ranking diverged from the dict loop"
     return {"cells": n_jobs * n_cfgs, "us_dict": us_dict,
-            "us_numpy": us_numpy, "us_jax": us_jax,
-            "speedup": us_dict / us_numpy}
+            "us_numpy": us_numpy, "speedup": us_dict / us_numpy}
 
 
 def main() -> None:
-    enable_compile_cache()
-    print("name,cells,us_dict,us_numpy,us_jax,speedup")
+    print("name,cells,us_dict,us_numpy,speedup")
     for n_jobs, n_cfgs in ((10, 10), (50, 20), (100, 100), (500, 100),
                            (1000, 250)):
         r = compare(n_jobs, n_cfgs, repeat=5 if n_jobs >= 500 else 20)
         print(f"rank_{n_jobs}x{n_cfgs},{r['cells']},{r['us_dict']:.1f},"
-              f"{r['us_numpy']:.1f},{r['us_jax']:.1f},{r['speedup']:.1f}x")
+              f"{r['us_numpy']:.1f},{r['speedup']:.1f}x")
 
 
 if __name__ == "__main__":

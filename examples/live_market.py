@@ -20,7 +20,7 @@ from repro.market import (MarketEvent, SelectionDaemon, SimulatedSpotFeed,
 from repro.selector import PriceTable
 
 
-def build_service(backend=None):
+def build_service(backend="numpy"):
     options = [
         MeshOption("v5e-dp256xtp1", "v5e", 256, (256, 1), ("data", "model")),
         MeshOption("v5e-dp16xtp16", "v5e", 256, (16, 16), ("data", "model")),
@@ -47,11 +47,11 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--events", type=int, default=400)
     ap.add_argument("--seed", type=int, default=3)
-    ap.add_argument("--backend", default=None,
-                    choices=["numpy", "jax", "jax_batched", "jax_sharded",
+    ap.add_argument("--backend", default="numpy",
+                    choices=["numpy", "jax_batched", "jax_sharded",
                              "jax_pallas"],
-                    help="ranking backend (default: FLORA_RANK_BACKEND "
-                         "env var, else numpy); jax_batched stacks every "
+                    help="ranking backend (default: numpy); "
+                         "jax_batched stacks every "
                          "live ranking into one batched kernel — a tick "
                          "is ONE dispatch for the whole fleet "
                          "(DESIGN.md §10)")

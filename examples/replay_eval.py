@@ -50,7 +50,7 @@ FIXTURE = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
                        "gcp_spot_prices.csv")
 
 
-def build_service(backend=None):
+def build_service(backend="numpy"):
     """The paper universe (Tables I x II) behind a live price table."""
     trace = spark_sim.generate_trace(seed=0)
     store = ProfilingStore.from_trace(trace)
@@ -82,10 +82,11 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=3)
     ap.add_argument("--record", action="store_true",
                     help="regenerate the bundled fixture and exit")
-    ap.add_argument("--backend", default=None, choices=["numpy", "jax"],
-                    help="ranking backend (default: FLORA_RANK_BACKEND "
-                         "env var, else numpy); jax serves the "
-                         "accelerator-resident float32 path and the "
+    ap.add_argument("--backend", default="numpy",
+                    choices=["numpy", "jax_batched", "jax_sharded",
+                             "jax_pallas"],
+                    help="ranking backend (default: numpy); a fleet "
+                         "backend serves the float32 device path and the "
                          "audit runs in tolerance mode (DESIGN.md §9)")
     args = ap.parse_args()
 

@@ -41,11 +41,10 @@ def main() -> None:
     ap.add_argument("--submissions", type=int, default=300)
     ap.add_argument("--ticks", type=int, default=120)
     ap.add_argument("--seed", type=int, default=42)
-    ap.add_argument("--backend", default=None,
-                    choices=["numpy", "jax", "jax_batched", "jax_sharded",
+    ap.add_argument("--backend", default="numpy",
+                    choices=["numpy", "jax_batched", "jax_sharded",
                              "jax_pallas"],
-                    help="ranking backend (default: FLORA_RANK_BACKEND "
-                         "env var, else numpy)")
+                    help="ranking backend (default: numpy)")
     args = ap.parse_args()
 
     store, ids, base = build_universe()

@@ -206,8 +206,8 @@ class DynamicEvaluation:
     #: realized cost is unknowable from the trace, so they are excluded
     #: from the means but never silently dropped.
     skipped: int
-    #: ranking backend that produced the judged decisions ("numpy" |
-    #: "jax") — consumers of the report need to know which
+    #: ranking backend that produced the judged decisions (the journal
+    #: header's stamp) — consumers of the report need to know which
     #: :class:`repro.selector.ScoreContract` the journal was audited
     #: under before trusting per-decision scores (DESIGN.md §9).
     backend: str = "numpy"

@@ -54,7 +54,7 @@ class Flora:
         self.one_class = one_class
         # the paper-table reproduction is definitionally the float64
         # bit-stable contract (legacy dict-loop parity at 1e-12), so the
-        # adapter pins numpy regardless of FLORA_RANK_BACKEND
+        # adapter pins numpy
         self.service = SelectionService(
             GcpVmCatalog(trace.configs, price),
             ProfilingStore.from_trace(trace), price, backend="numpy")

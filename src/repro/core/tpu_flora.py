@@ -87,11 +87,9 @@ class WorkloadRecord:
 def make_service(options: Sequence[MeshOption],
                  records: Sequence[WorkloadRecord],
                  price: TpuPriceModel,
-                 backend: Optional[str] = None) -> SelectionService:
-    """Wire catalog + store + price into a TPU-side selection service.
-
-    ``backend`` selects the ranking backend (``None`` resolves via
-    :func:`repro.selector.default_backend`)."""
+                 backend: str = "numpy") -> SelectionService:
+    """Wire catalog + store + price into a TPU-side selection service
+    on the ranking ``backend``."""
     return SelectionService(
         TpuSliceCatalog(options, price),
         ProfilingStore.from_workload_records(

@@ -30,13 +30,11 @@ See DESIGN.md for the full architecture.
 from repro.selector.catalog import (BaseCatalog, GcpVmCatalog,
                                     IdentityCatalog, PriceTable,
                                     ResourceCatalog, TpuSliceCatalog)
-from repro.selector.rank import (BACKEND_ENV_VAR, BACKENDS,
-                                 FLEET_BACKENDS,
+from repro.selector.rank import (BACKENDS, FLEET_BACKENDS,
                                  BackendUnavailableError, BatchedRankState,
-                                 JaxRankState, NothingRankableError,
-                                 RankedConfig, RankState, SCORE_CONTRACTS,
-                                 ScoreContract, backend_available,
-                                 default_backend, rank_dense, rank_pairs,
+                                 NothingRankableError, RankedConfig,
+                                 RankState, SCORE_CONTRACTS, ScoreContract,
+                                 backend_available, rank_dense, rank_pairs,
                                  score_contract)
 from repro.selector.pallas_rank import PallasBatchedRankState
 from repro.selector.sharded import ShardedBatchedRankState
@@ -44,13 +42,11 @@ from repro.selector.store import ProfilingStore
 from repro.selector.service import Decision, SelectionService
 
 __all__ = [
-    "BACKEND_ENV_VAR", "BACKENDS", "BackendUnavailableError", "BaseCatalog",
+    "BACKENDS", "BackendUnavailableError", "BaseCatalog",
     "BatchedRankState", "Decision", "FLEET_BACKENDS", "GcpVmCatalog",
-    "IdentityCatalog", "JaxRankState",
-    "NothingRankableError", "PallasBatchedRankState", "PriceTable",
-    "ProfilingStore", "RankState",
+    "IdentityCatalog", "NothingRankableError", "PallasBatchedRankState",
+    "PriceTable", "ProfilingStore", "RankState",
     "RankedConfig", "ResourceCatalog", "SCORE_CONTRACTS", "ScoreContract",
     "SelectionService", "ShardedBatchedRankState", "TpuSliceCatalog",
-    "backend_available",
-    "default_backend", "rank_dense", "rank_pairs", "score_contract",
+    "backend_available", "rank_dense", "rank_pairs", "score_contract",
 ]

@@ -10,21 +10,19 @@ A config with **zero** profiled cells scores ``+inf`` and therefore ranks
 last (an unprofiled config must never win by default — the historical dict
 loop left it at 0.0, i.e. argmin).
 
-Two backends:
+Backends:
 
   * ``"numpy"`` (default): float64, bit-stable with the historical
-    per-pair arithmetic — used for the paper-table reproductions;
-  * ``"jax"``: a jitted ``jax.numpy`` kernel (float32 on CPU/TPU) that
-    fuses the whole ranking into one XLA computation — the serving-scale
-    path for 10k+ (job x config) cells, benchmarked in
-    ``benchmarks/rank_bench.py``.
+    per-pair arithmetic — the paper-table reproductions and the
+    reference every journal audit re-ranks against;
+  * ``"jax_batched"``, ``"jax_sharded"``, ``"jax_pallas"``: the fleet
+    backends (float32, on the device).
 
 Each backend carries an explicit :class:`ScoreContract` (DESIGN.md §9):
 numpy guarantees bit-identity between the incremental and cold paths;
-jax is float32 and guarantees the same winner (or a winner tied within
+the float32 fleets guarantee the same winner (or a winner tied within
 tolerance) with scores inside a rel/abs envelope.  Incremental repricing
-lives in :class:`RankState` (numpy) and :class:`JaxRankState` (the
-accelerator-resident jitted delta-update kernel with donated buffers).
+lives in :class:`RankState` (numpy) and in the fleet states:
 :class:`BatchedRankState` stacks a whole fleet of (class, exclusion)
 rankings over one shared device-resident hours matrix, so a price tick
 is a *single* dispatch for every live ranking (DESIGN.md §10); the
@@ -36,15 +34,15 @@ dispatch per tick reprices the fleet at catalogs no single device holds
 (:mod:`repro.selector.pallas_rank`) replaces the batched tick's
 two-matmul + mask/min/norm XLA sequence with ONE fused Pallas kernel
 over the tiled universe (:mod:`repro.kernels.rank_delta`, DESIGN.md
-§14).  Every state also serves :meth:`top_k` — the head of
-the ranking without materializing and sorting all C configs
-(``jax.lax.top_k`` on device for the jax-family states, a partial
-selection on numpy).
+§14).  A cold :func:`rank_dense` on any jax-family name is one jitted
+``jax.numpy`` ranking.  Every state also serves :meth:`top_k` — the
+head of the ranking without materializing and sorting all C configs
+(``jax.lax.top_k`` on device for the fleets, a partial selection on
+numpy).
 """
 from __future__ import annotations
 
 import dataclasses
-import os
 import threading
 from typing import (Any, Callable, Hashable, List, Mapping, Optional,
                     Sequence, Tuple, Union)
@@ -60,10 +58,9 @@ try:  # accelerator path; the selector core works without jax installed
 except ImportError:  # pragma: no cover
     _HAVE_JAX = False
 
-#: the knob CI's backend matrix turns; resolved by :func:`default_backend`.
-BACKEND_ENV_VAR = "FLORA_RANK_BACKEND"
-#: ``"jax_batched"`` shares the jax cold kernel and ScoreContract but
-#: makes the *service* stack every live (class, exclusion) ranking into
+#: what a :class:`~repro.selector.SelectionService` can be built with.
+#: ``"numpy"`` keeps one float64 :class:`RankState` per live (class,
+#: exclusion) ranking.  ``"jax_batched"`` stacks every live ranking into
 #: one :class:`BatchedRankState` — one dispatch per tick for the fleet.
 #: ``"jax_sharded"`` additionally shards the config axis of that fleet
 #: universe across every local device
@@ -75,14 +72,14 @@ BACKEND_ENV_VAR = "FLORA_RANK_BACKEND"
 #: (:mod:`repro.kernels.rank_delta`) instead of the two-matmul +
 #: mask/min/norm XLA sequence — native on TPU, ``interpret=True``
 #: elsewhere (DESIGN.md §14).
-BACKENDS = ("numpy", "jax", "jax_batched", "jax_sharded", "jax_pallas")
+BACKENDS = ("numpy", "jax_batched", "jax_sharded", "jax_pallas")
 #: the fleet backends: a SelectionService on one of these stacks every
 #: live (class, exclusion) ranking into a single shared state, so a
 #: price tick is one (possibly collective) kernel dispatch fleet-wide.
 FLEET_BACKENDS = ("jax_batched", "jax_sharded", "jax_pallas")
 #: span names of a device ``top_k``: the enqueue of the jitted call
-#: (per head on the per-state and sharded states, with their slot lookup
-#: and slices; on :class:`BatchedRankState` once over every slot per
+#: (per head on the sharded state, with its slot lookup and slices;
+#: on :class:`BatchedRankState` once over every slot per
 #: state change whose heads the reprice did not already take) and the
 #: blocking readback of its two results; the host assembly of a head
 #: lies outside both
@@ -97,24 +94,10 @@ _JAX_FAMILY = ("jax", "jax_batched", "jax_sharded", "jax_pallas")
 
 class BackendUnavailableError(RuntimeError):
     """A ranking backend was requested whose runtime dependency is not
-    installed (today: ``backend="jax"`` without jax).  Typed so callers —
+    installed (today: a jax-family backend without jax).  Typed so callers —
     and test harnesses — can skip rather than die: distinguishable from
     both misconfiguration ``ValueError``\\ s (unknown backend names) and
     genuine crashes."""
-
-
-def default_backend() -> str:
-    """The backend used when a :class:`~repro.selector.SelectionService`
-    is built without an explicit ``backend=``: the ``FLORA_RANK_BACKEND``
-    env var, else ``"numpy"``.  ``rank_dense`` itself always defaults to
-    numpy — the float64 bit-stable reference that replay audits re-rank
-    against must not move under the env var."""
-    backend = os.environ.get(BACKEND_ENV_VAR, "numpy")
-    if backend not in BACKENDS:
-        raise ValueError(
-            f"unknown backend {backend!r} in ${BACKEND_ENV_VAR} "
-            f"(expected one of {BACKENDS})")
-    return backend
 
 
 @dataclasses.dataclass(frozen=True)
@@ -175,12 +158,12 @@ class ScoreContract:
 #: ticks, with two orders of magnitude of headroom (DESIGN.md §9).
 SCORE_CONTRACTS: Mapping[str, ScoreContract] = {
     "numpy": ScoreContract("numpy", bit_identical=True),
+    # the stamp of journals from the retired per-state writer (replay only)
     "jax": ScoreContract("jax", bit_identical=False,
                          rel_tol=1e-4, abs_tol=1e-6),
-    # same float32 physics as "jax" (shared row-min/norm intermediates,
-    # delta-folded accumulators); batching adds no new drift source —
-    # member scores are re-reduced per changed column like the per-state
-    # kernel, so the envelope is identical (DESIGN.md §10).
+    # the float32 fleet: row-min/norm intermediates shared by every
+    # member, changed columns re-reduced from scratch and handed-off rows
+    # delta-folded into the accumulators (DESIGN.md §9-§10).
     "jax_batched": ScoreContract("jax_batched", bit_identical=False,
                                  rel_tol=1e-4, abs_tol=1e-6),
     # sharding the C axis changes *where* each column's arithmetic runs,
@@ -204,11 +187,10 @@ SCORE_CONTRACTS: Mapping[str, ScoreContract] = {
 
 def backend_available(backend: str) -> bool:
     """Can ``backend`` actually run here?  ``"numpy"`` always; the
-    jax-family backends (``"jax"``, ``"jax_batched"``,
-    ``"jax_sharded"``, ``"jax_pallas"``) only when jax imports.
-    Unknown names are *not*
-    an error from this predicate (they fail later with ``ValueError``
-    at dispatch)."""
+    jax-family backends (the fleets, and ``"jax"``, which a cold
+    :func:`rank_dense` and old journals still name) only when jax
+    imports.  Unknown names are *not* an error from this predicate
+    (they fail later with ``ValueError`` at dispatch)."""
     return backend not in _JAX_FAMILY or _HAVE_JAX
 
 
@@ -242,7 +224,7 @@ def _canonicalize_universe(
         job_ids: Optional[Sequence[Hashable]]
 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Shared input validation for every dense entry point
-    (:func:`rank_dense`, :class:`RankState`, :class:`JaxRankState`):
+    (:func:`rank_dense`, :class:`RankState`, the fleet states):
     canonicalize dtypes, check shapes, reject empty job axes and
     non-positive profiled costs (both indicate a broken trace, not a
     rankable universe)."""
@@ -418,10 +400,9 @@ class RankState:
     path at 10k configs; see ``benchmarks/market_bench.py``).
 
     numpy/float64 only — float32 has no exact incremental story, so the
-    jax backend's accelerator-resident counterpart,
-    :class:`JaxRankState`, serves a *tolerance* contract instead
-    (same winner or tied within tolerance; see :class:`ScoreContract`
-    and DESIGN.md §9).
+    accelerator-resident fleet states (:class:`BatchedRankState` and its
+    siblings) serve a *tolerance* contract instead (same winner or tied
+    within tolerance; see :class:`ScoreContract` and DESIGN.md §9).
     """
 
     def __init__(self, hours: np.ndarray, mask: np.ndarray,
@@ -551,7 +532,7 @@ class RankState:
         return RankedConfig(c, s, m)
 
 
-# --- the accelerator-resident incremental path (jax backend) ----------------------
+# --- the accelerator-resident incremental path (fleet backends) -----------------
 
 def _validated_deltas(pos: Mapping[Hashable, int],
                       deltas: Union[Mapping[Hashable, float],
@@ -593,13 +574,12 @@ def _validated_delta_cols(pos: Mapping[Hashable, int],
                                         Sequence[Tuple[Hashable, float]]],
                           bucket_base: int
                           ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
-    """Shared delta-batch preparation for the jitted jax states
-    (:class:`JaxRankState`, :class:`BatchedRankState`): validate ids and
-    prices (:func:`_validated_deltas`), then pad ``(cols, new_prices)``
-    to the next power-of-4 column-count bucket so the jitted step
-    compiles O(log C) shape variants.  Padding repeats the first
-    (column, price) pair, which every kernel op treats idempotently.
-    Returns ``None`` for an empty batch."""
+    """Delta-batch preparation for :class:`BatchedRankState`'s jitted
+    step: validate ids and prices (:func:`_validated_deltas`), then pad
+    ``(cols, new_prices)`` to the next power-of-4 column-count bucket so
+    the jitted step compiles O(log C) shape variants.  Padding repeats
+    the first (column, price) pair, which every kernel op treats
+    idempotently.  Returns ``None`` for an empty batch."""
     validated = _validated_deltas(pos, deltas)
     if validated is None:
         return None
@@ -615,7 +595,7 @@ def _validated_delta_cols(pos: Mapping[Hashable, int],
 
 
 if _HAVE_JAX:
-    _JAX_STATE_FNS: Optional[Tuple[Any, Any, Any]] = None
+    _JAX_COLD_FN: Optional[Any] = None
     _JAX_TOPK_FN: Optional[Any] = None
     #: guards every lazy jitted-kernel singleton below (double-checked
     #: locking): the serving front-end first-calls from N snapshot
@@ -636,10 +616,8 @@ if _HAVE_JAX:
 
     def _delta_universe_update(prices, cost, row_best, hours, mask,
                                cols, new_prices):
-        """The shared universe half of every jitted delta step (traced
-        inside both the per-state and the batched kernels, so the two
-        backends can never silently diverge on the numerically critical
-        logic):
+        """The universe half of the batched delta step (the sharded
+        step's local half mirrors it, ``sharded.py``):
 
         * changed columns: gather, recompute cells, scatter back;
         * min-handoff rows: the masked row-minimum was in a changed
@@ -730,211 +708,26 @@ if _HAVE_JAX:
                     _JAX_TOPK_FN = jax.jit(_masked_top_k, static_argnums=2)
         return _JAX_TOPK_FN
 
-    def _jax_state_fns() -> Tuple[Any, Any, Any]:
-        """``(cold, step, winner)`` jitted kernels, built once on first
-        use (so importing the selector never initializes an accelerator
-        backend).  The step donates its five state buffers — a tick
-        updates the resident arrays in place instead of allocating a
-        fresh universe — except on CPU, whose client cannot donate and
-        would warn on every call site."""
-        global _JAX_STATE_FNS
-        if _JAX_STATE_FNS is not None:
-            return _JAX_STATE_FNS
-        with _JAX_FNS_LOCK:
-            if _JAX_STATE_FNS is not None:
-                return _JAX_STATE_FNS
-            return _build_jax_state_fns()
+    def _jax_cold_fn() -> Any:
+        """``cold(hours, mask, prices)``: the cold-path arithmetic in
+        float32 -- cost, row minima, normalised cost and its column sums,
+        the state a fleet's delta stream starts from -- jitted once on
+        first use (so importing the selector never initializes an
+        accelerator backend)."""
+        global _JAX_COLD_FN
+        if _JAX_COLD_FN is None:
+            with _JAX_FNS_LOCK:
+                if _JAX_COLD_FN is None:
+                    def cold(hours, mask, prices):
+                        # the cold-path arithmetic (float32): the state a
+                        # delta stream starts from
+                        cost = _cost_of(hours, mask, prices[None, :])
+                        row_best = jnp.min(cost, axis=1)
+                        norm = _norm_of(cost, mask, row_best)
+                        return cost, row_best, norm, norm.sum(axis=0)
 
-    def _build_jax_state_fns() -> Tuple[Any, Any, Any]:
-        global _JAX_STATE_FNS
-
-        def cold(hours, mask, prices):
-            # the cold-path arithmetic (float32): the state a delta
-            # stream starts from
-            cost = _cost_of(hours, mask, prices[None, :])
-            row_best = jnp.min(cost, axis=1)
-            norm = _norm_of(cost, mask, row_best)
-            return cost, row_best, norm, norm.sum(axis=0)
-
-        def step(prices, cost, row_best, norm, scores, hours, mask,
-                 cols, new_prices):
-            (prices, cost, row_best, fresh_rows, moved,
-             col_norm) = _delta_universe_update(prices, cost, row_best,
-                                                hours, mask, cols,
-                                                new_prices)
-            # handed-off rows renormalize whole rows; the delta folds
-            # into the standing score accumulators — the per-tick ulp
-            # drift the jax ScoreContract tolerances cover (DESIGN.md §9)
-            scores = scores + jnp.where(moved[:, None],
-                                        fresh_rows - norm, 0.0).sum(axis=0)
-            norm = jnp.where(moved[:, None], fresh_rows, norm)
-            # changed columns re-sum from scratch with a .set — the
-            # duplicate indices bucket padding introduces are idempotent
-            # under .set (a .add of deltas would double-count them)
-            norm = norm.at[:, cols].set(col_norm)
-            scores = scores.at[cols].set(col_norm.sum(axis=0))
-            return prices, cost, row_best, norm, scores, moved.sum()
-
-        def winner(scores, finite):
-            masked = jnp.where(finite, scores, jnp.inf)
-            i = jnp.argmin(masked)
-            return i, scores[i]
-
-        donate = () if jax.default_backend() == "cpu" else (0, 1, 2, 3, 4)
-        _JAX_STATE_FNS = (jax.jit(cold),
-                          jax.jit(step, donate_argnums=donate),
-                          jax.jit(winner))
-        return _JAX_STATE_FNS
-
-
-class JaxRankState:
-    """Accelerator-resident incremental repricing (the jax backend).
-
-    The float32 counterpart of :class:`RankState` for serving-scale
-    universes: the runtime matrix, mask and every intermediate (cost,
-    row-min, normalized-cost, score accumulators) live as device arrays,
-    and :meth:`reprice` runs one jitted delta-update kernel whose state
-    buffers are donated — a tick updates the universe in place, touching
-    only the changed cost/norm columns plus the rows whose masked
-    row-minimum handed off, with per-column score re-sums for changed
-    columns and delta-folds for handed-off rows.  Host traffic per tick
-    is the delta batch in and one scalar (the handoff count) out; a cold
-    ``rank_dense(backend="jax")`` instead re-uploads the whole float64
-    universe and re-materializes every ranking
-    (``benchmarks/market_bench.py`` quantifies the gap).
-
-    **Tolerance contract** (:data:`SCORE_CONTRACTS` ``["jax"]``): float32
-    sums are not decomposable, and the delta-folded score accumulators
-    drift by ulps per tick, so — unlike :class:`RankState` — rankings
-    are *not* bit-identical to a cold re-rank.  The contract is
-    same-winner-or-tied-within-tolerance, scores inside the rel/abs
-    envelope; ``JournalReplayer.audit`` verifies journals produced
-    through this path in exactly those terms (DESIGN.md §9).
-
-    Delta batches are padded to power-of-4 column-count buckets so the
-    jitted step compiles O(log C) shape variants, not one per batch
-    size; padding repeats the first (column, price) pair, which every
-    kernel op treats idempotently.
-    """
-
-    backend = "jax"
-    contract = SCORE_CONTRACTS["jax"]
-    _BUCKET_BASE = 8
-
-    def __init__(self, hours: np.ndarray, mask: np.ndarray,
-                 prices: np.ndarray, config_ids: Sequence[Hashable],
-                 job_ids: Optional[Sequence[Hashable]] = None,
-                 metrics: Optional[MetricsRegistry] = None):
-        if not _HAVE_JAX:
-            raise BackendUnavailableError(
-                "JaxRankState requires jax; use RankState (numpy) "
-                "when it is not installed")
-        self.config_ids = list(config_ids)
-        self.job_ids = list(job_ids) if job_ids is not None else None
-        self._metrics = metrics
-        self._c_mat = (None if metrics is None
-                       else metrics.counter("rank.materializations"))
-        hours, mask, prices = _canonicalize_universe(hours, mask, prices,
-                                                     self.job_ids)
-        self._pos = _position_index(self.config_ids)
-        cold, self._step, self._winner_fn = _jax_state_fns()
-        # read-only residents (uploaded once, never donated)
-        self.d_hours = jnp.asarray(hours, dtype=jnp.float32)
-        self.d_mask = jnp.asarray(mask)
-        self.counts = mask.sum(axis=0)
-        self._d_finite = jnp.asarray(self.counts > 0)
-        # the donated state buffers
-        self.d_prices = jnp.asarray(prices, dtype=jnp.float32)
-        (self.d_cost, self.d_row_best, self.d_norm,
-         self.d_scores) = cold(self.d_hours, self.d_mask, self.d_prices)
-        #: ticks applied since construction (diagnostics, cache keys).
-        self.reprices = 0
-        #: host materializations actually performed: :meth:`ranking` is
-        #: memoized on ``reprices``, so repeat calls between two ticks —
-        #: previously a fresh device→host transfer + C-object build +
-        #: sort *every call* — reuse the last sort (the counter the
-        #: freshness regression test asserts on).
-        self.materializations = 0
-        self._ranking_memo: Optional[Tuple[int, List[RankedConfig]]] = None
-
-    @property
-    def prices(self) -> np.ndarray:
-        """Current per-config $/h as seen by the kernel (float32 quotes
-        lifted to a host float64 vector)."""
-        return np.asarray(self.d_prices, dtype=np.float64)
-
-    @property
-    def scores(self) -> np.ndarray:
-        """Current score accumulators on the host (float64 lift)."""
-        return np.asarray(self.d_scores, dtype=np.float64)
-
-    def reprice(self, deltas: Union[Mapping[Hashable, float],
-                                    Sequence[Tuple[Hashable, float]]]
-                ) -> int:
-        """Apply ``{config_id: new $/h}`` deltas on device; returns
-        #rows whose masked row-minimum handed off (synced to host, so a
-        return means the tick's kernel has completed)."""
-        prepared = _validated_delta_cols(self._pos, deltas,
-                                         self._BUCKET_BASE)
-        if prepared is None:
-            return 0
-        cols, new_prices = prepared
-        (self.d_prices, self.d_cost, self.d_row_best, self.d_norm,
-         self.d_scores, moved) = self._step(
-            self.d_prices, self.d_cost, self.d_row_best, self.d_norm,
-            self.d_scores, self.d_hours, self.d_mask,
-            jnp.asarray(cols), jnp.asarray(new_prices, dtype=jnp.float32))
-        self.reprices += 1
-        return int(moved)
-
-    def ranking(self) -> List[RankedConfig]:
-        """The full sorted ranking under the tolerance contract: one
-        device→host score transfer, then the same materialization as
-        every other path (ties broken by catalog order).  Memoized on
-        the state's tick count — the host sort used to re-run on *every*
-        call even when no tick had been applied since the last
-        materialization (the dominant serving cost at 10k configs); a
-        fresh list copy is returned each call so the memo stays
-        pristine."""
-        if self._ranking_memo is None or \
-                self._ranking_memo[0] != self.reprices:
-            self.materializations += 1
-            if self._c_mat is not None:
-                self._c_mat.inc()
-            self._ranking_memo = (
-                self.reprices,
-                _materialize(self.scores, self.counts, self.config_ids))
-        return list(self._ranking_memo[1])
-
-    def top_k(self, k: int) -> List[RankedConfig]:
-        """The first ``k`` entries of :meth:`ranking` served from the
-        device: ``jax.lax.top_k`` over the resident score buffer, then
-        an O(k) readback — the full C-config materialize/sort never
-        happens.  Tie-break (catalog order on equal scores) matches the
-        materialized ranking; see :func:`_jax_topk_fn`."""
-        with maybe_span(self._metrics, TOPK_DISPATCH_SPAN):
-            k = _check_k(k, len(self.config_ids))
-            idx, vals = _jax_topk_fn()(self.d_scores, self._d_finite, k)
-        with maybe_span(self._metrics, TOPK_READBACK_SPAN):
-            idx = np.asarray(idx)
-            vals = np.asarray(vals, dtype=np.float64)
-        out = []
-        for i, s in zip(idx, vals):
-            n = int(self.counts[i])
-            out.append(RankedConfig(
-                self.config_ids[int(i)],
-                float(s) if n else float("inf"),
-                float(s) / n if n else float("inf")))
-        return out
-
-    def winner(self) -> RankedConfig:
-        """argmin on device — only two scalars cross to the host."""
-        i, s = self._winner_fn(self.d_scores, self._d_finite)
-        i = int(i)
-        c = self.config_ids[i]
-        if not self.counts[i]:
-            return RankedConfig(c, float("inf"), float("inf"))
-        return RankedConfig(c, float(s), float(s) / int(self.counts[i]))
+                    _JAX_COLD_FN = jax.jit(cold)
+        return _JAX_COLD_FN
 
 
 # --- batched multi-state repricing (jax_batched backend) --------------------------
@@ -947,8 +740,7 @@ if _HAVE_JAX:
         """The batched delta step's traced body, shared by the plain
         step and the step that also takes the fleet's heads
         (:func:`_jax_batched_fns`)."""
-        # the universe half is the SAME traced helper as the
-        # per-state kernel — the backends cannot diverge on it
+        # the universe half: the shared traced helper
         (prices, cost, row_best, fresh_rows, moved,
          col_norm) = _delta_universe_update(prices, cost, row_best,
                                             hours, mask, cols,
@@ -982,7 +774,7 @@ if _HAVE_JAX:
             scores[s, c] = Σ_j row_masks[s, j] · norm[j, c]
 
         so the per-tick step updates the shared cost/row-min/norm
-        buffers exactly like :class:`JaxRankState`'s kernel and then
+        buffers once (:func:`_delta_universe_update`) and then
         refreshes *all* member accumulators with two small matmuls
         (handed-off-row deltas folded in; changed columns re-reduced
         from scratch) — one dispatch for the whole fleet, independent
@@ -1058,8 +850,8 @@ class BatchedRankState:
     The serving problem this solves (DESIGN.md §10): a live
     :class:`~repro.selector.SelectionService` holds one ranking state
     per (job class, exclusion set) — a *fleet* of states over the same
-    profiling store.  With per-state :class:`JaxRankState`\\ s a price
-    tick is one kernel dispatch *per state*; ``BatchedRankState`` stacks
+    profiling store.  With one device state per selection a price tick
+    would be one kernel dispatch *per state*; ``BatchedRankState`` stacks
     the fleet over a single shared device-resident universe — hours,
     profiled mask, cost, row-min and normalized-cost buffers are stored
     **once** (they are member-independent: every member shares the
@@ -1067,8 +859,8 @@ class BatchedRankState:
     per-member structure reduced to a row-mask matrix (S×J) and a score
     accumulator matrix (S×C), both carrying the member axis in front.
     :meth:`reprice` then runs one batched jitted delta-update kernel
-    (donated state buffers, the same power-of-4 delta bucketing as
-    :class:`JaxRankState`) that refreshes every member's scores in the
+    (donated state buffers, delta batches padded to power-of-4 column
+    buckets) that refreshes every member's scores in the
     same dispatch.
 
     Members are added (:meth:`add_state`) and retired
@@ -1089,10 +881,10 @@ class BatchedRankState:
     program and readback, so a tick that reprices and then publishes
     makes one round trip to the device, not two.
 
-    **Contract** (:data:`SCORE_CONTRACTS` ``["jax_batched"]``): same
-    float32 tolerance envelope as the per-state jax kernel — batching
-    adds no drift source beyond the member-axis reduction order, which
-    the shared rel/abs tolerances already cover (DESIGN.md §10).
+    **Contract** (:data:`SCORE_CONTRACTS` ``["jax_batched"]``): float32
+    scores within the rel/abs envelope of a cold float64 rank, the
+    winner identical or tied within it (DESIGN.md §9-§10); a fleet of
+    one member is the plain incremental float32 ranking.
     """
 
     backend = "jax_batched"
@@ -1119,7 +911,7 @@ class BatchedRankState:
                          {j: i for i, j in enumerate(self.job_ids)})
         self._mask = mask.copy()              # host copy: member counts
         self._n_jobs = hours.shape[0]
-        cold = _jax_state_fns()[0]
+        cold = _jax_cold_fn()
         (self._step, self._step_heads,
          self._member_scores) = _jax_batched_fns()
         # shared read-only residents (uploaded once, never donated)

@@ -30,10 +30,9 @@ from repro.obs import MetricsRegistry
 from repro.selector.catalog import BaseCatalog, PriceTable
 from repro.selector.rank import (BACKENDS, FLEET_BACKENDS,
                                  BackendUnavailableError,
-                                 BatchedRankState, JaxRankState,
-                                 NothingRankableError, RankedConfig,
-                                 RankState, backend_available,
-                                 default_backend)
+                                 BatchedRankState, NothingRankableError,
+                                 RankedConfig, RankState,
+                                 backend_available)
 from repro.selector.pallas_rank import PallasBatchedRankState
 from repro.selector.sharded import ShardedBatchedRankState
 from repro.selector.store import ProfilingStore
@@ -71,7 +70,7 @@ class SelectionService:
                  price_source: Optional[Any] = None,
                  classifier: Optional[Callable[[Hashable],
                                                JobClass]] = None,
-                 backend: Optional[str] = None,
+                 backend: str = "numpy",
                  serve_top_k: Optional[int] = None,
                  metrics: Optional[MetricsRegistry] = None):
         self.catalog = catalog
@@ -83,18 +82,20 @@ class SelectionService:
         #: whole tick/serve pipeline.  Inject a shared registry to merge
         #: with store/train/engine telemetry.
         self.metrics = metrics if metrics is not None else MetricsRegistry()
-        #: ``None`` resolves via :func:`repro.selector.default_backend`
-        #: (the ``FLORA_RANK_BACKEND`` env var — CI's backend matrix),
-        #: else "numpy".  "numpy" serves the bit-identical float64
-        #: contract; "jax" the accelerator-resident float32 tolerance
-        #: contract (DESIGN.md §9); "jax_batched" the same contract with
-        #: every live (class, exclusion) ranking stacked into one
-        #: :class:`BatchedRankState` — a tick is one kernel dispatch for
-        #: the whole fleet (DESIGN.md §10); "jax_sharded" the batched
-        #: fleet with its config axis sharded across every local device
-        #: (:class:`ShardedBatchedRankState`) — a tick is one
-        #: *collective* dispatch (DESIGN.md §13).
-        self.backend = backend if backend is not None else default_backend()
+        #: "numpy" (the default) keeps one :class:`RankState` per live
+        #: (class, exclusion) selection and serves the bit-identical
+        #: float64 contract.  The fleet backends serve the float32
+        #: tolerance contract (DESIGN.md §9) with every live selection
+        #: stacked into one device state: "jax_batched"
+        #: (:class:`BatchedRankState`, a tick is one kernel dispatch for
+        #: the whole fleet, DESIGN.md §10), "jax_pallas"
+        #: (:class:`PallasBatchedRankState`, the same tick as one fused
+        #: Pallas kernel, DESIGN.md §14) and "jax_sharded"
+        #: (:class:`ShardedBatchedRankState`, the config axis sharded
+        #: across every local device, one *collective* dispatch,
+        #: DESIGN.md §13).  The caller chooses; nothing in the
+        #: environment does.
+        self.backend = backend
         # fail at construction, not first submit: a service that can
         # never rank is misconfiguration the caller should see now
         if self.backend not in BACKENDS:
@@ -169,10 +170,9 @@ class SelectionService:
 
     @property
     def reprice_dispatches(self) -> int:
-        """Kernel dispatches spent repricing: one per live state per tick
-        for the per-state backends, exactly one per tick for the fleet
-        backends ("jax_batched"/"jax_sharded") regardless of fleet size
-        (the soak/bench gate)."""
+        """Reprice calls spent per tick: one per live state on numpy,
+        exactly one kernel dispatch for the fleet backends regardless of
+        fleet size (the soak/bench gate)."""
         return self._c_dispatches.value
 
     # -- price management ---------------------------------------------------
@@ -334,8 +334,8 @@ class SelectionService:
         sync with the store takes the cells in one ingest dispatch
         (:meth:`BatchedRankState.ingest`): its members, their slots and
         the price tag survive.  A cell of a job the fleet does not hold
-        (a new row), the ``jax_sharded`` fleet and the per-state
-        backends fall back to the cold path: the live states are
+        (a new row), the ``jax_sharded`` fleet and the numpy backend
+        fall back to the cold path: the live states are
         dropped and rebuilt on demand, counted in
         ``service.ingest_fallbacks`` (and the rebuild in
         ``rank.cold_rebuilds``).  Cells of configurations outside the
@@ -395,8 +395,8 @@ class SelectionService:
                        ) -> Tuple[Callable[[], Sequence[RankedConfig]],
                                   Callable[[int], Sequence[RankedConfig]]]:
         """Cold-build the live state serving ``base_key`` and return its
-        ``(ranking_fn, top_k_fn)``.  Per-state backends build one
-        RankState/JaxRankState over the selection's rows; the fleet
+        ``(ranking_fn, top_k_fn)``.  numpy builds one RankState over
+        the selection's rows; the fleet
         backends register the selection as a member of the one shared
         :class:`BatchedRankState` (or, for "jax_sharded", the
         multi-device :class:`ShardedBatchedRankState`) over the full
@@ -435,23 +435,19 @@ class SelectionService:
                 b.add_state(member, jobs=jobs)
             return (lambda: b.ranking(member),
                     lambda k: b.top_k(member, k))
+        if self.backend != "numpy":
+            # reached only when ``backend`` was changed after construction
+            raise ValueError(f"unknown backend {self.backend!r} "
+                             f"(expected one of {BACKENDS})")
         hours, mask = self.store.matrix(job_ids=jobs, config_ids=config_ids)
         # build through a live state so later reprices are incremental:
         # RankState's arithmetic is the cold numpy path verbatim
-        # (bit-identical); JaxRankState serves the accelerator-resident
-        # float32 tolerance contract (DESIGN.md §9).
-        if self.backend == "numpy":
-            state_cls = RankState
-        elif self.backend == "jax":
-            state_cls = JaxRankState
-        else:
-            raise ValueError(f"unknown backend {self.backend!r} "
-                             f"(expected one of {BACKENDS})")
+        # (bit-identical)
         for stale in [k for k in self._states
                       if k[0] != self.store.version]:
             del self._states[stale]
             self._state_tags.pop(stale, None)
-        state = state_cls(hours, mask, prices, config_ids, job_ids=jobs,
+        state = RankState(hours, mask, prices, config_ids, job_ids=jobs,
                           metrics=self.metrics)
         self._states[base_key] = state
         self._state_tags[base_key] = tag

@@ -2,15 +2,15 @@
 
 Hypothesis-driven: random universes and event-bearing reprice streams
 (the discount/eviction strategies from ``test_rank_properties``) assert
-that the jax float32 backend — cold ``rank_dense`` and the jitted
-accelerator-resident :class:`~repro.selector.JaxRankState` delta path —
+that the float32 device path — cold jax ``rank_dense`` and the jitted
+delta step of a one-member :class:`~repro.selector.BatchedRankState` —
 picks the same winner as the numpy float64 backend (or one tied within
 tolerance) and keeps every score inside the
 :class:`~repro.selector.ScoreContract` envelope.
 
 Also home to the no-jax degradation test: the selector core must import
-and rank with jax uninstalled, and ``backend="jax"`` must fail with the
-typed, skippable :class:`~repro.selector.BackendUnavailableError`.
+and rank with jax uninstalled, and the jax-family backends must fail
+with the typed, skippable :class:`~repro.selector.BackendUnavailableError`.
 """
 import os
 import subprocess
@@ -20,10 +20,9 @@ import textwrap
 import numpy as np
 import pytest
 
-from repro.selector import (BackendUnavailableError, JaxRankState,
+from repro.selector import (BackendUnavailableError, BatchedRankState,
                             RankState, ScoreContract, SelectionService,
-                            backend_available, default_backend, rank_dense,
-                            score_contract)
+                            backend_available, rank_dense, score_contract)
 
 try:        # the property half needs hypothesis; the differential
             # smoke/edge tests below run without it
@@ -35,10 +34,10 @@ try:        # the property half needs hypothesis; the differential
 except ImportError:  # pragma: no cover
     HAVE_HYPOTHESIS = False
 
-needs_jax = pytest.mark.skipif(not backend_available("jax"),
+needs_jax = pytest.mark.skipif(not backend_available("jax_batched"),
                                reason="jax not installed")
 
-CONTRACT = score_contract("jax")
+CONTRACT = score_contract("jax_batched")
 
 
 def assert_within_contract(candidate, reference,
@@ -53,6 +52,15 @@ def assert_within_contract(candidate, reference,
     for r in candidate:
         assert contract.scores_match(r.score, ref_score[r.config_id]), (
             r, ref_score[r.config_id])
+
+
+def fleet_of_one(hours, mask, prices, ids, job_ids=None):
+    """A :class:`BatchedRankState` holding one member, ``"m"``, over every
+    job row: the incremental float32 ranking of the whole universe,
+    served as ``ranking("m")``, ``top_k("m", k)`` and ``winner("m")``."""
+    state = BatchedRankState(hours, mask, prices, ids, job_ids=job_ids)
+    state.add_state("m", rows=range(np.asarray(hours).shape[0]))
+    return state
 
 
 # --- the contract itself -----------------------------------------------------------
@@ -129,7 +137,7 @@ def test_jax_delta_stream_within_contract_seeded(seed):
     rank at the live prices, under the contract."""
     rng, hours, mask, prices, ids = _random_universe(
         100 + seed, 5, 12 + 4 * seed, partial=seed % 2 == 0)
-    jx = JaxRankState(hours, mask, prices.copy(), ids)
+    jx = fleet_of_one(hours, mask, prices.copy(), ids)
     ref = RankState(hours, mask, prices.copy(), ids)
     live = prices.copy()
     for _ in range(6):
@@ -141,9 +149,9 @@ def test_jax_delta_stream_within_contract_seeded(seed):
         ref.reprice(deltas)
         for c, p in deltas.items():
             live[int(c[1:])] = p
-        assert_within_contract(jx.ranking(), ref.ranking())
+        assert_within_contract(jx.ranking("m"), ref.ranking())
         assert_within_contract(
-            jx.ranking(),
+            jx.ranking("m"),
             rank_dense(hours, mask, live, ids, backend="jax"))
 
 
@@ -159,7 +167,7 @@ def test_event_market_jax_reprice_within_contract_deterministic():
         base, seed=5, change_fraction=0.3, volatility=0.15,
         events=[MarketEvent("us-central1", 2, 4, 0.25, "discount"),
                 MarketEvent("europe-west3", 5, 3, 4.0, "eviction")])
-    state = JaxRankState(hours, mask, prices.copy(), ids)
+    state = fleet_of_one(hours, mask, prices.copy(), ids)
     live = prices.copy()
     for t in range(10):
         batch = feed.poll(t)
@@ -168,7 +176,7 @@ def test_event_market_jax_reprice_within_contract_deterministic():
         state.reprice({d.config_id: d.price for d in batch})
         for d in batch:
             live[ids.index(d.config_id)] = d.price
-        assert_within_contract(state.ranking(),
+        assert_within_contract(state.ranking("m"),
                                rank_dense(hours, mask, live, ids))
 
 
@@ -199,12 +207,12 @@ if HAVE_HYPOTHESIS:
         hours = np.asarray([[rt[(j, c)] for c in cfgs] for j in jobs])
         mask = np.ones_like(hours, dtype=bool)
         pv = np.asarray([prices[c] for c in cfgs])
-        jx = JaxRankState(hours, mask, pv, cfgs, job_ids=jobs)
+        jx = fleet_of_one(hours, mask, pv, cfgs, job_ids=jobs)
         ref = RankState(hours, mask, pv.copy(), cfgs, job_ids=jobs)
         for deltas in stream:
             jx.reprice(deltas)
             ref.reprice(deltas)
-            assert_within_contract(jx.ranking(), ref.ranking())
+            assert_within_contract(jx.ranking("m"), ref.ranking())
 
     @needs_jax
     @settings(max_examples=20, deadline=None)
@@ -218,14 +226,14 @@ if HAVE_HYPOTHESIS:
         hours = np.asarray([[rt[(j, c)] for c in cfgs] for j in jobs])
         mask = np.ones_like(hours, dtype=bool)
         live = np.asarray([prices[c] for c in cfgs])
-        jx = JaxRankState(hours, mask, live.copy(), cfgs, job_ids=jobs)
+        jx = fleet_of_one(hours, mask, live.copy(), cfgs, job_ids=jobs)
         for deltas in stream:
             jx.reprice(deltas)
             for c, p in deltas.items():
                 live[cfgs.index(c)] = p
             cold = rank_dense(hours, mask, live, cfgs, job_ids=jobs,
                               backend="jax")
-            assert_within_contract(jx.ranking(), cold)
+            assert_within_contract(jx.ranking("m"), cold)
 
     @needs_jax
     @settings(max_examples=20, deadline=None)
@@ -239,7 +247,7 @@ if HAVE_HYPOTHESIS:
         hours = np.asarray([[rt[(j, c)] for c in cfgs] for j in jobs])
         mask = np.ones_like(hours, dtype=bool)
         live = np.asarray([base[c] for c in cfgs])
-        state = JaxRankState(hours, mask, live.copy(), cfgs, job_ids=jobs)
+        state = fleet_of_one(hours, mask, live.copy(), cfgs, job_ids=jobs)
         feed = _event_feed(base, events, seed, change_fraction)
         for t in range(n_ticks):
             batch = feed.poll(t)
@@ -248,7 +256,7 @@ if HAVE_HYPOTHESIS:
             state.reprice({d.config_id: d.price for d in batch})
             for d in batch:
                 live[cfgs.index(d.config_id)] = d.price
-            assert_within_contract(state.ranking(),
+            assert_within_contract(state.ranking("m"),
                                    rank_dense(hours, mask, live, cfgs,
                                               job_ids=jobs))
 
@@ -257,7 +265,7 @@ if HAVE_HYPOTHESIS:
     @given(event_markets(), st.integers(0, 2 ** 16))
     def test_event_market_jax_daemon_audits_within_tolerance(market,
                                                              stream_seed):
-        """End-to-end: a jax-backed daemon over any event-bearing
+        """End-to-end: a fleet-backed daemon over any event-bearing
         market journals decisions the tolerance audit confirms against
         cold float64 re-ranks."""
         from repro.core.trace import JobClass
@@ -272,7 +280,7 @@ if HAVE_HYPOTHESIS:
                 store.add(f"j{j}", c, 0.1 + ((j * 7 + i * 3) % 11) / 5.0,
                           job_class=JobClass.A if j % 2 else JobClass.B)
         svc = SelectionService(IdentityCatalog(cfgs), store,
-                               PriceTable(base), backend="jax")
+                               PriceTable(base), backend="jax_batched")
         daemon = SelectionDaemon(svc, _event_feed(base, events, seed,
                                                   change_fraction))
         daemon.run(synthetic_stream(store.job_ids, 25, seed=stream_seed,
@@ -303,7 +311,7 @@ def test_jax_state_partial_mask_and_unprofiled_columns():
     mask[:, C - 1] = False                      # never profiled
     prices = rng.uniform(0.5, 20.0, C)
     ids = [f"c{i}" for i in range(C)]
-    jx = JaxRankState(hours, mask, prices.copy(), ids)
+    jx = fleet_of_one(hours, mask, prices.copy(), ids)
     ref = RankState(hours, mask, prices.copy(), ids)
     live = prices.copy()
     for t in range(8):
@@ -314,11 +322,12 @@ def test_jax_state_partial_mask_and_unprofiled_columns():
         ref.reprice(batch)
         for c, p in batch.items():
             live[int(c[1:])] = p
-        assert_within_contract(jx.ranking(), ref.ranking())
-        unprofiled = [r for r in jx.ranking() if r.config_id == ids[C - 1]]
+        assert_within_contract(jx.ranking("m"), ref.ranking())
+        unprofiled = [r for r in jx.ranking("m")
+                      if r.config_id == ids[C - 1]]
         assert unprofiled[0].score == float("inf")
         # the device-side winner peek agrees with the materialized list
-        assert jx.winner() == jx.ranking()[0]
+        assert jx.winner("m") == jx.ranking("m")[0]
 
 
 @needs_jax
@@ -326,10 +335,10 @@ def test_jax_state_validates_like_numpy():
     hours = np.asarray([[1.0, 2.0]])
     mask = np.ones_like(hours, dtype=bool)
     with pytest.raises(ValueError, match="shape mismatch"):
-        JaxRankState(hours, mask, np.asarray([1.0]), ["a", "b"])
+        fleet_of_one(hours, mask, np.asarray([1.0]), ["a", "b"])
     with pytest.raises(ValueError, match="duplicate config ids"):
-        JaxRankState(hours, mask, np.asarray([1.0, 2.0]), ["a", "a"])
-    state = JaxRankState(hours, mask, np.asarray([1.0, 2.0]), ["a", "b"])
+        fleet_of_one(hours, mask, np.asarray([1.0, 2.0]), ["a", "a"])
+    state = fleet_of_one(hours, mask, np.asarray([1.0, 2.0]), ["a", "b"])
     with pytest.raises(ValueError, match="unknown config id"):
         state.reprice({"ghost": 1.0})
     with pytest.raises(ValueError, match="non-positive"):
@@ -337,7 +346,7 @@ def test_jax_state_validates_like_numpy():
     assert state.reprice({}) == 0
     from repro.selector import NothingRankableError
     with pytest.raises(NothingRankableError):
-        JaxRankState(np.zeros((0, 2)), np.zeros((0, 2), dtype=bool),
+        fleet_of_one(np.zeros((0, 2)), np.zeros((0, 2), dtype=bool),
                      np.asarray([1.0, 2.0]), ["a", "b"])
 
 
@@ -352,7 +361,7 @@ def test_jax_delta_bucket_padding_is_idempotent():
     mask = np.ones((J, C), dtype=bool)
     prices = rng.uniform(0.5, 20.0, C)
     ids = [f"c{i}" for i in range(C)]
-    jx = JaxRankState(hours, mask, prices.copy(), ids)
+    jx = fleet_of_one(hours, mask, prices.copy(), ids)
     live = prices.copy()
     for k in (1, 7, 8, 9, 32, C):
         cols = rng.choice(C, k, replace=False)
@@ -361,7 +370,7 @@ def test_jax_delta_bucket_padding_is_idempotent():
         jx.reprice(batch)
         for c, p in batch.items():
             live[int(c[1:])] = p
-        assert_within_contract(jx.ranking(),
+        assert_within_contract(jx.ranking("m"),
                                rank_dense(hours, mask, live, ids))
 
 
@@ -380,42 +389,50 @@ def test_service_backend_knob_serves_jax_states():
                       job_class=JobClass.A if j % 2 else JobClass.B)
     table = PriceTable({c: float(rng.uniform(1.0, 20.0)) for c in ids})
     svc = SelectionService(IdentityCatalog(ids), store, table,
-                           backend="jax")
+                           backend="jax_batched")
     ref = SelectionService(IdentityCatalog(ids), store,
                            PriceTable(dict(table.items())),
                            backend="numpy")
     d1 = svc.submit("j1")
     d2 = ref.submit("j1")
     assert_within_contract(list(d1.ranking), list(d2.ranking))
-    # ticks run the donated-buffer delta kernel through service.reprice
+    # ticks run the fleet's delta kernel through service.reprice
     deltas = {ids[0]: 0.7, ids[5]: 9.0}
-    assert svc.reprice(deltas) == 1       # the one live state refreshed
+    assert svc.reprice(deltas) == 1       # the one live member refreshed
     ref.reprice(deltas)
     assert_within_contract(list(svc.submit("j1").ranking),
                            list(ref.submit("j1").ranking))
     assert svc.reprice_refreshes == 1
 
 
-def test_service_rejects_unknown_backend_at_construction():
-    """A misspelled backend fails when the service is built, not on the
-    first submit — wiring a never-rankable service into a daemon should
-    be impossible."""
+@pytest.mark.parametrize("backend", ["torch", "jax"])
+def test_service_rejects_unknown_backend_at_construction(backend):
+    """A misspelled backend, or the retired per-state ``"jax"``, fails
+    when the service is built, not on the first submit — wiring a
+    never-rankable service into a daemon should be impossible — and the
+    message names what a service can be built with."""
     from repro.selector import IdentityCatalog, PriceTable, ProfilingStore
     store = ProfilingStore(config_ids=["a"])
     store.add("j", "a", 1.0)
-    with pytest.raises(ValueError, match="unknown backend"):
+    with pytest.raises(ValueError, match=r"unknown backend .*'numpy', "
+                       r"'jax_batched', 'jax_sharded', 'jax_pallas'"):
         SelectionService(IdentityCatalog(["a"]), store,
-                         PriceTable({"a": 1.0}), backend="torch")
+                         PriceTable({"a": 1.0}), backend=backend)
 
 
 def test_default_backend_resolves_env(monkeypatch):
-    monkeypatch.delenv("FLORA_RANK_BACKEND", raising=False)
-    assert default_backend() == "numpy"
-    monkeypatch.setenv("FLORA_RANK_BACKEND", "jax")
-    assert default_backend() == "jax"
-    monkeypatch.setenv("FLORA_RANK_BACKEND", "torch")
-    with pytest.raises(ValueError, match="unknown backend"):
-        default_backend()
+    """A service built without ``backend=`` serves numpy whatever the
+    environment holds: the device path is the caller's choice."""
+    from repro.selector import IdentityCatalog, PriceTable, ProfilingStore
+    monkeypatch.setenv("FLORA_RANK_BACKEND", "jax_batched")
+    store = ProfilingStore(config_ids=["a", "b"])
+    store.add("j", "a", 1.0)
+    store.add("j", "b", 2.0)
+    svc = SelectionService(IdentityCatalog(["a", "b"]), store,
+                           PriceTable({"a": 1.0, "b": 1.0}))
+    assert svc.backend == "numpy"
+    assert svc.submit("j").config_id == "a"
+    assert svc._batched is None and len(svc._states) == 1
 
 
 # --- graceful degradation with jax uninstalled (satellite fix) ---------------------
@@ -427,7 +444,7 @@ NO_JAX_PROBE = textwrap.dedent("""
     sys.modules["jax"] = None
     sys.modules["jax.numpy"] = None
 
-    from repro.selector import (BackendUnavailableError, JaxRankState,
+    from repro.selector import (BackendUnavailableError, BatchedRankState,
                                 PallasBatchedRankState, SelectionService,
                                 IdentityCatalog, PriceTable,
                                 ProfilingStore, rank_dense)
@@ -452,11 +469,11 @@ NO_JAX_PROBE = textwrap.dedent("""
     for attempt in (
         lambda: rank_dense(hours, mask, prices, ["a", "b"],
                            backend="jax"),
-        lambda: JaxRankState(hours, mask, prices, ["a", "b"]),
+        lambda: BatchedRankState(hours, mask, prices, ["a", "b"]),
         lambda: PallasBatchedRankState(hours, mask, prices, ["a", "b"]),
         lambda: SelectionService(IdentityCatalog(["a", "b"]), store,
                                  PriceTable({"a": 3.0, "b": 4.0}),
-                                 backend="jax"),
+                                 backend="jax_batched"),
         lambda: SelectionService(IdentityCatalog(["a", "b"]), store,
                                  PriceTable({"a": 3.0, "b": 4.0}),
                                  backend="jax_pallas"),
@@ -474,14 +491,11 @@ NO_JAX_PROBE = textwrap.dedent("""
 def test_selector_core_works_with_jax_uninstalled():
     """Satellite (ISSUE 4): with jax unimportable (sys.modules guard in
     a fresh interpreter, so this process's jax state is untouched), the
-    selector imports, ranks and serves on numpy, and ``backend="jax"``
-    raises the typed ``BackendUnavailableError`` — previously an
+    selector imports, ranks and serves on numpy, and the jax-family
+    backends raise the typed ``BackendUnavailableError`` — previously an
     untyped ``RuntimeError``."""
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = {**os.environ, "PYTHONPATH": os.path.join(repo_root, "src")}
-    # the probe's default-backend construction must resolve to numpy —
-    # don't let CI's jax matrix leg leak into the simulated jax-less box
-    env.pop("FLORA_RANK_BACKEND", None)
     result = subprocess.run(
         [sys.executable, "-c", NO_JAX_PROBE],
         capture_output=True, text=True, env=env, cwd=repo_root)

@@ -2,15 +2,9 @@
 
 The batched fleet kernel (:class:`~repro.selector.BatchedRankState`,
 DESIGN.md §10) must be indistinguishable — within the jax
-``ScoreContract`` — from the fleet it replaces: for random fleets of
-(row-subset) member states, every tick of the batched state must match
-
-  * per-state :class:`~repro.selector.JaxRankState` ticks (the PR-4
-    path: one dispatch per state per tick),
-  * a cold numpy float64 ``rank_dense`` at the live prices (the audit
-    reference),
-
-including event-bearing deltas (discount/eviction boundary re-quote
+``ScoreContract`` — from a cold numpy float64 ``rank_dense`` at the live
+prices (the audit reference) for every member of random fleets of
+(row-subset) member states, at every tick, including event-bearing deltas (discount/eviction boundary re-quote
 bursts) and members added or retired mid-stream.  A hypothesis property
 half reuses the market strategies from ``test_rank_properties``; the
 seeded deterministic half runs without hypothesis.
@@ -24,11 +18,11 @@ import numpy as np
 import pytest
 
 from repro.core.trace import JobClass
-from repro.selector import (BatchedRankState, IdentityCatalog, JaxRankState,
+from repro.selector import (BatchedRankState, IdentityCatalog,
                             NothingRankableError, PriceTable, ProfilingStore,
                             RankState, SelectionService, backend_available,
                             rank_dense, score_contract)
-from test_backend_parity import assert_within_contract
+from test_backend_parity import assert_within_contract, fleet_of_one
 
 try:        # the property half needs hypothesis; everything else runs
             # without it
@@ -66,17 +60,12 @@ def _fleet_universe(seed, n_jobs=10, n_cfgs=24, n_members=4, partial=True):
     return rng, hours, mask, prices, ids, members
 
 
-def _assert_fleet_parity(batched, members, hours, mask, live, ids,
-                         refs=None):
+def _assert_fleet_parity(batched, members, hours, mask, live, ids):
     """Every member of ``batched`` is within contract of a cold numpy
-    float64 rank over its rows (and of its per-state jax ref, when
-    given)."""
+    float64 rank over its rows."""
     for key, rows in members.items():
         cold = rank_dense(hours[rows], mask[rows], live, ids)
         assert_within_contract(batched.ranking(key), cold, CONTRACT)
-        if refs is not None:
-            assert_within_contract(batched.ranking(key),
-                                   refs[key].ranking(), CONTRACT)
 
 
 # --- deterministic differential sweeps (run without hypothesis) --------------------
@@ -84,17 +73,15 @@ def _assert_fleet_parity(batched, members, hours, mask, live, ids,
 @needs_jax
 @pytest.mark.parametrize("seed", range(6))
 def test_batched_fleet_within_contract_seeded(seed):
-    """Seeded fleets: after every tick, each batched member matches its
-    per-state JaxRankState and the cold numpy float64 rank, under the
-    contract — one batched dispatch per tick versus one per state."""
+    """Seeded fleets: after every tick, each batched member matches the
+    cold numpy float64 rank under the contract — one batched dispatch
+    per tick for the whole fleet."""
     rng, hours, mask, prices, ids, members = _fleet_universe(
         seed, n_jobs=6 + seed, n_cfgs=12 + 4 * seed,
         partial=seed % 2 == 0)
     batched = BatchedRankState(hours, mask, prices.copy(), ids)
     for key, rows in members.items():
         batched.add_state(key, rows=rows)
-    refs = {key: JaxRankState(hours[rows], mask[rows], prices.copy(), ids)
-            for key, rows in members.items()}
     live = prices.copy()
     for _ in range(6):
         k = int(rng.integers(1, len(ids)))
@@ -102,12 +89,9 @@ def test_batched_fleet_within_contract_seeded(seed):
         deltas = {ids[c]: float(live[c] * rng.uniform(0.5, 2.0))
                   for c in cols}
         batched.reprice(deltas)
-        for ref in refs.values():
-            ref.reprice(deltas)
         for c, p in deltas.items():
             live[int(c[1:])] = p
-        _assert_fleet_parity(batched, members, hours, mask, live, ids,
-                             refs)
+        _assert_fleet_parity(batched, members, hours, mask, live, ids)
     # the accounting the bench gates on: one dispatch per tick, fleet-wide
     assert batched.dispatches == batched.reprices == 6
     assert batched.n_active == len(members)
@@ -293,27 +277,22 @@ if HAVE_HYPOTHESIS:
     @given(fleet_streams())
     def test_batched_fleet_within_contract(data):
         """For any fleet of member states and any reprice stream, every
-        batched tick matches per-state JaxRankState ticks and the cold
-        numpy float64 rank within the contract."""
+        batched tick matches the cold numpy float64 rank within the
+        contract."""
         jobs, cfgs, rt, prices, stream, members = data
         hours = np.asarray([[rt[(j, c)] for c in cfgs] for j in jobs])
         mask = np.ones_like(hours, dtype=bool)
         pv = np.asarray([prices[c] for c in cfgs])
         batched = BatchedRankState(hours, mask, pv.copy(), cfgs)
-        refs = {}
         for key, rows in members.items():
             batched.add_state(key, rows=rows)
-            refs[key] = JaxRankState(hours[rows], mask[rows], pv.copy(),
-                                     cfgs)
         live = pv.copy()
         for deltas in stream:
             batched.reprice(deltas)
-            for ref in refs.values():
-                ref.reprice(deltas)
             for c, p in deltas.items():
                 live[cfgs.index(c)] = p
             _assert_fleet_parity(batched, members, hours, mask, live,
-                                 cfgs, refs)
+                                 cfgs)
 
     @needs_jax
     @settings(max_examples=15, deadline=None)
@@ -412,11 +391,11 @@ def test_numpy_top_k_is_head_of_ranking(k):
 @pytest.mark.parametrize("k", [1, 3, None])
 def test_jax_top_k_is_head_of_ranking(k):
     hours, mask, prices, ids = _universe_with_ties()
-    state = JaxRankState(hours, mask, prices, ids)
+    state = fleet_of_one(hours, mask, prices, ids)
     k = len(ids) if k is None else k
-    assert state.top_k(k) == state.ranking()[:k]
+    assert state.top_k("m", k) == state.ranking("m")[:k]
     state.reprice({ids[3]: 0.01, ids[7]: 40.0})
-    assert state.top_k(k) == state.ranking()[:k]
+    assert state.top_k("m", k) == state.ranking("m")[:k]
 
 
 @needs_jax
@@ -447,9 +426,9 @@ def test_top_k_exact_ties_resolve_in_catalog_order():
     i = ranked_ids.index(clones[0])
     assert ranked_ids[i:i + 3] == clones
     assert [r.config_id for r in state.top_k(C)][i:i + 3] == clones
-    if backend_available("jax"):
-        jx = JaxRankState(hours, mask, prices, ids)
-        assert [r.config_id for r in jx.top_k(C)][i:i + 3] == clones
+    if backend_available("jax_batched"):
+        jx = fleet_of_one(hours, mask, prices, ids)
+        assert [r.config_id for r in jx.top_k("m", C)][i:i + 3] == clones
 
 
 def test_top_k_clamps_and_validates():
@@ -459,11 +438,11 @@ def test_top_k_clamps_and_validates():
     for bad in (0, -1, 1.5, True):
         with pytest.raises(ValueError, match="positive integer"):
             state.top_k(bad)
-    if backend_available("jax"):
-        jx = JaxRankState(hours, mask, prices, ids)
-        assert jx.top_k(len(ids) + 50) == jx.ranking()
+    if backend_available("jax_batched"):
+        jx = fleet_of_one(hours, mask, prices, ids)
+        assert jx.top_k("m", len(ids) + 50) == jx.ranking("m")
         with pytest.raises(ValueError, match="positive integer"):
-            jx.top_k(0)
+            jx.top_k("m", 0)
 
 
 def test_top_k_unprofiled_configs_rank_last_with_inf():
@@ -479,25 +458,25 @@ def test_top_k_unprofiled_configs_rank_last_with_inf():
 
 @needs_jax
 def test_jax_ranking_memoized_until_next_tick():
-    """The fix: ``JaxRankState.ranking()`` used to re-materialize (one
-    device→host transfer + C-object build + host sort) on *every* call
-    even when no tick had been applied — now it memoizes on the tick
-    count, like the numpy state."""
+    """The fix: a device state's ``ranking()`` used to re-materialize
+    (one device→host transfer + C-object build + host sort) on *every*
+    call even when no tick had been applied — now it memoizes on the
+    tick count, like the numpy state."""
     hours, mask, prices, ids = _universe_with_ties()
-    state = JaxRankState(hours, mask, prices, ids)
-    first = state.ranking()
+    state = fleet_of_one(hours, mask, prices, ids)
+    first = state.ranking("m")
     assert state.materializations == 1
-    assert state.ranking() == first
-    assert state.ranking() == first
+    assert state.ranking("m") == first
+    assert state.ranking("m") == first
     assert state.materializations == 1      # no re-materialization
     state.reprice({ids[2]: 0.5})
     assert state.materializations == 1      # reprice alone is lazy
-    again = state.ranking()
+    again = state.ranking("m")
     assert state.materializations == 2      # tick invalidated the memo
     assert again != first
     # the returned list is a fresh copy: callers cannot corrupt the memo
     again.reverse()
-    assert state.ranking() == list(reversed(again))
+    assert state.ranking("m") == list(reversed(again))
     assert state.materializations == 2
 
 
@@ -570,7 +549,7 @@ def test_service_jax_batched_backend_one_dispatch_per_tick():
     assert svc.reprice_dispatches == 2
 
 
-@pytest.mark.parametrize("backend", ["numpy", "jax", "jax_batched",
+@pytest.mark.parametrize("backend", ["numpy", "jax_batched",
                                      "jax_pallas"])
 def test_service_top_k_decision_matches_full_serving(backend):
     """A top-k-served Decision carries the same winner, score and $/h
@@ -678,7 +657,8 @@ def _fleet_programs():
         *universe, sd((1, C), f32), sd((J, 1), f32), sd((J,), f32))
 
 
-@pytest.mark.skipif(not backend_available("jax"), reason="jax missing")
+@pytest.mark.skipif(not backend_available("jax_batched"),
+                    reason="jax missing")
 def test_fleet_matmuls_run_at_float32_precision():
     """A TPU's default float32 matmul rounds its operands to bfloat16,
     which alone breaks the 1e-4 ScoreContract on the chip: every fleet
@@ -692,7 +672,8 @@ def test_fleet_matmuls_run_at_float32_precision():
             (name, dots)
 
 
-@pytest.mark.skipif(not backend_available("jax"), reason="jax missing")
+@pytest.mark.skipif(not backend_available("jax_batched"),
+                    reason="jax missing")
 def test_fleet_precision_probe_runs_small(monkeypatch):
     """``benchmarks/fleet_precision.py`` measures on the chip what
     DEFAULT precision does to the fleet's scores.  At a tiny size on the

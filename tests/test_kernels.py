@@ -367,12 +367,14 @@ def _stress_first_call(monkeypatch, reset, getter, expected_jits):
 
 
 def test_jax_state_fns_first_call_races_build_once(monkeypatch):
+    """The fleet's cold program, the first thing every fleet state
+    builds."""
     from repro.selector import rank
 
     _stress_first_call(
         monkeypatch,
-        lambda mp: mp.setattr(rank, "_JAX_STATE_FNS", None),
-        rank._jax_state_fns, expected_jits=3)
+        lambda mp: mp.setattr(rank, "_JAX_COLD_FN", None),
+        rank._jax_cold_fn, expected_jits=1)
 
 
 def test_jax_topk_fn_first_call_races_build_once(monkeypatch):

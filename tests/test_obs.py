@@ -487,13 +487,13 @@ def test_disabled_spans_leave_no_histograms_and_no_annotations(tmp_path):
 
 
 @pytest.mark.parametrize("backend,per_head,per_build", [
-    ("numpy", 0, 0), ("jax", 1, 0), ("jax_batched", 0, 0),
+    ("numpy", 0, 0), ("jax_batched", 0, 0),
     ("jax_pallas", 0, 1), ("jax_sharded", 1, 0)])
 def test_one_topk_dispatch_and_readback_per_live_selection(backend,
                                                            per_head,
                                                            per_build):
-    """Per-state and sharded states launch and read back one top-k per
-    live head; the Pallas fleet one per publication; the XLA fleet none
+    """The sharded state launches and reads back one top-k per live
+    head; the Pallas fleet one per publication; the XLA fleet none
     in a steady tick, because its reprice takes the heads asked at the
     previous publication."""
     if backend != "numpy":

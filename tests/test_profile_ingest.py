@@ -303,10 +303,10 @@ def test_cells_outside_the_catalog_are_stored_and_rank_nowhere():
 
 
 @pytest.mark.parametrize("backend", ["jax_batched", "jax_sharded",
-                                     "numpy", "jax"])
+                                     "numpy"])
 def test_the_cold_path_is_an_explicit_counted_fallback(backend):
     """A new job row on the fleet backends, and any ingest on
-    ``jax_sharded`` and the per-state backends, drop the live states
+    ``jax_sharded`` and numpy, drop the live states
     (``service.ingest_fallbacks``); the rebuild that follows serves the
     new store (``rank.cold_rebuilds`` on the fleet backends)."""
     if not backend_available(backend):
@@ -320,7 +320,7 @@ def test_the_cold_path_is_an_explicit_counted_fallback(backend):
         return {sel: svc.rank_head(*sel, k=3)[0] for sel in SELECTIONS}
 
     serve()
-    fleet = backend != "numpy" and backend != "jax"
+    fleet = backend != "numpy"
     assert rebuilds.value == (1 if fleet else 0)
     svc.ingest([("j1", "c0", 0.05)])             # a known job's cell
     cold_known = backend not in FLEETS

@@ -32,8 +32,12 @@ from repro.selector import (IdentityCatalog, PriceTable, ProfilingStore,
 FIXTURES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                         "fixtures")
 GOLDEN_JOURNAL = os.path.join(FIXTURES, "decision_journal_v2.golden.jsonl")
+#: written by the retired per-state "jax" backend; read-only now (no
+#: service can write it), kept so old journals stay auditable
 GOLDEN_JOURNAL_JAX = os.path.join(FIXTURES,
                                   "decision_journal_v2_jax.golden.jsonl")
+GOLDEN_JOURNAL_JAX_BATCHED = os.path.join(
+    FIXTURES, "decision_journal_v2_jax_batched.golden.jsonl")
 GOLDEN_JOURNAL_TOPK = os.path.join(
     FIXTURES, "decision_journal_v2_topk.golden.jsonl")
 PRICE_FIXTURE = os.path.join(os.path.dirname(FIXTURES), "..", "examples",
@@ -52,7 +56,7 @@ SPEED = {"dp256xtp1": {"train_4k": 1.0, "decode_32k": 4.0},
          "v5p-dp16xtp16": {"train_4k": 0.8, "decode_32k": 0.55}}
 
 
-def live_service(backend=None) -> SelectionService:
+def live_service(backend="numpy") -> SelectionService:
     recs = [WorkloadRecord(arch=a, shape=s, mesh=m, step_seconds=v)
             for a in ("a1", "a2")
             for m, shapes in SPEED.items() for s, v in shapes.items()]
@@ -64,7 +68,7 @@ def live_service(backend=None) -> SelectionService:
 
 
 def synth_service(n_jobs=6, n_cfgs=12, seed=0,
-                  backend=None) -> SelectionService:
+                  backend="numpy") -> SelectionService:
     """Identity-catalog universe with correlated per-class runtimes."""
     rng = np.random.default_rng(seed)
     ids = [f"c{i}" for i in range(n_cfgs)]
@@ -257,8 +261,7 @@ def test_duplicate_tick_quote_raises_with_line_number():
 # --- journal schema v2: golden files ----------------------------------------------
 
 def golden_daemon(backend="numpy", serve_top_k=None) -> SelectionDaemon:
-    # the goldens pin one journal layout per backend, so the backend is
-    # explicit here — never FLORA_RANK_BACKEND-resolved
+    # the goldens pin one journal layout per backend
     svc = live_service(backend=backend)
     svc.serve_top_k = serve_top_k
     feed = SimulatedSpotFeed(dict(svc.price_source.items()), seed=6,
@@ -287,10 +290,10 @@ def test_journal_schema_golden_file():
 
 
 def test_journal_golden_file_jax_backend():
-    """Satellite (ISSUE 4): the journal layout of a jax-backed daemon is
-    pinned alongside the numpy golden.  The header stamps
-    ``"backend": "jax"`` so replays know the tolerance audit mode
-    applies.
+    """The journal layout of a device-backed daemon
+    serving full rankings (``jax_batched``) is pinned alongside the
+    numpy golden.  The header stamps ``"backend": "jax_batched"`` so
+    replays know the tolerance audit mode applies.
 
     The pin mirrors the backend's own contract: every record is
     compared field-for-field exactly — kinds, seqs, winners, $/h,
@@ -302,15 +305,16 @@ def test_journal_golden_file_jax_backend():
     golden (same command, same commit discipline)."""
     pytest.importorskip("jax")
     from repro.selector import score_contract
-    daemon = golden_daemon(backend="jax")
+    daemon = golden_daemon(backend="jax_batched")
     daemon.run(GOLDEN_STREAM)
     header, records = SelectionDaemon.loads_journal(daemon.journal_dump())
-    assert header["backend"] == "jax"
-    with open(GOLDEN_JOURNAL_JAX) as f:
+    assert header["backend"] == "jax_batched"
+    assert not any("served_via" in r for r in records)
+    with open(GOLDEN_JOURNAL_JAX_BATCHED) as f:
         g_header, g_records = SelectionDaemon.loads_journal(f.read())
     assert header == g_header
     assert len(records) == len(g_records)
-    contract = score_contract("jax")
+    contract = score_contract("jax_batched")
     for rec, golden in zip(records, g_records):
         assert {k: v for k, v in rec.items() if k != "score"} == \
             {k: v for k, v in golden.items() if k != "score"}
@@ -319,13 +323,29 @@ def test_journal_golden_file_jax_backend():
             assert contract.scores_match(rec["score"], golden["score"])
 
 
+def test_retired_per_state_jax_journal_still_audits_clean():
+    """Journals written by the retired per-state writer stamp
+    ``"backend": "jax"``.  No service can write one any more, but the
+    committed golden replays and audits clean in tolerance mode under
+    its stamped contract."""
+    from repro.selector import score_contract
+    store = live_service().store
+    replayer = JournalReplayer.load(store, GOLDEN_JOURNAL_JAX)
+    assert replayer.backend == "jax"
+    audit = replayer.audit()
+    assert audit.ok, audit.mismatches[:3]
+    assert audit.contract == score_contract("jax")
+    assert not audit.contract.bit_identical
+    assert audit.decisions > 0 and audit.ticks > 0
+
+
 def test_journal_golden_file_topk_serving():
     """Satellite (ISSUE 5): the journal layout of a batched daemon
     serving every decision via device-side top-k (DESIGN.md §10) is
     pinned alongside the other goldens.  The header stamps
     ``"backend": "jax_batched"`` and decision records carry the
     additive ``"served_via": "top_k"`` field (absent on full-ranking
-    journals, so the numpy/jax goldens keep their bytes).
+    journals, so the full-ranking goldens keep their bytes).
 
     Pinned with the jax golden's discipline: every field exact except
     the float32-derived ``score``, held to the ScoreContract instead
@@ -454,8 +474,7 @@ def test_audit_detects_tampered_selection():
 
 def test_audit_detects_single_ulp_score_drift():
     # a float64 ulp is only a mismatch under the numpy bit-identity
-    # contract — pin the backend so FLORA_RANK_BACKEND=jax (CI's matrix)
-    # doesn't soften this audit into tolerance mode
+    # contract
     daemon = run_daemon(svc=synth_service(backend="numpy"))
     header, records = SelectionDaemon.loads_journal(daemon.journal_dump())
     victim = next(r for r in records if r["kind"] == "decision")
@@ -465,18 +484,18 @@ def test_audit_detects_single_ulp_score_drift():
 
 
 def test_tolerance_audit_surfaces_drift_and_bounds_it():
-    """Satellite (ISSUE 4): a jax-backed journal audits in tolerance
+    """A device-backed journal audits in tolerance
     mode — float32 score divergence from the cold float64 re-rank is
     surfaced as ``drift`` (not a failure) while anything beyond the
     ScoreContract still fails the audit."""
     pytest.importorskip("jax")
     from repro.selector import score_contract
-    daemon = run_daemon(svc=synth_service(backend="jax"))
+    daemon = run_daemon(svc=synth_service(backend="jax_batched"))
     replayer = JournalReplayer(daemon.service.store, daemon.journal_dump())
-    assert replayer.backend == "jax"
+    assert replayer.backend == "jax_batched"
     audit = replayer.audit()
     assert audit.ok, audit.mismatches[:3]
-    assert audit.contract == score_contract("jax")
+    assert audit.contract == score_contract("jax_batched")
     # float32 scores against float64 cold re-ranks: drift is expected
     # and must be *surfaced*, not silently absorbed
     assert any(d.field == "score-drift" for d in audit.drift)
@@ -711,8 +730,9 @@ def test_bundled_fixture_replay_end_to_end():
 
 
 def test_bundled_fixture_jax_daemon_audits_in_tolerance_mode():
-    """ISSUE 4 acceptance: a *jax-backed* daemon over the same bundled
-    fixture journals decisions the tolerance audit confirms against
+    """A *device-backed* daemon serving full
+    rankings (``jax_batched``) over the same bundled fixture journals
+    decisions the tolerance audit confirms against
     cold float64 re-ranks — same winners (or contract-tied), scores
     within the ScoreContract, float32 drift surfaced rather than
     silently absorbed — and the dynamic evaluation still beats the
@@ -725,18 +745,19 @@ def test_bundled_fixture_jax_daemon_audits_in_tolerance_mode():
     store = ProfilingStore.from_trace(trace)
     catalog = GcpVmCatalog(trace.configs, costmodel.LinearPriceModel())
     svc = SelectionService(catalog, store, PriceTable.from_catalog(catalog),
-                           backend="jax")
+                           backend="jax_batched")
     daemon = SelectionDaemon(svc, RecordedPriceFeed.load(PRICE_FIXTURE))
     daemon.run(synthetic_stream([j.name for j in trace.jobs], 400, seed=3,
                                 tick_fraction=0.15))
     replayer = JournalReplayer(store, daemon.journal_dump())
-    assert replayer.backend == "jax"
+    assert replayer.backend == "jax_batched"
     audit = replayer.audit()
     assert audit.ok, audit.mismatches[:3]
-    assert audit.contract == score_contract("jax")
+    assert audit.contract == score_contract("jax_batched")
     assert audit.decisions > 100 and audit.ticks > 10
+    assert all(d.served_via == "ranking" for d in replayer.decisions())
     ev = replayer.evaluate()
-    assert ev.summary()["backend"] == "jax"
+    assert ev.summary()["backend"] == "jax_batched"
     assert 0.0 <= ev.mean_deviation < 0.25
     assert ev.mean_deviation < ev.static_mean_deviation
 
@@ -817,7 +838,7 @@ if __name__ == "__main__":
     if "--regen-golden" in sys.argv:
         for backend, top_k, path in (
                 ("numpy", None, GOLDEN_JOURNAL),
-                ("jax", None, GOLDEN_JOURNAL_JAX),
+                ("jax_batched", None, GOLDEN_JOURNAL_JAX_BATCHED),
                 ("jax_batched", 2, GOLDEN_JOURNAL_TOPK)):
             daemon = golden_daemon(backend=backend, serve_top_k=top_k)
             daemon.run(GOLDEN_STREAM)

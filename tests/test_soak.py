@@ -146,7 +146,7 @@ def _assert_soak_invariants(svc, store, daemon, stats, backend,
         assert stats.epochs - 1 <= svc.reprice_dispatches <= stats.epochs
         assert svc._batched.dispatches == svc.reprice_dispatches
     else:
-        # per-state backends pay one update per live state per epoch
+        # numpy pays one update per live state per epoch
         assert svc.reprice_dispatches >= stats.epochs
     return audit
 
