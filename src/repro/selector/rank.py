@@ -572,26 +572,39 @@ def _bucket_size(n: int, bucket_base: int) -> int:
 def _validated_delta_cols(pos: Mapping[Hashable, int],
                           deltas: Union[Mapping[Hashable, float],
                                         Sequence[Tuple[Hashable, float]]],
-                          bucket_base: int
-                          ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
+                          bucket_base: int) -> Optional[np.ndarray]:
     """Delta-batch preparation for :class:`BatchedRankState`'s jitted
-    step: validate ids and prices (:func:`_validated_deltas`), then pad
-    ``(cols, new_prices)`` to the next power-of-4 column-count bucket so
-    the jitted step compiles O(log C) shape variants.  Padding repeats
-    the first (column, price) pair, which every kernel op treats
-    idempotently.  Returns ``None`` for an empty batch."""
+    step: validate ids and prices (:func:`_validated_deltas`), pad the
+    batch to the next power-of-4 column-count bucket so the jitted step
+    compiles O(log C) shape variants, and pack it into the step's one
+    host operand: an ``int32`` array of length 2·bucket holding the
+    columns, then the bits of the prices rounded to float32 (the
+    rounding the device's convert gives; :func:`_unpack_delta` splits
+    it).  Padding repeats the first (column, price) pair, which every
+    kernel op treats idempotently.  Returns ``None`` for an empty
+    batch."""
     validated = _validated_deltas(pos, deltas)
     if validated is None:
         return None
     cols, new_prices = validated
     k = cols.shape[0]
     bucket = _bucket_size(k, bucket_base)
-    if bucket > k:
-        cols = np.concatenate(
-            [cols, np.full(bucket - k, cols[0], dtype=np.int32)])
-        new_prices = np.concatenate(
-            [new_prices, np.full(bucket - k, new_prices[0])])
-    return cols, new_prices
+    delta = np.empty(2 * bucket, dtype=np.int32)
+    prices = delta[bucket:].view(np.float32)
+    delta[:bucket], prices[:] = cols[0], new_prices[0]
+    delta[:k], prices[:k] = cols, new_prices
+    return delta
+
+
+def _unpacked_heads(out: np.ndarray, k: int
+                    ) -> Tuple[int, np.ndarray, np.ndarray]:
+    """The host half of :func:`_pack_heads`: ``(moved, idx, vals)``
+    from the one ``int32`` readback of a reprice that took the heads,
+    ``idx`` and ``vals`` of shape (capacity, k), ``vals`` lifted to
+    float64."""
+    n = (out.shape[0] - 1) // 2
+    return (int(out[0]), out[1:1 + n].reshape(-1, k),
+            out[1 + n:].view(np.float32).reshape(-1, k).astype(np.float64))
 
 
 if _HAVE_JAX:
@@ -760,6 +773,22 @@ if _HAVE_JAX:
                                                       col_norm))
         return prices, cost, row_best, norm, scores, moved.sum()
 
+    def _unpack_delta(delta):
+        """A reprice's one host operand (:func:`_validated_delta_cols`)
+        split back into the bucket's columns and float32 prices."""
+        n = delta.shape[0] // 2
+        return delta[:n], jax.lax.bitcast_convert_type(delta[n:],
+                                                       jnp.float32)
+
+    def _pack_heads(moved, idx, vals):
+        """The handoff count and every slot's head as one ``int32``
+        array, ``[moved, idx.ravel(), bits of vals.ravel()]``, so the
+        reprice reads its results back in one transfer
+        (:func:`_unpacked_heads`)."""
+        return jnp.concatenate([
+            moved.astype(jnp.int32)[None], idx.ravel(),
+            jax.lax.bitcast_convert_type(vals, jnp.int32).ravel()])
+
     def _jax_batched_fns() -> Tuple[Any, Any, Any]:
         """``(step, step_heads, member_scores)`` jitted kernels for
         :class:`BatchedRankState`, built once on first use.
@@ -778,10 +807,13 @@ if _HAVE_JAX:
         refreshes *all* member accumulators with two small matmuls
         (handed-off-row deltas folded in; changed columns re-reduced
         from scratch) — one dispatch for the whole fleet, independent
-        of the member count.  ``step_heads(..., finite, k)`` is the same
-        step followed by :func:`_masked_top_k` over the new scores at
-        the static depth ``k``: the tick and every slot's head in one
-        program."""
+        of the member count.  Both steps take the delta batch as one
+        packed host operand (:func:`_unpack_delta`).
+        ``step_heads(..., finite, k)`` is the same step followed by
+        :func:`_masked_top_k` over the new scores at the static depth
+        ``k``, its handoff count and heads returned as one packed array
+        (:func:`_pack_heads`): the tick and every slot's head in one
+        program and one readback."""
         global _JAX_BATCHED_FNS
         if _JAX_BATCHED_FNS is not None:
             return _JAX_BATCHED_FNS
@@ -793,11 +825,18 @@ if _HAVE_JAX:
     def _build_jax_batched_fns() -> Tuple[Any, Any, Any]:
         global _JAX_BATCHED_FNS
 
+        def step(prices, cost, row_best, norm, scores, hours, mask,
+                 row_masks, delta):
+            return _batched_step(prices, cost, row_best, norm, scores,
+                                 hours, mask, row_masks,
+                                 *_unpack_delta(delta))
+
         def step_heads(prices, cost, row_best, norm, scores, hours, mask,
-                       row_masks, cols, new_prices, finite, k):
-            out = _batched_step(prices, cost, row_best, norm, scores,
-                                hours, mask, row_masks, cols, new_prices)
-            return out + _masked_top_k(out[4], finite, k)
+                       row_masks, delta, finite, k):
+            out = step(prices, cost, row_best, norm, scores, hours, mask,
+                       row_masks, delta)
+            heads = _pack_heads(out[5], *_masked_top_k(out[4], finite, k))
+            return out[:5] + (heads,)
 
         def member_scores(norm, row_mask):
             # a new member's accumulators from the current shared norm
@@ -805,8 +844,8 @@ if _HAVE_JAX:
 
         donate = () if jax.default_backend() == "cpu" else (0, 1, 2, 3, 4)
         _JAX_BATCHED_FNS = (
-            jax.jit(_batched_step, donate_argnums=donate),
-            jax.jit(step_heads, donate_argnums=donate, static_argnums=11),
+            jax.jit(step, donate_argnums=donate),
+            jax.jit(step_heads, donate_argnums=donate, static_argnums=10),
             jax.jit(member_scores))
         return _JAX_BATCHED_FNS
 
@@ -973,6 +1012,8 @@ class BatchedRankState:
                              else m.counter("rank.head_memo_hits"))
         self._c_reprice_heads = (None if m is None
                                  else m.counter("rank.reprice_heads"))
+        self._c_reprice_transfers = (
+            None if m is None else m.counter("rank.reprice_transfers"))
         self._c_ingests = (None if m is None
                            else m.counter("rank.ingest_batches"))
 
@@ -1125,38 +1166,41 @@ class BatchedRankState:
         and refresh **every** member's accumulators in one batched
         kernel dispatch; returns #rows whose masked row-minimum handed
         off (synced to host, so a return means the tick's kernel has
-        completed).
+        completed).  The batch crosses to the device as one packed host
+        operand and the results come back in one readback.
 
         When heads were asked since the previous reprice
         (:meth:`_fleet_heads`), the same program also runs the fleet's
         ``top_k`` over the new scores at the deepest depth asked, and
-        the one readback of the handoff count brings every slot's head
-        home into the heads memo, each asked depth a prefix of the
-        deepest (``lax.top_k`` keeps the lower index first on ties)."""
-        prepared = _validated_delta_cols(self._pos, deltas,
-                                         self._BUCKET_BASE)
-        if prepared is None:
+        the one readback, packed with the handoff count, brings every
+        slot's head home into the heads memo, each asked depth a prefix
+        of the deepest (``lax.top_k`` keeps the lower index first on
+        ties)."""
+        delta = _validated_delta_cols(self._pos, deltas, self._BUCKET_BASE)
+        if delta is None:
             return 0
-        cols, new_prices = prepared
         args = (self.d_prices, self.d_cost, self.d_row_best, self.d_norm,
                 self.d_scores, self.d_hours, self.d_mask, self.d_row_masks,
-                jnp.asarray(cols), jnp.asarray(new_prices,
-                                               dtype=jnp.float32))
+                delta)
         asked, self._asked = self._asked, set()
         if asked:
+            k = max(asked)
             (self.d_prices, self.d_cost, self.d_row_best, self.d_norm,
-             self.d_scores, moved, idx, vals) = self._step_heads(
-                *args, self._d_finite, max(asked))
-            moved, idx, vals = jax.device_get((moved, idx, vals))
-            vals = vals.astype(np.float64)
+             self.d_scores, out) = self._step_heads(*args, self._d_finite,
+                                                    k)
+            moved, idx, vals = _unpacked_heads(jax.device_get(out), k)
             self._head_memo = (self.d_scores, self._d_finite,
-                               {k: (idx[:, :k], vals[:, :k])
-                                for k in asked})
+                               {d: (idx[:, :d], vals[:, :d])
+                                for d in asked})
             if self._c_reprice_heads is not None:
                 self._c_reprice_heads.inc()
         else:
             (self.d_prices, self.d_cost, self.d_row_best, self.d_norm,
              self.d_scores, moved) = self._step(*args)
+        if self._c_reprice_transfers is not None:
+            # the packed delta in, the packed result (or the bare
+            # handoff count) out
+            self._c_reprice_transfers.inc(2)
         self.reprices += 1
         self.dispatches += 1
         return int(moved)

@@ -640,8 +640,7 @@ def _fleet_programs():
     step, step_heads, member = rank._jax_batched_fns()
     state = (sd((C,), f32), sd((J, C), f32), sd((J,), f32),
              sd((J, C), f32), sd((S, C), f32))
-    tick = (*state, *universe, sd((S, J), f32), sd((B,), jnp.int32),
-            sd((B,), f32))
+    tick = (*state, *universe, sd((S, J), f32), sd((2 * B,), jnp.int32))
     yield "jax_batched step", step.lower(*tick)
     yield "jax_batched step+heads", step_heads.lower(
         *tick, sd((S, C), jnp.bool_), 3)
@@ -690,3 +689,65 @@ def test_fleet_precision_probe_runs_small(monkeypatch):
     assert sorted(out) == ["DEFAULT", "HIGHEST"]
     for worst, n_bad in out.values():
         assert n_bad == 0 and worst < 1e-6
+
+
+# --- the reprice's packed operand and packed result -----------------------
+
+#: batch sizes landing in the 8, 32, 128 and 512 buckets, and an
+#: eviction storm that re-quotes every column of the catalog at once
+PACKED_BATCHES = {"8": (5, 8), "32": (20, 32), "128": (100, 128),
+                  "512": (400, 512), "eviction": (700, 2048)}
+
+
+@needs_jax
+@pytest.mark.parametrize("heads", [True, False], ids=["heads", "plain"])
+@pytest.mark.parametrize("batch", sorted(PACKED_BATCHES))
+def test_packed_reprice_is_bit_identical_to_the_traced_step(batch, heads):
+    """A reprice whose batch crosses as one packed int32 operand and
+    whose results come back as one packed readback leaves every resident
+    buffer, the handoff count and the heads memo bit-identical to the
+    shared traced step and top-k run on the unpacked columns and the
+    device's float32 rounding of the prices, jitted as one program on
+    the unpacked operands."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.selector import rank
+
+    n, bucket = PACKED_BATCHES[batch]
+    _, hours, mask, prices, ids, members = _fleet_universe(
+        7, n_jobs=10, n_cfgs=700)
+    state = BatchedRankState(hours, mask, prices, ids)
+    for key, rows in members.items():
+        state.add_state(key, rows=rows)
+    if heads:
+        state.top_k(next(iter(members)), 3)      # the reprice takes them
+    rng = np.random.default_rng(n)
+    cols = rng.choice(len(ids), n, replace=False).astype(np.int32)
+    new = rng.uniform(0.05, 20.0, n)
+    # prices float32 cannot hold exactly, one of them a new row minimum
+    new[:4] = [0.1, 3.3333, 1 / 3, 1e-3]
+    before = [jnp.array(b) for b in (
+        state.d_prices, state.d_cost, state.d_row_best, state.d_norm,
+        state.d_scores)]
+    pad_cols = np.full(bucket, cols[0], dtype=np.int32)
+    pad_cols[:n] = cols
+    pad_new = np.full(bucket, new[0])
+    pad_new[:n] = new
+    want = jax.jit(rank._batched_step)(
+        *before, state.d_hours, state.d_mask, state.d_row_masks,
+        jnp.asarray(pad_cols), jnp.asarray(pad_new, dtype=jnp.float32))
+    moved = state.reprice({ids[c]: float(p) for c, p in zip(cols, new)})
+    got = (state.d_prices, state.d_cost, state.d_row_best, state.d_norm,
+           state.d_scores)
+    for g, w in zip(got, want[:5]):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    assert moved == int(want[5]) and moved > 0
+    idx, vals = rank._masked_top_k(want[4], state._d_finite, 3)
+    memo = state._head_memo[2].get(3)
+    if heads:
+        np.testing.assert_array_equal(memo[0], np.asarray(idx))
+        np.testing.assert_array_equal(
+            memo[1], np.asarray(vals).astype(np.float64))
+    else:
+        assert memo is None

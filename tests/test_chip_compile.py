@@ -147,18 +147,18 @@ def test_profile_ingest_step_compiles_for_v5e(shape, backend):
 def test_fleet_reprice_with_heads_compiles_for_v5e(shape):
     """The XLA fleet's reprice+top-k program (no Pallas kernel) at the
     benchmark's saturate deployment: 18 jobs x 15,360 configurations,
-    16 member slots, the 512-column bucket of a 1% re-quote, 3-deep
-    heads."""
+    16 member slots, the 512-column bucket of a 1% re-quote packed into
+    one int32 operand, 3-deep heads packed with the handoff count into
+    one int32 result."""
     from repro.selector import rank
     f32 = jnp.float32
-    j, c, s, b = 18, 15_360, 16, 512
+    j, c, s, b, k = 18, 15_360, 16, 512, 3
     args = (shape((c,), f32), shape((j, c), f32), shape((j,), f32),
             shape((j, c), f32), shape((s, c), f32), shape((j, c), f32),
             shape((j, c), jnp.bool_), shape((s, j), f32),
-            shape((b,), jnp.int32), shape((b,), f32),
-            shape((s, c), jnp.bool_))
-    lowered = rank._jax_batched_fns()[1].lower(*args, 3)
-    idx, vals = lowered.out_info[-2:]
-    assert idx.shape == vals.shape == (s, 3)
+            shape((2 * b,), jnp.int32), shape((s, c), jnp.bool_))
+    lowered = rank._jax_batched_fns()[1].lower(*args, k)
+    out = lowered.out_info[-1]
+    assert out.shape == (1 + 2 * s * k,) and out.dtype == jnp.int32
     compiled = lowered.compile()
     assert compiled.memory_analysis() is not None

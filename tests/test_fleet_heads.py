@@ -165,15 +165,24 @@ def _separate_heads(state, k):
     return np.asarray(idx), np.asarray(vals, dtype=np.float64)
 
 
-def _spy_depths(state):
+def _spy_depths(state, operands=None):
     """Record the depth of each fused reprice+top-k program ``state``
-    runs."""
-    depths, real = [], state._step_heads
+    runs, and, into ``operands``, the delta operand each reprice hands
+    its program, fused or plain."""
+    depths, real, plain = [], state._step_heads, state._step
 
     def spy(*args):
         depths.append(args[-1])
+        if operands is not None:
+            operands.append(args[8])
         return real(*args)
+
+    def spy_plain(*args):
+        operands.append(args[8])
+        return plain(*args)
     state._step_heads = spy
+    if operands is not None:
+        state._step = spy_plain
     return depths
 
 
@@ -238,6 +247,36 @@ def test_a_reprice_with_no_depth_asked_is_the_plain_step():
     state.reprice({ids[5]: 0.3})                       # now the depth is asked
     state.reprice({ids[5]: 0.4})                       # and used up
     assert depths == [3] and _fused(reg) == 1
+
+
+@pytest.mark.parametrize("asked", [True, False], ids=["depth", "none"])
+def test_the_reprice_crosses_once_each_way(asked):
+    """The delta batch reaches the jitted program as one host int32
+    array (columns, then the float32 prices' bits) of twice the bucket,
+    and the registry counts two crossings a reprice: that operand in,
+    the packed result (or the bare handoff count) out."""
+    state, reg, ids = _state("jax_batched")
+    operands = []
+    depths = _spy_depths(state, operands)
+
+    def transfers():
+        return reg.snapshot()["counters"]["rank.reprice_transfers"]
+    for n, bucket in ((3, 8), (9, 32)):
+        if asked:
+            state.top_k("all", 2)
+        before = transfers()
+        batch = {ids[c]: 0.1 * c for c in range(1, n + 1)}
+        state.reprice(batch)
+        assert transfers() - before == 2
+        delta = operands[-1]
+        assert type(delta) is np.ndarray and delta.dtype == np.int32
+        assert delta.shape == (2 * bucket,)
+        np.testing.assert_array_equal(delta[:n], np.arange(1, n + 1))
+        np.testing.assert_array_equal(
+            delta[bucket:bucket + n].view(np.float32),
+            np.float32([0.1 * c for c in range(1, n + 1)]))
+    assert len(operands) == 2 and len(depths) == (2 if asked else 0)
+    assert _fused(reg) == (2 if asked else 0)
 
 
 def test_an_ingest_before_the_reprice_lands_in_the_heads_it_takes():
